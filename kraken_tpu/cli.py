@@ -79,6 +79,14 @@ async def _run_until_signal(node, describe: dict,
     # own (possibly ephemeral) port; report it so harnesses can find it.
     if getattr(node, "registry_addr", None):
         describe["registry_addr"] = node.registry_addr
+    # Where a device hasher really places its work (platform, device
+    # kind, device count as JAX reports them): `hasher: tpu` on a host
+    # without a chip runs on the CPU backend, and this line says so.
+    for holder in ("generator", "verifier"):
+        hasher = getattr(getattr(node, holder, None), "hasher", None)
+        devices = hasher.device_info() if hasher is not None else None
+        if devices is not None:
+            describe["hasher_devices"] = devices
     # One machine-readable line so herd harnesses can scrape the bound ports.
     print("READY " + json.dumps(describe), flush=True)
     await stop.wait()

@@ -21,6 +21,7 @@ of pieces. A per-piece call would hide the batch axis the hardware needs.
 from __future__ import annotations
 
 import hashlib
+import os
 import threading
 import time
 from concurrent.futures import Future, ThreadPoolExecutor
@@ -146,6 +147,14 @@ class PieceHasher:
     # directly; None = strictly serial hashing.
     pool: HashPool | None = None
 
+    def device_info(self) -> dict | None:
+        """``{"platform", "device_kind", "count"}`` of the devices this
+        hasher places its work on, as JAX reports them; None for a
+        hasher that runs on the host. Printed on the component's READY
+        line, so a deployment (and chip_smoke.py) can see where
+        ``hasher: tpu`` really landed."""
+        return None
+
     def hash_pieces(self, data: bytes | memoryview, piece_length: int) -> np.ndarray:
         """Split ``data`` into ``piece_length`` pieces (last may be short)
         and return the SHA-256 of each as a ``[num_pieces, 32] uint8``
@@ -247,6 +256,29 @@ def register_hasher(name: str, factory: Callable[[], PieceHasher]) -> None:
     _REGISTRY[name] = factory
 
 
+def _place_compile_cache() -> None:
+    """Give JAX's persistent compilation cache a home before the first
+    device hasher jits anything: each Mosaic SHA shape costs seconds to
+    compile, and origin and agent would otherwise pay it on every start.
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own business and
+    nothing is set here; otherwise the cache is ``.jax_cache`` beside the
+    package -- one fixed path, because the path is part of the cache key
+    and a directory that moves never hits."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        os.path.join(
+            os.path.dirname(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__)
+            ))),
+            ".jax_cache",
+        ),
+    )
+
+
 def get_hasher(name: str = "cpu", workers: int = 0) -> PieceHasher:
     """Resolve a hasher by registry name (``cpu``, ``tpu``,
     ``tpu-sharded`` -- the last fans the piece batch across every local
@@ -270,6 +302,8 @@ def get_hasher(name: str = "cpu", workers: int = 0) -> PieceHasher:
         if name not in _REGISTRY:
             # Importing the plane registers its hashers; deferred so that
             # pure-CPU components never pay the JAX import.
+            if name in ("tpu", "tpu-sharded"):
+                _place_compile_cache()
             if name == "tpu":
                 import kraken_tpu.ops.sha256  # noqa: F401
             elif name == "tpu-sharded":

@@ -1,10 +1,9 @@
 """Pipelined zero-copy ingest plane: upload spool -> device hash.
 
-The bench trajectory (PERF.md, BENCH_r04-r05) left the chip ~200x faster
-than the pipe feeding it: the packed SHA-256 kernel runs at ~81 GB/s/chip
-while e2e origin ingest measured 0.365 GB/s, because the feed path was
-serial -- read the whole window, then hash it, then read the next. This
-module turns that into a multi-window stream:
+The feed path used to be serial -- read the whole window, then hash it,
+then read the next -- which leaves the chip idle while the host reads and
+the host idle while the chip hashes. This module turns that into a
+multi-window stream:
 
     read -> pack -> transfer -> hash        (per window)
 

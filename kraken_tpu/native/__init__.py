@@ -10,7 +10,10 @@ fallback when no toolchain is available.
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
+import platform
 import shutil
 import subprocess
 import tempfile
@@ -18,18 +21,44 @@ from typing import Optional
 
 import numpy as np
 
+_log = logging.getLogger("kraken.native")
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "hostpack.c")
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
 
+def _host_identity() -> bytes:
+    """What tells one build host from another: architecture, host name
+    and the CPU's feature flags."""
+    flags = b""
+    try:
+        with open("/proc/cpuinfo", "rb") as f:
+            for line in f:
+                if line.startswith(b"flags"):
+                    flags = line
+                    break
+    except OSError:
+        pass
+    return f"{platform.machine()}|{platform.node()}|".encode() + flags
+
+
 def _build() -> Optional[str]:
+    """Path of the shared object built from THIS source on THIS host,
+    compiling it if absent. The name carries a hash of the source and of
+    the host's identity, so an object that arrived with a copied tree
+    (another source revision, another machine) is never picked up --
+    loading one built for a different CPU is an illegal instruction, not
+    an exception. The build also takes no ``-march=native``: the AVX-512
+    packer is selected at run time by ``__builtin_cpu_supports``."""
     cc = shutil.which("cc") or shutil.which("gcc") or shutil.which("clang")
     if cc is None:
         return None
-    out = os.path.join(_HERE, "_hostpack.so")
-    if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(_SRC):
+    with open(_SRC, "rb") as f:
+        key = hashlib.sha256(f.read() + b"\0" + _host_identity())
+    out = os.path.join(_HERE, f"_hostpack-{key.hexdigest()[:16]}.so")
+    if os.path.exists(out):
         return out
     # Build into a temp file then atomically rename: concurrent importers
     # (test workers, herd processes) must never load a half-written .so.
@@ -37,8 +66,7 @@ def _build() -> Optional[str]:
     os.close(fd)
     try:
         subprocess.run(
-            [cc, "-O3", "-march=native", "-shared", "-fPIC", "-pthread",
-             _SRC, "-o", tmp],
+            [cc, "-O3", "-shared", "-fPIC", "-pthread", _SRC, "-o", tmp],
             check=True,
             capture_output=True,
         )
@@ -59,6 +87,11 @@ def _load() -> Optional[ctypes.CDLL]:
     _TRIED = True
     path = _build()
     if path is None:
+        _log.info(
+            "host chunker/packer: NumPy fallback (no C compiler, or the "
+            "build failed)",
+            extra={"impl": "numpy"},
+        )
         return None
     try:
         lib = ctypes.CDLL(path)
@@ -94,11 +127,15 @@ def _load() -> Optional[ctypes.CDLL]:
         ]
         lib.kt_cdc_chunk.restype = ctypes.c_size_t
         _LIB = lib
-    except (OSError, AttributeError):
-        # AttributeError: a stale cached _hostpack.so from an older source
-        # (timestamp-preserving deploys defeat the mtime check) lacks the
-        # symbol -- fall back to NumPy rather than crash the feeder.
+        _log.info(
+            "host chunker/packer: C library %s", path, extra={"impl": "c"}
+        )
+    except (OSError, AttributeError) as e:
         _LIB = None
+        _log.warning(
+            "host chunker/packer: NumPy fallback (%s failed to load: %s)",
+            path, e, extra={"impl": "numpy"},
+        )
     return _LIB
 
 

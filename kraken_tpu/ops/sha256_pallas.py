@@ -113,7 +113,7 @@ def _make_kernel(nb_real: int, pad_words: np.ndarray, packed: bool):
 
     The shared SHA padding block is folded from compile-time constants
     (``pad_words``) after the last real block -- it never exists in HBM.
-    ``packed=False``: blk_ref is a natural [1, N_TILE, _KB*64] uint8 BYTE
+    ``packed=False``: blk_ref is a natural [N_TILE, _KB*64] uint8 BYTE
     slab, transposed in VMEM at u8 granularity. ``packed=True``: blk_ref
     is pre-packed [1, _KB, 16, _SUB, _LANES] BE words -- no relayout.
     out_ref: [1, 8, _SUB, _LANES], revisited across the block-group axis
@@ -137,7 +137,7 @@ def _make_kernel(nb_real: int, pad_words: np.ndarray, packed: bool):
             # ~22, u32 ~18. Recombining the four byte planes into
             # big-endian words costs 3 shifts + 3 ors per word and IS the
             # byteswap -- the LE->BE conversion falls out of plane order.
-            t8 = jnp.transpose(blk_ref[0], (1, 0)).reshape(
+            t8 = jnp.transpose(blk_ref[...], (1, 0)).reshape(
                 _KB, 16, 4, _SUB, _LANES
             )
 
@@ -202,29 +202,32 @@ def sha256_tiles(
     unpadded_blocks: int,
     interpret: bool | None = None,
 ):
-    """Hash T*N_TILE equal-length pieces from the NATURAL layout.
+    """Hash equal-length pieces from the NATURAL layout.
 
-    data_u8: [M, P] uint8 with M % N_TILE == 0 and P = unpadded_blocks * 64;
+    data_u8: [M, P] uint8, any M >= 1, P = unpadded_blocks * 64;
     pad_block: [16] uint32 shared SHA padding block (kept for API
     stability; the kernel folds compile-time constants). Returns [M, 8]
     uint32 digest words.
+
+    A batch shorter than a tile is NOT padded to N_TILE rows: the last
+    tile's block simply overhangs the array, and the lanes past row M
+    hash whatever the edge block holds and are sliced off. Shipped
+    batches are 4-16 rows of 4-16 MiB; padding them to the tile cost
+    1024 x piece_length of device memory per dispatch.
     """
     interpret = _resolve_interpret(interpret)
     m = data_u8.shape[0]
-    t = m // N_TILE
+    t = pl.cdiv(m, N_TILE)
     nb = unpadded_blocks
     ngroups = (nb + _KB - 1) // _KB
 
     # Natural piece-major BYTE slabs, one _KB-block group per grid step --
     # no XLA-side data movement (an XLA pre-transpose was the v1
     # bottleneck: ~12 GB/s); the kernel does the u8 relayout in VMEM.
-    slabs = data_u8.reshape(t, N_TILE, nb * 64)
     if nb % _KB:
         # Pad the block axis so the final (masked) grid group has a real
         # slab to DMA; the kernel's validity mask ignores the content.
-        slabs = jnp.pad(
-            slabs, ((0, 0), (0, 0), (0, (ngroups * _KB - nb) * 64))
-        )
+        data_u8 = jnp.pad(data_u8, ((0, 0), (0, (ngroups * _KB - nb) * 64)))
 
     pad_words = np.asarray(_pad_block_for(nb * 64), dtype=np.uint32)
 
@@ -234,7 +237,7 @@ def sha256_tiles(
         grid=(t, ngroups),
         in_specs=[
             pl.BlockSpec(
-                (1, N_TILE, _KB * 64), lambda ti, bi: (ti, 0, bi),
+                (N_TILE, _KB * 64), lambda ti, bi: (ti, bi),
                 memory_space=pltpu.VMEM,
             )
         ],
@@ -243,8 +246,8 @@ def sha256_tiles(
             memory_space=pltpu.VMEM,
         ),
         out_shape=jax.ShapeDtypeStruct((t, 8, _SUB, _LANES), jnp.uint32),
-    )(slabs)
-    return out.reshape(t, 8, N_TILE).transpose(0, 2, 1).reshape(m, 8)
+    )(data_u8)
+    return out.reshape(t, 8, N_TILE).transpose(0, 2, 1).reshape(-1, 8)[:m]
 
 
 @functools.partial(jax.jit, static_argnames=("unpadded_blocks", "interpret"))
@@ -364,71 +367,16 @@ def pack_tiles_device(
     )(slabs)
 
 
-def hash_pieces_device_packed(
-    data_u8: jax.Array, piece_length: int, interpret: bool | None = None
-) -> jax.Array:
-    """``pack: device`` hash path: on-device relayout
-    (:func:`pack_tiles_device`) feeding the pure-rounds packed kernel.
-    data_u8: [M, piece_length] uint8, any M; returns [M, 8] uint32."""
-    if piece_length % 64:
-        raise ValueError("pallas path requires piece_length % 64 == 0")
-    m = data_u8.shape[0]
-    pad_rows = (-m) % N_TILE
-    if pad_rows:
-        data_u8 = jnp.concatenate(
-            [data_u8, jnp.zeros((pad_rows, piece_length), dtype=jnp.uint8)]
-        )
-    packed = pack_tiles_device(
-        data_u8, piece_length // 64, interpret=interpret
-    )
-    return sha256_packed_tiles(
-        packed, piece_length // 64, interpret=interpret
-    )[:m]
-
-
 def hash_pieces_device(
     data_u8: jax.Array, piece_length: int, interpret: bool | None = None
 ) -> jax.Array:
     """Device-resident uniform-piece hashing from the natural layout.
 
-    data_u8: [M, piece_length] uint8 (any M -- padded up to N_TILE
-    internally); returns [M, 8] uint32 digest words. piece_length must be a
-    multiple of 64.
+    data_u8: [M, piece_length] uint8, any M >= 1 (a short batch rides
+    the last tile's edge block -- see :func:`sha256_tiles`); returns
+    [M, 8] uint32 digest words. piece_length must be a multiple of 64.
     """
     if piece_length % 64:
         raise ValueError("pallas path requires piece_length % 64 == 0")
-    m = data_u8.shape[0]
-    pad_rows = (-m) % N_TILE
-    if pad_rows:
-        data_u8 = jnp.concatenate(
-            [data_u8, jnp.zeros((pad_rows, piece_length), dtype=jnp.uint8)]
-        )
     pad = jnp.asarray(_pad_block_for(piece_length))
-    return sha256_tiles(data_u8, pad, piece_length // 64, interpret=interpret)[:m]
-
-
-def hash_packed_pieces(
-    data: np.ndarray, piece_length: int, interpret: bool | None = None
-) -> jax.Array:
-    """Host pack (native AVX-512 when available) + packed-kernel hash.
-
-    data: host [M, piece_length] uint8. The pack replaces the staging copy
-    a production feeder performs anyway; see PERF.md for the feed-rate
-    math. Returns [M, 8] uint32 digest words on device.
-    """
-    from kraken_tpu.native import pack_tiles
-
-    if piece_length % 64:
-        raise ValueError("pallas path requires piece_length % 64 == 0")
-    m = data.shape[0]
-    pad_rows = (-m) % N_TILE
-    if pad_rows:
-        data = np.concatenate(
-            [data, np.zeros((pad_rows, piece_length), dtype=np.uint8)]
-        )
-    nb = packed_nb(piece_length // 64)
-    packed = pack_tiles(np.ascontiguousarray(data), nb)
-    packed = packed.reshape(-1, nb, 16, _SUB, _LANES)
-    return sha256_packed_tiles(
-        jnp.asarray(packed), piece_length // 64, interpret=interpret
-    )[:m]
+    return sha256_tiles(data_u8, pad, piece_length // 64, interpret=interpret)

@@ -8,21 +8,22 @@ single-chip kernel (Pallas on real TPUs, interpret/XLA-scan on CPU), and
 the [N, 8] digest matrix is optionally all-gathered to every chip (32
 bytes/piece -- the collective is noise next to the hashing itself).
 
-Every placement is explicit (``jax.device_put`` with a ``NamedSharding``):
-the mesh may be virtual-CPU while a real accelerator is attached, and a
-stray default-device ``jnp.asarray`` would land there.
+Every placement on the mesh is explicit (``jax.device_put`` with a
+``NamedSharding``): a caller may hand in a mesh that is not on the
+default platform, and a stray default-device ``jnp.asarray`` would land
+off it.
 """
 
 from __future__ import annotations
 
 import functools
+import logging
 import time
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from kraken_tpu.parallel import compat
 from kraken_tpu.core.hasher import (
     DIGEST_SIZE,
     PieceHasher,
@@ -35,6 +36,8 @@ from kraken_tpu.ops.sha256 import (
     _sha256_uniform,
     JaxPieceHasher,
 )
+
+_log = logging.getLogger("kraken.hashplane")
 
 
 @functools.lru_cache(maxsize=32)
@@ -56,10 +59,7 @@ def _sharded_fn(
             )
         return _sha256_uniform(data_u8, pad_block, unpadded_blocks)
 
-    # Through the version shim (parallel/compat.py): jax.shard_map on
-    # new JAX, jax.experimental.shard_map (check_rep spelling) on the
-    # pinned toolchain, typed ParallelCompatError when neither exists.
-    mapped = compat.shard_map(
+    mapped = jax.shard_map(
         per_shard,
         mesh=mesh,
         in_specs=(P("pieces", None), P()),
@@ -69,7 +69,7 @@ def _sharded_fn(
         check_vma=False,
     )
     out_spec = P() if replicate else P("pieces", None)
-    return compat.jit_with_sharding(mapped, mesh, out_spec)
+    return jax.jit(mapped, out_shardings=NamedSharding(mesh, out_spec))
 
 
 def stage_sharded_pieces(
@@ -166,7 +166,39 @@ class ShardedPieceHasher(PieceHasher):
         if use_pallas is None:
             use_pallas = self._mesh.devices.flat[0].platform != "cpu"
         self._use_pallas = use_pallas
-        self._fallback = JaxPieceHasher(use_pallas=False)
+        # hash_batch (agent verify, dedup chunks) and ragged tails are not
+        # sharded: they run on the default device through the single-chip
+        # hasher, which needs the tile kernel for piece-sized batches as
+        # much as a `tpu` agent does.
+        self._fallback = JaxPieceHasher(use_pallas=use_pallas)
+        self._dispatched = False
+
+    def device_info(self) -> dict:
+        devs = list(self._mesh.devices.flat)
+        return {
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "count": len(devs),
+        }
+
+    def _hash_staged(self, staged: jax.Array, m: int, piece_length: int):
+        if not self._dispatched:
+            # Once per process: which devices really hold rows. A mesh
+            # that collapsed onto one device hashes just as correctly.
+            self._dispatched = True
+            _log.info(
+                "sharded hasher first dispatch",
+                extra={"rows_per_device": {
+                    str(s.device.id): int(s.data.shape[0])
+                    for s in staged.addressable_shards
+                }},
+            )
+        return _digest_bytes(
+            hash_sharded_staged(
+                self._mesh, staged, m, piece_length,
+                use_pallas=self._use_pallas, replicate=False,
+            )
+        )
 
     def hash_pieces(self, data, piece_length: int) -> np.ndarray:
         if piece_length <= 0:
@@ -183,17 +215,10 @@ class ShardedPieceHasher(PieceHasher):
         out = []
         if n_full:
             arr = np.frombuffer(view[: n_full * piece_length], dtype=np.uint8)
-            out.append(
-                _digest_bytes(
-                    sharded_hash_pieces(
-                        self._mesh,
-                        arr.reshape(n_full, piece_length),
-                        piece_length,
-                        use_pallas=self._use_pallas,
-                        replicate=False,
-                    )
-                )
+            staged, m = stage_sharded_pieces(
+                self._mesh, arr.reshape(n_full, piece_length), piece_length
             )
+            out.append(self._hash_staged(staged, m, piece_length))
         if n > n_full:  # ragged tail piece (raw: this call records the
             # blob's FULL total below -- the metric-wrapping hash_batch
             # would double-count the tail bytes under hasher="tpu")
@@ -228,12 +253,7 @@ class ShardedPieceHasher(PieceHasher):
         """Hash a :meth:`stage_window` handle -> [M, 32] uint8 digests."""
         staged, m, piece_length = handle
         start = time.perf_counter()
-        out = _digest_bytes(
-            hash_sharded_staged(
-                self._mesh, staged, m, piece_length,
-                use_pallas=self._use_pallas, replicate=False,
-            )
-        )
+        out = self._hash_staged(staged, m, piece_length)
         record_hash_metrics(
             self.name, m * piece_length, m, time.perf_counter() - start,
             occupancy=1.0,

@@ -17,25 +17,20 @@ from jax.sharding import Mesh
 def piece_mesh(
     n_devices: int | None = None, platform: str | None = None
 ) -> Mesh:
-    """Build a 1-D ``pieces`` mesh.
+    """Build a 1-D ``pieces`` mesh over the first ``n_devices`` devices
+    of ``platform`` (default: the default platform; all of its devices
+    when ``n_devices`` is None).
 
-    ``platform=None`` uses the default platform's devices; if those are too
-    few for ``n_devices`` (the usual single-real-chip dev setup), fall back
-    to the virtual CPU devices (``--xla_force_host_platform_device_count``).
-    Every array headed for this mesh must be placed with an explicit
-    ``NamedSharding`` -- never via default-device ``jnp.asarray``, which
-    would land on the (possibly flaky, possibly version-skewed) real
-    accelerator even when the mesh is CPU-virtual.
+    Asking for more devices than the platform has raises: a mesh that
+    quietly moved to other devices (virtual CPU ones, say) would hash
+    correctly and report nothing. Tests and the dry-run get their
+    virtual CPU devices by running ON the CPU platform
+    (``JAX_PLATFORMS=cpu`` + ``--xla_force_host_platform_device_count``).
     """
-    if platform is None:
-        devices = jax.devices()
-        if n_devices is not None and (
-            len(devices) < n_devices or devices[0].platform == "cpu"
-        ):
-            devices = jax.devices("cpu")
-    else:
-        devices = jax.devices(platform)
+    devices = jax.devices() if platform is None else jax.devices(platform)
     n = n_devices if n_devices is not None else len(devices)
     if len(devices) < n:
-        raise ValueError(f"need {n} devices, have {len(devices)}")
+        raise ValueError(
+            f"need {n} {devices[0].platform} devices, have {len(devices)}"
+        )
     return Mesh(np.asarray(devices[:n]), ("pieces",))

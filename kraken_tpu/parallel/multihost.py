@@ -31,7 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from kraken_tpu.ops.sha256 import _digest_bytes
-from kraken_tpu.parallel import compat
 from kraken_tpu.parallel.hashplane import sharded_hash_pieces
 
 
@@ -112,10 +111,8 @@ def _replicate_fn(mesh: Mesh):
     """Compile-cached replicating identity for one hosts mesh. A fresh
     ``jax.jit(lambda x: x)`` per call would key the jit cache on a new
     function object every time -- every batch would recompile (and
-    re-lower in lockstep on every host) the cross-host collective.
-    Compiled through the version shim (parallel/compat.py): pjit +
-    NamedSharding where available, typed error otherwise."""
-    return compat.jit_with_sharding(lambda x: x, mesh, P())
+    re-lower in lockstep on every host) the cross-host collective."""
+    return jax.jit(lambda x: x, out_shardings=NamedSharding(mesh, P()))
 
 
 def _gather(ctx: MultihostContext, local_block: np.ndarray, m: int):

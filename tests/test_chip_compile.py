@@ -1,0 +1,176 @@
+"""The served path's device programs, compiled for a TPU v5e that is
+described and not attached (no chip needed; nothing runs).
+
+What interpret mode and the CPU backend cannot show: whether the chip's
+compiler accepts each kernel at the SHIPPED shapes, and how much device
+memory the program takes there. The shapes are the ones the served path
+dispatches with the shipped config: a 64 MiB ingest window
+(config/origin/base.yaml ``ingest.window_bytes``) of 4, 8 or 16 MiB pieces
+(origin/metainfogen.py PieceLengthConfig). A compile that passes is not a
+chip run -- results and times come from ``chip_smoke.py`` on the chip.
+
+All of these live in this one file, and the topology is described inside a
+fixture: only one process at a time may load the TPU's library, so nothing
+here may touch it while a module is imported or collected.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+MIB = 1 << 20
+WINDOW = 64 * MIB  # config/origin/base.yaml ingest.window_bytes
+HBM = int(15.75 * (1 << 30))  # what the v5e's compiler says it has
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # whatever the plugin raises where it cannot
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A program compiled for a described chip is written to the persistent
+    cache but cannot be read back without the chip: the next compile would
+    warn and compile again. Keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+@pytest.mark.parametrize(
+    "rows,piece_mib",
+    [
+        pytest.param(16, 4, id="window-4MiB"),
+        pytest.param(8, 8, id="window-8MiB"),
+        pytest.param(4, 16, id="window-16MiB"),
+        pytest.param(64, 4, id="verify-batch-64x4MiB"),
+    ],
+)
+def test_tile_kernel_fits_the_chip_at_shipped_batches(one_chip, rows, piece_mib):
+    """The natural-layout SHA kernel at the batches the served path hands
+    it: one shipped 64 MiB window per piece tier (JaxPieceHasher.
+    hash_pieces) and the agent's largest verify batch (hash_batch, bounded
+    by sub_batch_bytes). Device memory is of the order of the batch.
+    Padding the rows up to the kernel's 1024-piece tile took 1024 x
+    piece_length of temp -- 4 GiB, 8 GiB, and more than the chip has at
+    16 MiB pieces."""
+    from kraken_tpu.ops.sha256_pallas import hash_pieces_device
+
+    plen = piece_mib * MIB
+    x = jax.ShapeDtypeStruct((rows, plen), jnp.uint8, sharding=one_chip)
+    compiled = _compile(
+        lambda d: hash_pieces_device(d, plen, interpret=False), x
+    )
+    mem = compiled.memory_analysis()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert mem.temp_size_in_bytes <= 2 * rows * plen, mem
+    assert mem.argument_size_in_bytes <= 2 * rows * plen, mem
+
+
+def test_ragged_scan_compiles_at_a_verify_batch(one_chip):
+    """The ragged scan at 16 rows of (4 MiB + SHA padding), block count
+    bucketed to the next power of two (ops/sha256.py _hash_batch_raw):
+    where a verify batch goes when its pieces are not of one length."""
+    from kraken_tpu.ops.sha256 import _sha256_ragged
+
+    blocks = jax.ShapeDtypeStruct(
+        (16, 131072, 64), jnp.uint8, sharding=one_chip
+    )
+    nblocks = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    mem = _compile(_sha256_ragged, blocks, nblocks).memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert total < HBM // 2, mem
+
+
+def test_gear_kernel_compiles_at_dispatch_size(one_chip):
+    from kraken_tpu.ops.cdc import CDCParams
+    from kraken_tpu.ops.cdc_pallas import _ROWS, _T_DISPATCH, _gear_pallas
+
+    p = CDCParams()
+    segs = jax.ShapeDtypeStruct(
+        (_T_DISPATCH, _ROWS, 128), jnp.uint8, sharding=one_chip
+    )
+    compiled = _compile(
+        lambda s: _gear_pallas(s, p.mask_strict, p.mask_loose), segs
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_packed_route_compiles_at_a_full_tile(one_chip):
+    """``pack_mode: device`` engages only on whole 1024-piece tiles
+    (core/ingest.py _packed_window): the relayout kernel and the
+    pure-rounds kernel at one tile of 4 MiB pieces."""
+    from kraken_tpu.ops.sha256_pallas import (
+        N_TILE,
+        pack_tiles_device,
+        packed_nb,
+        sha256_packed_tiles,
+    )
+
+    plen = 4 * MIB
+    nb = plen // 64
+    natural = jax.ShapeDtypeStruct((N_TILE, plen), jnp.uint8, sharding=one_chip)
+    packed = jax.ShapeDtypeStruct(
+        (1, packed_nb(nb), 16, 8, 128), jnp.uint32, sharding=one_chip
+    )
+    pack = _compile(
+        lambda d: pack_tiles_device(d, nb, interpret=False), natural
+    )
+    rounds = _compile(
+        lambda d: sha256_packed_tiles(d, nb, interpret=False), packed
+    )
+    for compiled in (pack, rounds):
+        assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes <= 2 * WINDOW, mem
+
+
+def test_sharded_window_hash_compiles_for_four_chips(topo):
+    """``hasher: tpu-sharded``: one shipped window row-sharded over the
+    four-chip host's mesh. Each chip runs the Pallas kernel on its own
+    rows; nothing crosses chips and no chip pads its 4 rows to a tile."""
+    from kraken_tpu.parallel.hashplane import _sharded_fn
+
+    import numpy as np
+
+    mesh = Mesh(np.asarray(topo.devices), ("pieces",))
+    plen = 4 * MIB
+    x = jax.ShapeDtypeStruct(
+        (WINDOW // plen, plen), jnp.uint8,
+        sharding=NamedSharding(mesh, P("pieces", None)),
+    )
+    pad = jax.ShapeDtypeStruct(
+        (16,), jnp.uint32, sharding=NamedSharding(mesh, P())
+    )
+    fn = _sharded_fn(mesh, plen // 64, True, False, False)
+    compiled = fn.lower(x, pad).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert "all-gather" not in text and "all-reduce" not in text
+    mem = compiled.memory_analysis()  # per device
+    assert mem.temp_size_in_bytes <= 2 * WINDOW, mem
+    assert mem.argument_size_in_bytes <= WINDOW, mem

@@ -2,6 +2,7 @@
 
 import hashlib
 import io
+import os
 
 import numpy as np
 import pytest
@@ -176,6 +177,39 @@ class TestCPUPieceHasher:
         assert get_hasher("cpu") is get_hasher("cpu")
         with pytest.raises(KeyError):
             get_hasher("nope")
+
+
+@pytest.mark.parametrize("env_dir", [None, "/somewhere/else"])
+def test_compile_cache_is_placed_from_outside(monkeypatch, env_dir):
+    """Before the first device hasher is built, JAX's persistent compile
+    cache gets ONE fixed home beside the package -- unless
+    JAX_COMPILATION_CACHE_DIR is set, and then the program sets nothing."""
+    import jax
+
+    from kraken_tpu.core import hasher as hasher_mod
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        if env_dir is None:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        else:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        hasher_mod._place_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        want = os.path.join(repo, ".jax_cache") if env_dir is None else None
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_device_hashers_report_their_devices():
+    """What a component prints as `hasher_devices` on its READY line:
+    None for the host hasher, the default device for `tpu`."""
+    assert get_hasher("cpu").device_info() is None
+    assert get_hasher("tpu").device_info() == {
+        "platform": "cpu", "device_kind": "cpu", "count": 1,
+    }
 
 
 class TestPooledCPUPieceHasher:

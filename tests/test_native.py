@@ -149,6 +149,25 @@ def test_pack_out_buffer_validation():
         native.pack_tiles(data, 8, out=ro)
 
 
+def test_shared_object_is_keyed_by_source_and_host(monkeypatch, tmp_path):
+    """The object is built from hostpack.c on the host that loads it: its
+    name carries a hash of the source and of the host's identity, so one
+    that arrived with a copied tree (the old fixed name `_hostpack.so`,
+    built elsewhere with -march=native) is never picked up."""
+    import os
+
+    if not native.have_native_packer():
+        pytest.skip("no C toolchain on this rig")
+    here = native._build()
+    assert os.path.basename(here) != "_hostpack.so"
+    assert native._build() == here  # stable on one host
+    monkeypatch.setattr(native, "_host_identity", lambda: b"another host")
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))
+    elsewhere = native._build()
+    assert elsewhere is not None and os.path.exists(elsewhere)
+    assert os.path.basename(elsewhere) != os.path.basename(here)
+
+
 def test_pooled_pack_scales_with_workers():
     """On a multi-core rig, 2 pack workers must beat 1 by a real margin
     (the pack loop is GIL-free and group-parallel). Interleaved pairwise

@@ -31,6 +31,47 @@ def test_piece_mesh_has_eight_devices():
     assert mesh.devices.flat[0].platform == "cpu"
 
 
+def test_piece_mesh_never_moves_to_another_platform(monkeypatch):
+    """Asking for more devices than the default platform has raises. The
+    mesh used to move to virtual CPU devices in that case, and a
+    `tpu-sharded` hasher on them hashed correctly and said nothing."""
+    import jax
+
+    class OneChip:
+        platform = "tpu"
+        id = 0
+
+    asked = []
+
+    def devices(platform=None):
+        asked.append(platform)
+        return [OneChip()]
+
+    monkeypatch.setattr(jax, "devices", devices)
+    with pytest.raises(ValueError, match="need 4 tpu devices, have 1"):
+        piece_mesh(4)
+    assert asked == [None]
+
+
+def test_sharded_hasher_says_where_its_rows_went(caplog):
+    """`device_info` is what the READY line prints; the first dispatch
+    logs the rows each device holds (chip_smoke.py --four-chips reads
+    both to prove four chips took work)."""
+    import logging
+
+    hasher = ShardedPieceHasher(mesh=piece_mesh(8))
+    assert hasher.device_info() == {
+        "platform": "cpu", "device_kind": "cpu", "count": 8,
+    }
+    blob = os.urandom(16 * 256)
+    with caplog.at_level(logging.INFO, logger="kraken.hashplane"):
+        hasher.hash_pieces(blob, 256)
+        hasher.hash_pieces(blob, 256)
+    first = [r for r in caplog.records if r.name == "kraken.hashplane"]
+    assert len(first) == 1  # once per hasher, not per dispatch
+    assert first[0].rows_per_device == {str(i): 2 for i in range(8)}
+
+
 # The Pallas variant is opt-in: XLA:CPU needs >5 min to compile the
 # kernel's unrolled body in any CPU mode (see dryrun_multichip docstring);
 # the kernel's correctness home is the real chip (entry() + bench.py).

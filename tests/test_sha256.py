@@ -115,6 +115,48 @@ def test_hash_batch_mixed_sizes_bounded_memory():
         assert bytes(row) == hashlib.sha256(p).digest()
 
 
+@pytest.mark.parametrize(
+    "sub_batch_pieces,want_shapes",
+    [(64, [(1, 8192), (16, 4096)]), (4, [(1, 4096), (1, 8192), (4, 4096)])],
+)
+def test_hash_batch_sends_piece_sized_uniform_groups_to_the_tile_kernel(
+    monkeypatch, sub_batch_pieces, want_shapes
+):
+    """On an accelerator (``use_pallas``) the equal-length, piece-sized
+    entries of a hash_batch -- an agent's verify batch -- go through the
+    tile kernel in rows bucketed to powers of four and bounded by the
+    sub-batch budget; everything else stays on the ragged scan, and every
+    digest lands on its own row. The kernel itself only runs on the chip
+    (chip_smoke.py holds it to hashlib there); a stand-in with the same
+    contract records what it was handed."""
+    import jax.numpy as jnp
+
+    from kraken_tpu.ops import sha256 as plane
+    from kraken_tpu.ops import sha256_pallas
+
+    shapes = []
+
+    def tile_kernel(data_u8, piece_length, interpret=None):
+        shapes.append(tuple(data_u8.shape))
+        pad = jnp.asarray(plane._pad_block_for(piece_length))
+        return plane._sha256_uniform(data_u8, pad, piece_length // 64)
+
+    monkeypatch.setattr(sha256_pallas, "hash_pieces_device", tile_kernel)
+    monkeypatch.setattr(plane, "_TILE_KERNEL_MIN_BYTES", 4096)
+    h = plane.JaxPieceHasher(
+        use_pallas=True, sub_batch_bytes=sub_batch_pieces * 4096
+    )
+    pieces = [
+        os.urandom(100), os.urandom(4096), os.urandom(4096), os.urandom(4097),
+        os.urandom(8192), os.urandom(4096), os.urandom(4096 - 64),
+        os.urandom(4096), os.urandom(4096), b"",
+    ]
+    got = h.hash_batch(pieces)
+    for row, p in zip(got, pieces):
+        assert bytes(row) == hashlib.sha256(p).digest()
+    assert sorted(shapes) == want_shapes
+
+
 @pytest.mark.skipif(
     not os.environ.get("RUN_PALLAS_INTERPRET"),
     reason="interpret-mode kernel execution takes minutes on CPU; the "
