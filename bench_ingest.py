@@ -418,7 +418,7 @@ class _NoopHasher:
         n = max(1, -(-len(data) // piece_length)) if data else 1
         return np.zeros((n, 32), dtype=np.uint8)
 
-    def hash_batch(self, pieces):
+    def hash_batch(self, pieces, purpose="verify"):
         return np.zeros((len(pieces), 32), dtype=np.uint8)
 
 

@@ -31,13 +31,19 @@ Stage semantics per window:
   configured.
 
 Every window observes ``ingest_stage_seconds{stage}`` and the per-upload
-stage walls land on the ingest trace span (origin/server.py). Digests are
+stage walls land on the ingest trace span (origin/server.py). Three more
+stages time what a commit waits for outside the window's own work and stay
+out of ``overlap_ratio``: **queue** (``submit`` until a worker picks the
+window up: the one executor every upload of the process shares), **join**
+and **publish** (origin/server.py: the commit's wait for its last window,
+then metainfo adoption and the post-commit fan-out). Digests are
 bit-identical to the serial oracle by construction: pipelining reorders
 WHEN a piece is hashed, never piece boundaries.
 """
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import logging
 import threading
@@ -47,7 +53,15 @@ from typing import Optional
 
 import numpy as np
 
-from kraken_tpu.core.hasher import DIGEST_SIZE, HashPool, PieceHasher
+from kraken_tpu.core.hasher import (
+    DIGEST_SIZE,
+    HashPool,
+    PieceHasher,
+    device_section,
+    profiler_annotation,
+    record_hash_metrics,
+    sha_blocks,
+)
 from kraken_tpu.utils import failpoints
 
 _log = logging.getLogger("kraken.ingest")
@@ -58,7 +72,7 @@ PACK_MODES = ("host", "native", "device")
 
 # Stage walls span ~100 us (a reshape) to ~10 s (a multi-GiB window on a
 # cold page cache): wider-than-default log-spaced buckets.
-_STAGE_BUCKETS = (
+STAGE_BUCKETS = (
     0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
     1.0, 2.5, 5.0, 10.0, 30.0,
 )
@@ -71,8 +85,38 @@ def record_stage(stage: str, seconds: float) -> None:
     REGISTRY.histogram(
         "ingest_stage_seconds",
         "Per-window wall of each ingest pipeline stage",
-        buckets=_STAGE_BUCKETS,
+        buckets=STAGE_BUCKETS,
     ).observe(seconds, stage=stage)
+
+
+class timed_stage:
+    """``with timed_stage("hash", bill):`` -- one clock reading serves the
+    histogram, the session's stage wall (``bill(stage, seconds)``) and a
+    ``kraken.<plane>.<stage>`` annotation in the profiler's file. A stage
+    that raises is not billed. One thread, no ``await`` inside."""
+
+    __slots__ = ("_stage", "_bill", "_plane", "_annotation", "_t0", "seconds")
+
+    def __init__(self, stage: str, bill=record_stage, plane: str = "ingest"):
+        self._stage = stage
+        self._bill = bill
+        self._plane = plane
+        self.seconds = 0.0
+
+    def __enter__(self) -> "timed_stage":
+        self._annotation = profiler_annotation(
+            f"kraken.{self._plane}.{self._stage}"
+        )
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        if exc_type is None:
+            self.seconds = time.perf_counter() - self._t0
+            self._bill(self._stage, self.seconds)
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -261,6 +305,9 @@ class IngestSession:
         self.stage_seconds: dict[str, float] = dict.fromkeys(
             ("read", "pack", "transfer", "hash"), 0.0
         )
+        # Submit -> a worker picks the window up, summed over windows.
+        # Not a stage of the window's own work: out of overlap_ratio.
+        self.queue_seconds = 0.0
         self.windows = 0
         self.wall_seconds = 0.0
 
@@ -292,7 +339,10 @@ class IngestSession:
         if not 0 <= nbytes <= self.window_bytes:
             raise ValueError(f"submit: {nbytes} outside window")
         lease, self._lease = self._lease, None
-        read_s = time.perf_counter() - self._read_t0
+        # No profiler annotation for read: begin_window and submit may run
+        # on different threads (one PATCH flush each).
+        t_submit = time.perf_counter()
+        read_s = t_submit - self._read_t0
         self.stage_seconds["read"] += read_s
         record_stage("read", read_s)
         self.windows += 1
@@ -300,8 +350,11 @@ class IngestSession:
             lease.release()
             self._sem.release()
             return
+        # The submitter's context rides along: the window's spans and
+        # device sections are children of the request that submitted it.
         fut = self.pipeline._get_executor().submit(
-            self._process, lease, nbytes
+            contextvars.copy_context().run,
+            self._process, lease, nbytes, t_submit,
         )
         self._futs.append(fut)
 
@@ -400,7 +453,10 @@ class IngestSession:
         self.stage_seconds[stage] += seconds
         record_stage(stage, seconds)
 
-    def _process(self, lease, nbytes: int) -> np.ndarray:
+    def _process(self, lease, nbytes: int, t_submit: float) -> np.ndarray:
+        queue_s = time.perf_counter() - t_submit
+        self.queue_seconds += queue_s
+        record_stage("queue", queue_s)
         try:
             view = lease.view[:nbytes]
             plen = self.piece_length
@@ -463,24 +519,19 @@ class IngestSession:
             if hasattr(hasher, "stage_window"):
                 if failpoints.fire("ingest.window.transfer"):
                     raise failpoints.FailpointError("ingest.window.transfer")
-                t0 = time.perf_counter()
-                handle = hasher.stage_window(arr, plen)
-                self._bill("transfer", time.perf_counter() - t0)
+                with timed_stage("transfer", self._bill):
+                    handle = hasher.stage_window(arr, plen)
                 if failpoints.fire("ingest.window.hash"):
                     raise failpoints.FailpointError("ingest.window.hash")
-                t0 = time.perf_counter()
-                out = hasher.hash_staged_window(handle)
-                self._bill("hash", time.perf_counter() - t0)
-                return out
+                with timed_stage("hash", self._bill):
+                    return hasher.hash_staged_window(handle)
         # CPU HashPool path, ragged final window, hashers without the
         # staged protocol: one batch call, billed to hash. Bit-identical
         # by definition -- same boundaries.
         if failpoints.fire("ingest.window.hash"):
             raise failpoints.FailpointError("ingest.window.hash")
-        t0 = time.perf_counter()
-        out = hasher.hash_pieces(view, plen)
-        self._bill("hash", time.perf_counter() - t0)
-        return out
+        with timed_stage("hash", self._bill):
+            return hasher.hash_pieces(view, plen)
 
     def _host_window(self, view, plen: int) -> np.ndarray:
         """Inline hashlib piece pass -- the degradation target. No
@@ -491,13 +542,12 @@ class IngestSession:
         nbytes = len(view)
         n = max(1, -(-nbytes // plen)) if nbytes else 0
         out = np.empty((n, DIGEST_SIZE), dtype=np.uint8)
-        t0 = time.perf_counter()
-        for i in range(n):
-            piece = view[i * plen:(i + 1) * plen]
-            out[i] = np.frombuffer(
-                hashlib.sha256(piece).digest(), dtype=np.uint8
-            )
-        self._bill("hash", time.perf_counter() - t0)
+        with timed_stage("hash", self._bill):
+            for i in range(n):
+                piece = view[i * plen:(i + 1) * plen]
+                out[i] = np.frombuffer(
+                    hashlib.sha256(piece).digest(), dtype=np.uint8
+                )
         return out
 
     def _packed_window(self, arr: np.ndarray, plen: int) -> np.ndarray:
@@ -515,31 +565,30 @@ class IngestSession:
         if failpoints.fire("ingest.window.pack"):
             raise failpoints.FailpointError("ingest.window.pack")
         nb = packed_nb(plen // 64)
+        packed = None
         if self._cfg.pack_mode == "native":
             from kraken_tpu import native
 
-            t0 = time.perf_counter()
-            packed = native.pack_tiles_pooled(
-                arr, nb, self.pipeline._get_pack_pool()
-            ).reshape(-1, nb, 16, 8, 128)
-            self._bill("pack", time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            xdev = jax.device_put(packed)
-            self._bill("transfer", time.perf_counter() - t0)
-        else:  # device: transfer natural bytes, relayout on-chip
-            t0 = time.perf_counter()
-            xdev_nat = jax.device_put(arr)
-            self._bill("transfer", time.perf_counter() - t0)
-            t0 = time.perf_counter()
-            xdev = pack_tiles_device(xdev_nat, plen // 64)
-            self._bill("pack", time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        out = _digest_bytes(sha256_packed_tiles(xdev, plen // 64))
-        hash_s = time.perf_counter() - t0
-        self._bill("hash", hash_s)
-        from kraken_tpu.core.hasher import record_hash_metrics
-
-        record_hash_metrics(
-            self.pipeline.hasher.name, arr.size, arr.shape[0], hash_s
-        )
+            with timed_stage("pack", self._bill):
+                packed = native.pack_tiles_pooled(
+                    arr, nb, self.pipeline._get_pack_pool()
+                ).reshape(-1, nb, 16, 8, 128)
+        m = arr.shape[0]
+        # Transfer, on-chip relayout and hash are one device section: the
+        # result is on the host only after the last.
+        with device_section(
+            "piece", "sha256_packed", rows=m, blocks=sha_blocks(plen),
+            useful_blocks=m * sha_blocks(plen), payload_bytes=arr.size,
+        ):
+            if packed is not None:
+                with timed_stage("transfer", self._bill):
+                    xdev = jax.device_put(packed)
+            else:  # device: transfer natural bytes, relayout on-chip
+                with timed_stage("transfer", self._bill):
+                    xdev_nat = jax.device_put(arr)
+                with timed_stage("pack", self._bill):
+                    xdev = pack_tiles_device(xdev_nat, plen // 64)
+            with timed_stage("hash", self._bill):
+                out = _digest_bytes(sha256_packed_tiles(xdev, plen // 64))
+        record_hash_metrics(self.pipeline.hasher.name, arr.size, m)
         return out

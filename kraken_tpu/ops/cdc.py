@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kraken_tpu.core.hasher import device_section
 from kraken_tpu.ops import next_pow2
 
 _WINDOW = 32  # bytes of history in a 32-bit gear hash
@@ -227,6 +228,15 @@ def _host_select_cuts(
 _SEGMENT = 4 * 1024 * 1024
 
 
+def cdc_section(kernel: str, rows: int, row_bytes: int, useful_bytes: int):
+    """A device section of the chunking plane: a block is 64 bytes of a
+    dispatched row, ``useful_bytes`` what of the dispatch is real input."""
+    return device_section(
+        "cdc", kernel, rows=rows, blocks=-(-row_bytes // 64),
+        useful_blocks=-(-useful_bytes // 64), payload_bytes=useful_bytes,
+    )
+
+
 def _candidate_indices(
     arr: np.ndarray, n: int, params: CDCParams
 ) -> tuple[np.ndarray, np.ndarray]:
@@ -256,13 +266,12 @@ def _candidate_indices(
             # release it asynchronously; callers hand us mmap-backed views
             # whose close() must not race a device transfer (BufferError).
             arr = np.array(arr[:n], copy=True)
-        strict, loose = _gear_candidates(
-            jnp.asarray(arr), params.mask_strict, params.mask_loose
-        )
-        return (
-            np.flatnonzero(np.asarray(strict)[:n]),
-            np.flatnonzero(np.asarray(loose)[:n]),
-        )
+        with cdc_section("gear_candidates", 1, padded, n):
+            strict, loose = _gear_candidates(
+                jnp.asarray(arr), params.mask_strict, params.mask_loose
+            )
+            strict, loose = np.asarray(strict), np.asarray(loose)
+        return np.flatnonzero(strict[:n]), np.flatnonzero(loose[:n])
     buf_len = _SEGMENT + _WINDOW - 1  # one fixed jit shape for every segment
     strict_parts: list[np.ndarray] = []
     loose_parts: list[np.ndarray] = []
@@ -272,12 +281,14 @@ def _candidate_indices(
         seg = arr[lo : min(s + _SEGMENT, n)]
         buf[: len(seg)] = seg
         buf[len(seg) :] = 0
-        strict, loose = _gear_candidates(
-            jnp.asarray(buf), params.mask_strict, params.mask_loose
-        )
+        with cdc_section("gear_candidates", 1, buf_len, len(seg)):
+            strict, loose = _gear_candidates(
+                jnp.asarray(buf), params.mask_strict, params.mask_loose
+            )
+            strict, loose = np.asarray(strict), np.asarray(loose)
         local = slice(s - lo, len(seg))  # valid, non-overlap positions
-        strict_parts.append(np.flatnonzero(np.asarray(strict)[local]) + s)
-        loose_parts.append(np.flatnonzero(np.asarray(loose)[local]) + s)
+        strict_parts.append(np.flatnonzero(strict[local]) + s)
+        loose_parts.append(np.flatnonzero(loose[local]) + s)
     return np.concatenate(strict_parts), np.concatenate(loose_parts)
 
 

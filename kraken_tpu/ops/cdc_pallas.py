@@ -26,7 +26,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from kraken_tpu.ops.cdc import _WINDOW, _gear_fn_vec
+from kraken_tpu.ops.cdc import _WINDOW, _gear_fn_vec, cdc_section
 
 _SEG = 1 << 18          # data bytes per grid step (VMEM-bounded: u32
                         # intermediates are 4x, plus live doubling copies)
@@ -132,20 +132,23 @@ def candidate_indices_pallas(
         while t_disp < t:
             t_disp *= 2
         segs = np.zeros((t_disp, _BUF), dtype=np.uint8)
+        filled = 0
         for i in range(t):
             s = (group + i) * _SEG
             lo = max(0, s - _PAD)
             chunk = arr[lo : min(s + _SEG, n)]
             segs[i, _LEAD - (s - lo) : _LEAD - (s - lo) + len(chunk)] = chunk
-        strict, loose = _gear_pallas(
-            jnp.asarray(segs.reshape(t_disp, _ROWS, 128)),
-            mask_s, mask_l,
-            first_group=(group == 0), interpret=interpret,
-        )
-        # Slice to live segments ON DEVICE: fetching the padded rows back
-        # would double the D2H bytes for ragged tails.
-        strict = np.asarray(strict[:t]).reshape(t, _SEG)
-        loose = np.asarray(loose[:t]).reshape(t, _SEG)
+            filled += len(chunk)
+        with cdc_section("gear_pallas", t_disp, _BUF, filled):
+            strict, loose = _gear_pallas(
+                jnp.asarray(segs.reshape(t_disp, _ROWS, 128)),
+                mask_s, mask_l,
+                first_group=(group == 0), interpret=interpret,
+            )
+            # Slice to live segments ON DEVICE: fetching the padded rows
+            # back would double the D2H bytes for ragged tails.
+            strict = np.asarray(strict[:t]).reshape(t, _SEG)
+            loose = np.asarray(loose[:t]).reshape(t, _SEG)
         for i in range(t):
             s = (group + i) * _SEG
             valid = min(_SEG, n - s)

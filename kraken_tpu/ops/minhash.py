@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from kraken_tpu.core.hasher import device_section
 from kraken_tpu.ops import next_pow2 as _next_pow2
 
 
@@ -77,6 +78,16 @@ def _topk_kernel(query: jax.Array, corpus: jax.Array, n_live, k: int):
 _SCORE_DEVICE_MIN = 4096
 
 
+def _sketch_section(kernel: str, rows: int, useful_rows: int, slots: int):
+    """A device section of the sketch plane: a block is one uint32 slot
+    of a row (a fingerprint, a sketch coordinate), ``rows`` as padded."""
+    return device_section(
+        "sketch", kernel, rows=rows, blocks=slots,
+        useful_blocks=useful_rows * slots,
+        payload_bytes=4 * useful_rows * slots,
+    )
+
+
 def _pad_pow2_rows(arr: np.ndarray) -> np.ndarray:
     """Zero-pad the row axis to a power of two (bounded jit cache)."""
     n = arr.shape[0]
@@ -99,9 +110,11 @@ def _score(query: np.ndarray, corpus: np.ndarray) -> np.ndarray:
     n = corpus.shape[0]
     if n < _SCORE_DEVICE_MIN:
         return np.mean(corpus == query[None, :], axis=1, dtype=np.float32)
-    return np.asarray(
-        _score_kernel(jnp.asarray(query), jnp.asarray(_pad_pow2_rows(corpus)))
-    )[:n]
+    padded = _pad_pow2_rows(corpus)
+    with _sketch_section("minhash_score", len(padded), n, corpus.shape[1]):
+        return np.asarray(
+            _score_kernel(jnp.asarray(query), jnp.asarray(padded))
+        )[:n]
 
 
 class MinHasher:
@@ -139,10 +152,15 @@ class MinHasher:
         for i, s in enumerate(sets):
             fps[i, : len(s)] = s
             mask[i, : len(s)] = True
-        out = _sketch_kernel(
-            jnp.asarray(fps), jnp.asarray(mask), jnp.asarray(self._a), jnp.asarray(self._b)
-        )
-        return np.asarray(out)[:b]
+        filled = sum(len(s) for s in sets)
+        with device_section(
+            "sketch", "minhash_sketch", rows=bb, blocks=m,
+            useful_blocks=filled, payload_bytes=4 * filled,
+        ):
+            out = _sketch_kernel(
+                jnp.asarray(fps), jnp.asarray(mask), jnp.asarray(self._a), jnp.asarray(self._b)
+            )
+            return np.asarray(out)[:b]
 
 
 def estimate_jaccard(sketch_a: np.ndarray, sketch_b: np.ndarray) -> float:
@@ -326,21 +344,26 @@ class LSHIndex:
         if len(live) >= _SCORE_DEVICE_MIN:
             # Large corpus: scan the cached device copy of the live rows
             # (rebuilt only when the index mutated since the last scan).
+            rows = None
             if self._corpus_dev is None or self._dev_gen != self._gen:
-                rows = (
+                rows = _pad_pow2_rows(
                     self._corpus
                     if len(live) == len(self._keys)
                     else self._corpus[live]
                 )
-                self._corpus_dev = jnp.asarray(_pad_pow2_rows(rows))
-                self._dev_gen = self._gen
             kk = min(k, len(live))
-            top_v, top_i = _topk_kernel(
-                jnp.asarray(query), self._corpus_dev, len(live), kk
-            )
+            with _sketch_section(
+                "minhash_topk", _next_pow2(len(live)), len(live), len(query)
+            ):
+                if rows is not None:
+                    self._corpus_dev = jnp.asarray(rows)
+                    self._dev_gen = self._gen
+                top_v, top_i = _topk_kernel(
+                    jnp.asarray(query), self._corpus_dev, len(live), kk
+                )
+                top_i, top_v = np.asarray(top_i), np.asarray(top_v)
             return [
-                (self._keys[live[i]], float(v))
-                for i, v in zip(np.asarray(top_i), np.asarray(top_v))
+                (self._keys[live[i]], float(v)) for i, v in zip(top_i, top_v)
             ]
         scores = _score(query, self._corpus[live])
         order = np.argsort(-scores)[:k]
@@ -704,20 +727,24 @@ class CompactLSHIndex:
             return []
         query = np.asarray(sketch, dtype=np.uint32)
         if len(self) >= _SCORE_DEVICE_MIN:
+            rows = None
             if self._dev is None or self._dev_gen != self._gen:
                 self._dev_live = np.flatnonzero(self._alive[: self._n])
-                self._dev = jnp.asarray(
-                    _pad_pow2_rows(self._mat[self._dev_live])
-                )
-                self._dev_gen = self._gen
+                rows = _pad_pow2_rows(self._mat[self._dev_live])
             live = self._dev_live
             kk = min(k, len(live))
-            top_v, top_i = _topk_kernel(
-                jnp.asarray(query), self._dev, len(live), kk
-            )
+            with _sketch_section(
+                "minhash_topk", _next_pow2(len(live)), len(live), len(query)
+            ):
+                if rows is not None:
+                    self._dev = jnp.asarray(rows)
+                    self._dev_gen = self._gen
+                top_v, top_i = _topk_kernel(
+                    jnp.asarray(query), self._dev, len(live), kk
+                )
+                top_i, top_v = np.asarray(top_i), np.asarray(top_v)
             return [
-                (self._keys[live[i]], float(v))
-                for i, v in zip(np.asarray(top_i), np.asarray(top_v))
+                (self._keys[live[i]], float(v)) for i, v in zip(top_i, top_v)
             ]
         live = np.flatnonzero(self._alive[: self._n])
         scores = _score(query, self._mat[live])

@@ -25,13 +25,18 @@ stays small across varying blob sizes.
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from kraken_tpu.core.hasher import DIGEST_SIZE, PieceHasher, register_hasher
+from kraken_tpu.core.hasher import (
+    DIGEST_SIZE,
+    PieceHasher,
+    device_section,
+    register_hasher,
+    sha_blocks,
+)
 from kraken_tpu.core.hasher import record_hash_metrics as _record_hash_metrics
 from kraken_tpu.ops import next_pow2 as _next_pow2
 
@@ -188,10 +193,16 @@ def _pack_be_u32_np(b: np.ndarray) -> np.ndarray:
 _TILE_KERNEL_MIN_BYTES = 1 << 20
 
 
+def _tile_rows(rows: int) -> int:
+    """Lanes the tile kernel's grid covers for ``rows`` dispatched rows:
+    whole tiles of 1024 (``sha256_pallas.N_TILE``), filled or not."""
+    return -(-rows // 1024) * 1024
+
+
 def _sha_pad_np(piece: memoryview, nblocks_out: int) -> np.ndarray:
     """SHA-pad one piece into [nblocks_out, 64] uint8 (zero-filled beyond)."""
     ln = len(piece)
-    need = (ln + 8) // 64 + 1
+    need = sha_blocks(ln)
     assert need <= nblocks_out
     out = np.zeros((nblocks_out, 64), dtype=np.uint8)
     flat = out.reshape(-1)
@@ -224,13 +235,8 @@ class JaxPieceHasher(PieceHasher):
             use_pallas = jax.default_backend() != "cpu"
         self._use_pallas = use_pallas
 
-    def device_info(self) -> dict:
-        dev = jax.devices()[0]  # every dispatch lands on the default device
-        return {
-            "platform": dev.platform,
-            "device_kind": dev.device_kind,
-            "count": 1,
-        }
+    def devices(self) -> list:
+        return jax.devices()[:1]  # every dispatch lands on the default device
 
     # -- blob -> per-piece digests (origin metainfo-gen hot loop) ----------
 
@@ -241,40 +247,46 @@ class JaxPieceHasher(PieceHasher):
         total = len(view)
         if total == 0:
             return np.empty((0, DIGEST_SIZE), dtype=np.uint8)
-        start = time.perf_counter()
-        dispatched_rows = 0  # padded rows actually sent to the device
         n = (total + piece_length - 1) // piece_length
         n_full = total // piece_length
 
-        outs: list[jax.Array] = []
+        parts: list[np.ndarray] = []
         if n_full and piece_length % 64 == 0:
             # Fast path: full pieces go up as raw uint8, zero host reshaping.
-            pad = jnp.asarray(_pad_block_for(piece_length))
             per_batch = max(1, self._sub_batch_bytes // piece_length)
             arr = np.frombuffer(view[: n_full * piece_length], dtype=np.uint8)
             arr = arr.reshape(n_full, piece_length)
-            for s in range(0, n_full, per_batch):
-                chunk = arr[s : s + per_batch]
-                g = len(chunk)
-                # Bucket the batch axis (pad rows, slice results) so a short
-                # final sub-batch doesn't trigger a fresh compile per blob
-                # size.
-                gb = min(per_batch, _next_pow2(g))
-                dispatched_rows += gb
-                if gb != g:
-                    chunk = np.concatenate(
-                        [chunk, np.zeros((gb - g, piece_length), dtype=np.uint8)]
-                    )
-                if self._use_pallas:
-                    from kraken_tpu.ops.sha256_pallas import hash_pieces_device
-
-                    outs.append(
-                        hash_pieces_device(jnp.asarray(chunk), piece_length)[:g]
-                    )
-                else:
-                    outs.append(
-                        _sha256_uniform(jnp.asarray(chunk), pad, piece_length // 64)[:g]
-                    )
+            # Bucket the batch axis (pad rows, slice results) so a short
+            # final sub-batch doesn't trigger a fresh compile per blob size.
+            chunks = [
+                arr[s : s + per_batch] for s in range(0, n_full, per_batch)
+            ]
+            buckets = [min(per_batch, _next_pow2(len(c))) for c in chunks]
+            if buckets[-1] != len(chunks[-1]):
+                chunks[-1] = np.concatenate([
+                    chunks[-1],
+                    np.zeros(
+                        (buckets[-1] - len(chunks[-1]), piece_length),
+                        dtype=np.uint8,
+                    ),
+                ])
+            if self._use_pallas:
+                kernel, rows = "sha256_tiles", sum(map(_tile_rows, buckets))
+            else:
+                kernel, rows = "sha256_uniform", sum(buckets)
+            # One section over every sub-batch: async dispatch overlaps the
+            # copy of batch i+1 with the compute of batch i, and the result
+            # is on the host only after the last.
+            with device_section(
+                "piece", kernel, rows=rows, blocks=sha_blocks(piece_length),
+                useful_blocks=n_full * sha_blocks(piece_length),
+                payload_bytes=n_full * piece_length,
+                shape=(tuple(sorted(set(buckets))), piece_length),
+            ):
+                # Only the last sub-batch is padded, at its end.
+                parts.append(_digest_bytes(
+                    self._enqueue_uniform(chunks, piece_length)
+                )[:n_full])
             tail = [view[i * piece_length : total] for i in range(n_full, n)]
         else:
             # Odd piece length: everything through the ragged path.
@@ -284,30 +296,37 @@ class JaxPieceHasher(PieceHasher):
             ]
 
         if tail:
-            tail_digests = self._hash_batch_raw(tail)
-            if outs:
-                out = np.concatenate(
-                    [_digest_bytes(jnp.concatenate(outs)), tail_digests]
-                )
-            else:
-                out = tail_digests
+            parts.append(self._hash_batch_raw(tail, "piece"))
+        _record_hash_metrics("tpu", total, n)
+        return np.concatenate(parts) if len(parts) > 1 else parts[0]
+
+    def _enqueue_uniform(
+        self, chunks: list[np.ndarray], piece_length: int
+    ) -> jax.Array:
+        """Enqueue every [rows, piece_length] sub-batch; [sum rows, 8]
+        digest words, still on the device."""
+        if self._use_pallas:
+            from kraken_tpu.ops.sha256_pallas import hash_pieces_device
+
+            outs = [
+                hash_pieces_device(jnp.asarray(c), piece_length) for c in chunks
+            ]
         else:
-            out = _digest_bytes(
-                jnp.concatenate(outs) if len(outs) > 1 else outs[0]
-            )
-        _record_hash_metrics(
-            "tpu", total, n, time.perf_counter() - start,
-            occupancy=(n_full / dispatched_rows) if dispatched_rows else 1.0,
-        )
-        return out
+            pad = jnp.asarray(_pad_block_for(piece_length))
+            outs = [
+                _sha256_uniform(jnp.asarray(c), pad, piece_length // 64)
+                for c in chunks
+            ]
+        return jnp.concatenate(outs) if len(outs) > 1 else outs[0]
 
     # -- arbitrary piece batch (agent verify hot loop) ---------------------
 
-    def hash_batch(self, pieces: list[bytes | memoryview]) -> np.ndarray:
+    def hash_batch(
+        self, pieces: list[bytes | memoryview], purpose: str = "verify"
+    ) -> np.ndarray:
         if not pieces:
             return np.empty((0, DIGEST_SIZE), dtype=np.uint8)
-        start = time.perf_counter()
-        out = self._hash_batch_raw(pieces)
+        out = self._hash_batch_raw(pieces, purpose)
         # The agent VERIFY loop is the other north-star hot path: a TPU
         # agent that never moves hasher_bytes_total{hasher="tpu"} is
         # indistinguishable from one silently verifying on the CPU
@@ -316,13 +335,12 @@ class JaxPieceHasher(PieceHasher):
         # tail through the raw variant and records the blob's FULL total
         # itself -- metrics here too would double-count the tail.
         _record_hash_metrics(
-            "tpu", sum(len(memoryview(p)) for p in pieces), len(pieces),
-            time.perf_counter() - start,
+            "tpu", sum(len(memoryview(p)) for p in pieces), len(pieces)
         )
         return out
 
     def _hash_uniform_groups(
-        self, views: list[memoryview], out: np.ndarray
+        self, views: list[memoryview], out: np.ndarray, purpose: str
     ) -> list[int]:
         """Hash every group of equal-length, piece-sized entries through
         the tile kernel; returns the indices left for the ragged scan.
@@ -356,18 +374,26 @@ class JaxPieceHasher(PieceHasher):
                 rows = np.zeros((min(gb, per_batch), ln), dtype=np.uint8)
                 for r, i in enumerate(group):
                     rows[r] = np.frombuffer(views[i], dtype=np.uint8)
-                out[group] = _digest_bytes(
-                    hash_pieces_device(jnp.asarray(rows), ln)
-                )[: len(group)]
+                with device_section(
+                    purpose, "sha256_tiles", rows=_tile_rows(len(rows)),
+                    blocks=sha_blocks(ln),
+                    useful_blocks=len(group) * sha_blocks(ln),
+                    payload_bytes=len(group) * ln,
+                ):
+                    out[group] = _digest_bytes(
+                        hash_pieces_device(jnp.asarray(rows), ln)
+                    )[: len(group)]
         return rest
 
-    def _hash_batch_raw(self, pieces: list[bytes | memoryview]) -> np.ndarray:
+    def _hash_batch_raw(
+        self, pieces: list[bytes | memoryview], purpose: str
+    ) -> np.ndarray:
         if not pieces:
             return np.empty((0, DIGEST_SIZE), dtype=np.uint8)
         views = [memoryview(p) for p in pieces]
         out = np.empty((len(views), DIGEST_SIZE), dtype=np.uint8)
         todo = (
-            self._hash_uniform_groups(views, out)
+            self._hash_uniform_groups(views, out, purpose)
             if self._use_pallas
             else list(range(len(views)))
         )
@@ -382,9 +408,9 @@ class JaxPieceHasher(PieceHasher):
             # (pow2(count) rows x largest-piece block bucket) stays within
             # the sub-batch budget; always take at least one piece.
             g = 1
-            b_bucket = _next_pow2((len(views[order[s]]) + 8) // 64 + 1)
+            b_bucket = _next_pow2(sha_blocks(len(views[order[s]])))
             while s + g < n:
-                nxt = _next_pow2((len(views[order[s + g]]) + 8) // 64 + 1)
+                nxt = _next_pow2(sha_blocks(len(views[order[s + g]])))
                 grown = max(b_bucket, nxt)
                 if _next_pow2(g + 1) * grown * 64 > self._sub_batch_bytes:
                     break
@@ -397,10 +423,15 @@ class JaxPieceHasher(PieceHasher):
             for i, idx in enumerate(group):
                 v = views[idx]
                 blocks[i] = _sha_pad_np(v, b_bucket)
-                nblocks[i] = (len(v) + 8) // 64 + 1
-            digests = _digest_bytes(
-                _sha256_ragged(jnp.asarray(blocks), jnp.asarray(nblocks))
-            )
+                nblocks[i] = sha_blocks(len(v))
+            with device_section(
+                purpose, "sha256_ragged", rows=gb, blocks=b_bucket,
+                useful_blocks=int(nblocks.sum()),
+                payload_bytes=sum(len(views[idx]) for idx in group),
+            ):
+                digests = _digest_bytes(
+                    _sha256_ragged(jnp.asarray(blocks), jnp.asarray(nblocks))
+                )
             for i, idx in enumerate(group):
                 out[idx] = digests[i]
             s += g

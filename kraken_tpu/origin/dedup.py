@@ -28,6 +28,7 @@ import time
 
 from kraken_tpu.core.digest import Digest
 from kraken_tpu.core.hasher import PieceHasher, get_hasher
+from kraken_tpu.core.ingest import STAGE_BUCKETS, timed_stage
 from kraken_tpu.core.metainfo import ChunkRecipe
 from kraken_tpu.ops.cdc import (
     CDCParams, chunk_host, chunk_spans, spans_from_cuts,
@@ -40,6 +41,21 @@ from kraken_tpu.ops.minhash import (
 )
 from kraken_tpu.store import CAStore, Metadata, register_metadata
 from kraken_tpu.utils.metrics import REGISTRY
+
+
+def _record_dedup_stage(stage: str, seconds: float) -> None:
+    REGISTRY.histogram(
+        "dedup_stage_seconds",
+        "Per-blob wall of each stage of the dedup pass (chunk, hash,"
+        " sketch, index): background work that shares the chip with the"
+        " acknowledged ingest path",
+        buckets=STAGE_BUCKETS,
+    ).observe(seconds, stage=stage)
+
+
+def _dedup_stage(stage: str) -> timed_stage:
+    return timed_stage(stage, _record_dedup_stage, plane="dedup")
+
 
 class ChunkRouter:
     """Routes a blob's CDC pass to the host C chunker or the device gear
@@ -309,10 +325,12 @@ class DedupIndex:
     def _compute_record(
         self, data: bytes | memoryview
     ) -> ChunkSketchMetadata:
-        spans = self._router.spans(data)
+        with _dedup_stage("chunk"):
+            spans = self._router.spans(data)
         view = memoryview(data)
         chunks = [view[s:e] for s, e in spans]
-        digests = self.hasher.hash_batch(chunks)  # batched TPU dispatch
+        with _dedup_stage("hash"):  # batched TPU dispatch
+            digests = self.hasher.hash_batch(chunks, purpose="chunk")
         # Per-chunk fp table keeps duplicates/order (sizes align 1:1);
         # the sketch uses the deduped 32-bit set.
         fps_all = (
@@ -320,7 +338,8 @@ class DedupIndex:
             .astype(np.uint64)
         )
         sizes = np.asarray([e - s for s, e in spans], dtype=np.uint32)
-        sketch = self.minhasher.sketch(fingerprints_from_digests(digests))
+        with _dedup_stage("sketch"):
+            sketch = self.minhasher.sketch(fingerprints_from_digests(digests))
         return ChunkSketchMetadata(sketch, fps_all, sizes)
 
     def _load_record(self, d: Digest) -> ChunkSketchMetadata | None:
@@ -392,8 +411,9 @@ class DedupIndex:
                 # beside a deleted blob.
                 raise DedupEvictionRace(d.hex)
             self.store.set_metadata(d, record)
-        self._admit(d, record)
-        self._evict_over_cap(keep=d.hex)
+        with _dedup_stage("index"):
+            self._admit(d, record)
+            self._evict_over_cap(keep=d.hex)
         return record
 
     def _evict_over_cap(self, keep: str) -> None:

@@ -88,7 +88,7 @@ class _UploadDigest:
     pool path when both are configured."""
 
     __slots__ = (
-        "_hash", "_pos", "_active", "_valid", "created", "hash_seconds",
+        "_hash", "_pos", "_active", "_valid", "created",
         "_plen", "_piece", "_piece_len", "_piece_digests",
         "_pool", "_parts", "_futs", "_ses", "_win", "_win_pos",
         "stage_walls", "namespace", "digest_hex",
@@ -99,7 +99,6 @@ class _UploadDigest:
         import time
 
         self.created = time.monotonic()
-        self.hash_seconds = 0.0  # cumulative time inside sha updates
         self._hash = hashlib.sha256()
         self._pos = 0
         self._active = False
@@ -204,9 +203,6 @@ class _UploadDigest:
         """Advance the hash state over ``chunk`` WITHOUT a spool write --
         the shared half of write_and_update, also the session-adoption
         replay (the bytes are already on disk; only the state is gone)."""
-        import time
-
-        t0 = time.perf_counter()
         self._hash.update(chunk)
         self._pos += len(chunk)
         if self._ses is not None:
@@ -216,7 +212,6 @@ class _UploadDigest:
             # chunks land in the next window. submit() blocking on
             # windows_in_flight is the stream's backpressure -- this
             # runs on the PATCH flush thread, off-loop.
-            self.hash_seconds += time.perf_counter() - t0
             view = memoryview(chunk)
             while view:
                 if self._win is None:
@@ -254,10 +249,6 @@ class _UploadDigest:
                             self._pool.submit(self._hash_parts, parts)
                         )
                     self._piece_len = 0
-        # hash_seconds = serial-digest time only, so the stream-pass
-        # gauge stays honest: the backpressure wait below is pool lag,
-        # not hashing, and must not be billed here.
-        self.hash_seconds += time.perf_counter() - t0
         if self._pool is not None:
             # Bound buffered bytes: block on the OLDEST possibly-
             # unfinished future (FIFO pool) so at most 2*workers
@@ -348,6 +339,7 @@ class _UploadDigest:
             digests = ses.finish()
             self.stage_walls = {
                 **ses.stage_seconds,
+                "queue": ses.queue_seconds,
                 "windows": ses.windows,
                 "overlap_ratio": round(ses.overlap_ratio(), 3),
             }
@@ -1056,6 +1048,7 @@ class OriginServer(LameduckMixin):
     async def _commit_inner(self, req: web.Request) -> web.Response:
         import time
 
+        from kraken_tpu.core.ingest import record_stage, timed_stage
         from kraken_tpu.utils import trace
 
         uid = req.match_info["uid"]
@@ -1080,10 +1073,14 @@ class OriginServer(LameduckMixin):
                     # outstanding pool futures and hashes the trailing
                     # partial piece inline -- tens of ms a stalled loop
                     # would charge to every other request and conn pump.
+                    # "join": what the client's commit waits for here --
+                    # the queue and the hash of the blob's last window.
+                    t_join = time.perf_counter()
                     piece_hashes = await asyncio.to_thread(
                         tracker.piece_hashes,
                         size, self.generator.piece_lengths.piece_length(size),
                     )
+                    record_stage("join", time.perf_counter() - t_join)
             early_metainfo = None
             if (
                 self.serve_while_ingest
@@ -1121,11 +1118,16 @@ class OriginServer(LameduckMixin):
             # the spool bytes, so they overlap the verify+rename below.
             # No-op (None) at the shipped write_quorum: 1.
             quorum_push = self._begin_quorum_push(ns, d, uid)
-            t_commit = time.perf_counter()
+
+            def commit_stage() -> float:
+                # Timed on the worker thread, where the profiler
+                # annotation can wrap exactly the verify + rename.
+                with timed_stage("commit") as stage:
+                    self.store.commit_upload(uid, d, precomputed=precomputed)
+                return stage.seconds
+
             try:
-                await asyncio.to_thread(
-                    self.store.commit_upload, uid, d, precomputed=precomputed
-                )
+                commit_s = await asyncio.to_thread(commit_stage)
             except UploadNotFoundError:
                 await self._abort_quorum_push(quorum_push)
                 await self._retract_early_publish(d, early_metainfo)
@@ -1143,10 +1145,6 @@ class OriginServer(LameduckMixin):
                 return web.Response(status=409, text="already cached")
             if early_metainfo is not None and self.scheduler is not None:
                 self.scheduler.promote_partial(d, self.store.cache_path(d))
-            from kraken_tpu.core.ingest import record_stage
-
-            commit_s = time.perf_counter() - t_commit
-            record_stage("commit", commit_s)
             sp.set(size=size, commit_s=round(commit_s, 6))
             if tracker is not None and tracker.stage_walls is not None:
                 sp.set(**{
@@ -1154,20 +1152,17 @@ class OriginServer(LameduckMixin):
                     for k, v in tracker.stage_walls.items()
                 })
             metainfo = early_metainfo
+            t_publish = time.perf_counter()
             if piece_hashes is not None:
                 if tracker.stage_walls is None:
                     # Stream-time piece hashes cover the final size at the
                     # final piece length: the MetaInfo is free, no re-read
-                    # pass. The north-star hasher gauges still move (the
-                    # stream path IS the piece-hash plane on cpu origins).
-                    # On hash_workers origins hash_seconds counts only the
-                    # stream thread's serial blob digest -- the honest
-                    # wall bound; piece hashing overlapped it on the pool.
+                    # pass. The hasher's counters still move (the stream
+                    # path IS the piece-hash plane on cpu origins).
                     # (Pipelined trackers already recorded theirs inside
                     # the pipeline, labeled by the device hasher.)
                     record_hash_metrics(
-                        "cpu", size, len(piece_hashes) // 32,
-                        tracker.hash_seconds,
+                        "cpu", size, len(piece_hashes) // 32
                     )
                 if metainfo is None:  # early publish already adopted
                     metainfo = await asyncio.to_thread(
@@ -1176,6 +1171,7 @@ class OriginServer(LameduckMixin):
                         piece_hashes,
                     )
             await self._post_commit(ns, d, metainfo=metainfo)
+            record_stage("publish", time.perf_counter() - t_publish)
             if quorum_push is not None:
                 # With write_quorum > 1 the 201 below is a DURABILITY
                 # ack, not a local-commit ack -- it waits until enough

@@ -18,7 +18,6 @@ from __future__ import annotations
 
 import functools
 import logging
-import time
 
 import jax
 import numpy as np
@@ -27,8 +26,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from kraken_tpu.core.hasher import (
     DIGEST_SIZE,
     PieceHasher,
+    device_section,
     record_hash_metrics,
     register_hasher,
+    sha_blocks,
 )
 from kraken_tpu.ops.sha256 import (
     _digest_bytes,
@@ -173,13 +174,8 @@ class ShardedPieceHasher(PieceHasher):
         self._fallback = JaxPieceHasher(use_pallas=use_pallas)
         self._dispatched = False
 
-    def device_info(self) -> dict:
-        devs = list(self._mesh.devices.flat)
-        return {
-            "platform": devs[0].platform,
-            "device_kind": devs[0].device_kind,
-            "count": len(devs),
-        }
+    def devices(self) -> list:
+        return list(self._mesh.devices.flat)
 
     def _hash_staged(self, staged: jax.Array, m: int, piece_length: int):
         if not self._dispatched:
@@ -193,11 +189,28 @@ class ShardedPieceHasher(PieceHasher):
                     for s in staged.addressable_shards
                 }},
             )
-        return _digest_bytes(
-            hash_sharded_staged(
-                self._mesh, staged, m, piece_length,
-                use_pallas=self._use_pallas, replicate=False,
+        with self._section("sha256_sharded", staged.shape[0], m, piece_length):
+            return _digest_bytes(
+                hash_sharded_staged(
+                    self._mesh, staged, m, piece_length,
+                    use_pallas=self._use_pallas, replicate=False,
+                )
             )
+
+    def _stage(self, arr: np.ndarray, piece_length: int):
+        n_dev = self._mesh.devices.size
+        m = arr.shape[0]
+        with self._section("device_put", m + (-m) % n_dev, m, piece_length):
+            return stage_sharded_pieces(self._mesh, arr, piece_length)
+
+    @staticmethod
+    def _section(kernel: str, rows: int, m: int, piece_length: int):
+        """A device section of the acknowledged path over ``m`` full
+        pieces dispatched as ``rows`` (the mesh's device quantum)."""
+        blocks = sha_blocks(piece_length)
+        return device_section(
+            "piece", kernel, rows=rows, blocks=blocks,
+            useful_blocks=m * blocks, payload_bytes=m * piece_length,
         )
 
     def hash_pieces(self, data, piece_length: int) -> np.ndarray:
@@ -209,32 +222,30 @@ class ShardedPieceHasher(PieceHasher):
             return np.empty((0, DIGEST_SIZE), dtype=np.uint8)
         if piece_length % 64:
             return self._fallback.hash_pieces(data, piece_length)
-        start = time.perf_counter()
         n_full = total // piece_length
         n = (total + piece_length - 1) // piece_length
         out = []
         if n_full:
             arr = np.frombuffer(view[: n_full * piece_length], dtype=np.uint8)
-            staged, m = stage_sharded_pieces(
-                self._mesh, arr.reshape(n_full, piece_length), piece_length
+            staged, m = self._stage(
+                arr.reshape(n_full, piece_length), piece_length
             )
             out.append(self._hash_staged(staged, m, piece_length))
         if n > n_full:  # ragged tail piece (raw: this call records the
             # blob's FULL total below -- the metric-wrapping hash_batch
             # would double-count the tail bytes under hasher="tpu")
             out.append(
-                self._fallback._hash_batch_raw([view[n_full * piece_length :]])
+                self._fallback._hash_batch_raw(
+                    [view[n_full * piece_length :]], "piece"
+                )
             )
-        # Same north-star gauges as the single-chip hashers (GB/s,
-        # occupancy) -- a sharded origin must not go dark on dashboards.
-        record_hash_metrics(
-            self.name, total, n, time.perf_counter() - start,
-            occupancy=1.0,
-        )
+        # Same counters as the single-chip hashers -- a sharded origin
+        # must not go dark on dashboards.
+        record_hash_metrics(self.name, total, n)
         return np.concatenate(out) if len(out) > 1 else out[0]
 
-    def hash_batch(self, pieces) -> np.ndarray:
-        return self._fallback.hash_batch(pieces)
+    def hash_batch(self, pieces, purpose: str = "verify") -> np.ndarray:
+        return self._fallback.hash_batch(pieces, purpose)
 
     # -- staged-window protocol (core/ingest.py pipeline) ----------------
     # stage_window/hash_staged_window split hash_pieces at the host->
@@ -246,18 +257,14 @@ class ShardedPieceHasher(PieceHasher):
     def stage_window(self, arr: np.ndarray, piece_length: int):
         """Transfer one UNIFORM window ([M, piece_length] uint8, every row
         a full piece) to the mesh. Returns an opaque staged handle."""
-        staged, m = stage_sharded_pieces(self._mesh, arr, piece_length)
+        staged, m = self._stage(arr, piece_length)
         return (staged, m, piece_length)
 
     def hash_staged_window(self, handle) -> np.ndarray:
         """Hash a :meth:`stage_window` handle -> [M, 32] uint8 digests."""
         staged, m, piece_length = handle
-        start = time.perf_counter()
         out = self._hash_staged(staged, m, piece_length)
-        record_hash_metrics(
-            self.name, m * piece_length, m, time.perf_counter() - start,
-            occupancy=1.0,
-        )
+        record_hash_metrics(self.name, m * piece_length, m)
         return out
 
 

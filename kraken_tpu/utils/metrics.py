@@ -6,16 +6,19 @@ unverified; SURVEY.md SS2.4/SS5), rebuilt stdlib-only (no prometheus
 client in the image): a tiny typed registry rendering the Prometheus
 exposition format at ``GET /metrics`` on every component.
 
-The north-star gauges live here too: the SHA plane reports GB/s and
-batch occupancy per dispatch (SURVEY.md SS6 -- "GB/s/chip and
-batch-occupancy gauges ... are the north-star metric").
+The SHA plane's north-star numbers (SURVEY.md SS6: GB/s/chip and batch
+occupancy) derive from counters here: ``hasher_bytes_total`` and the
+device-section ledger's ``hasher_device_*`` (core/hasher.py).
 """
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
-from typing import Iterable
+from typing import Callable, Iterable
+
+_log = logging.getLogger("kraken.metrics")
 
 # One jax.profiler capture at a time, process-wide (the profiler itself
 # is global state).
@@ -68,6 +71,11 @@ class Counter(_Metric):
     def value(self, **labels: str) -> float:
         with self._lock:
             return self._values.get(self._key(labels), 0.0)
+
+    def total(self) -> float:
+        """Sum over every label set."""
+        with self._lock:
+            return sum(self._values.values())
 
     def render(self, exemplars: bool = False) -> Iterable[str]:
         with self._lock:  # snapshot: writers mutate from worker threads
@@ -178,6 +186,15 @@ class Registry:
     def __init__(self):
         self._metrics: dict[str, _Metric] = {}
         self._lock = threading.Lock()
+        self._scrape_hooks: list[Callable[[], None]] = []
+
+    def add_scrape_hook(self, hook: Callable[[], None]) -> None:
+        """Run ``hook`` at the start of every :meth:`render`: for gauges
+        whose reading costs a call (a device's ``memory_stats()``) and
+        must stay off the hot path. A hook that raises is logged and the
+        scrape goes on."""
+        with self._lock:
+            self._scrape_hooks.append(hook)
 
     def _get(self, cls, name: str, help_: str, **kw):
         with self._lock:
@@ -209,6 +226,13 @@ class Registry:
         scrape). Only emitted when the scraper negotiated OpenMetrics
         (classic text parsers reject in-line exemplars; see the Accept
         handling in instrument_app)."""
+        with self._lock:
+            hooks = list(self._scrape_hooks)
+        for hook in hooks:
+            try:
+                hook()
+            except Exception:  # kt-lint: disable=bare-except  # a gauge that cannot be read must not fail the scrape of every other metric
+                _log.warning("metrics scrape hook failed", exc_info=True)
         with self._lock:  # registration happens from worker threads too
             metrics = [self._metrics[n] for n in sorted(self._metrics)]
         lines: list[str] = []
@@ -506,7 +530,15 @@ def instrument_app(app, component: str, registry: Registry = REGISTRY):
         # profiler is process-global. ?dir= must resolve under the
         # capture root (KRAKEN_PROFILE_DIR or the system tempdir): this
         # is a debug mux, but it must not be a write-anywhere primitive.
+        #
+        # Defaults are for a LOADED server: the Python tracer is off
+        # (?python_tracer=1 turns it on), the host tracer records
+        # TraceAnnotations only (?host_tracer=N), and the device tracer
+        # keeps XLA's modules and operations. stop_trace serializes some
+        # 120 us an event and the ragged scan emits 0.8-3 M events a busy
+        # second (PERF.md section 3): ask for tenths of a second there.
         import asyncio
+        import glob
         import os
         import tempfile
 
@@ -514,14 +546,20 @@ def instrument_app(app, component: str, registry: Registry = REGISTRY):
             import jax
         except Exception:  # pragma: no cover - jax is a hard dep in prod
             return web.Response(status=501, text="jax unavailable")
+        query = request.query
         try:
-            seconds = min(60.0, max(0.1, float(request.query.get("seconds", 2))))
+            seconds = min(60.0, max(0.1, float(query.get("seconds", 2))))
+            python_tracer = int(query.get("python_tracer", 0))
+            host_tracer = int(query.get("host_tracer", 1))
         except ValueError:
-            return web.Response(status=400, text="malformed seconds")
+            return web.Response(
+                status=400,
+                text="malformed seconds, python_tracer or host_tracer",
+            )
         root = os.path.realpath(
             os.environ.get("KRAKEN_PROFILE_DIR") or tempfile.gettempdir()
         )
-        requested = request.query.get("dir")
+        requested = query.get("dir")
         if requested:
             out_dir = os.path.realpath(requested)
             if os.path.commonpath([out_dir, root]) != root:
@@ -533,19 +571,47 @@ def instrument_app(app, component: str, registry: Registry = REGISTRY):
             # One fixed parent, reused: jax writes a timestamped subtree
             # per capture, and a single parent keeps cleanup one rm -rf.
             out_dir = os.path.join(root, "kraken-jaxprof")
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = python_tracer
+        options.host_tracer_level = host_tracer
+        options.advanced_configuration = {"tpu_trace_mode": "TRACE_ONLY_XLA"}
+        from kraken_tpu.core.hasher import DEVICE_LEDGER
+
+        def mark(name: str) -> dict:
+            # The names benchmark/reduce_trace.py windows on; the held
+            # reading beside each is how the section ledger's estimate
+            # is checked against the device's own busy time.
+            with jax.profiler.TraceAnnotation(name):
+                return {"t": time.monotonic(),
+                        "held": DEVICE_LEDGER.held_seconds()}
+
         if not _profile_lock.acquire(blocking=False):
             return web.Response(status=409, text="capture already running")
         lock_deferred = False
+        doc = {
+            "trace_dir": out_dir, "python_tracer": python_tracer,
+            "host_tracer": host_tracer,
+        }
         try:
             # start/stop serialize the XPlane tree -- off the loop, and
             # stop_trace MUST run even if the client disconnects mid-
             # sleep (cancellation between start and stop would leave the
             # process-global profiler running forever, failing every
             # later capture).
-            await asyncio.to_thread(jax.profiler.start_trace, out_dir)
+            await asyncio.to_thread(
+                jax.profiler.start_trace, out_dir, profiler_options=options
+            )
             try:
+                opened = mark("bench_trace_open")
                 await asyncio.sleep(seconds)
+                closed = mark("bench_trace_close")
+                doc.update(
+                    t_open=opened["t"], t_close=closed["t"],
+                    seconds=closed["t"] - opened["t"],
+                    held_s=closed["held"] - opened["held"],
+                )
             finally:
+                t_stop = time.monotonic()
                 stop = asyncio.ensure_future(
                     asyncio.to_thread(jax.profiler.stop_trace)
                 )
@@ -564,10 +630,20 @@ def instrument_app(app, component: str, registry: Registry = REGISTRY):
                         lambda _f: _profile_lock.release()
                     )
                     raise
+                doc["stop_trace_s"] = time.monotonic() - t_stop
         finally:
             if not lock_deferred:
                 _profile_lock.release()
-        return web.json_response({"trace_dir": out_dir, "seconds": seconds})
+        found = sorted(
+            glob.glob(os.path.join(
+                out_dir, "plugins", "profile", "*", "*.xplane.pb"
+            )),
+            key=os.path.getmtime,
+        )
+        if found:
+            doc["xplane"] = found[-1]
+            doc["xplane_bytes"] = os.path.getsize(found[-1])
+        return web.json_response(doc)
 
     async def pprof_profile_endpoint(request):
         # The always-on sampling profiler's ring (utils/profiler.py):

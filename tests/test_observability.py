@@ -112,7 +112,9 @@ async def _drive_metrics_herd(tmp_path):
 
         # North-star hasher gauges moved (metainfo-gen hashed the layers).
         assert REGISTRY.counter("hasher_bytes_total").value(hasher="cpu") > 0
-        assert "hasher_last_gbps" in origin_text
+        # ... through device sections: who held the hasher, for how long.
+        assert 'hasher_device_sections_total{kernel="hashlib"' in origin_text
+        assert REGISTRY.counter("hasher_device_held_seconds_total").total() > 0
         # Agent verify plane counted the swarm pieces.
         assert REGISTRY.counter("verify_pieces_total").value() > 0
     finally:
@@ -306,7 +308,7 @@ def test_jax_profile_lock_survives_client_disconnect(monkeypatch):
     release = threading.Event()
     stopped = threading.Event()
     monkeypatch.setattr(
-        jax.profiler, "start_trace", lambda out_dir: started.set()
+        jax.profiler, "start_trace", lambda out_dir, **kw: started.set()
     )
 
     def slow_stop():
@@ -375,14 +377,44 @@ def test_debug_jax_profile_endpoint(tmp_path):
         await tracker.start()
         try:
             out = str(tmp_path / "trace")
+
+            async def hash_mid_capture():
+                # A device section that starts inside the capture.
+                from kraken_tpu.core.hasher import CPUPieceHasher
+
+                await asyncio.sleep(0.1)
+                await asyncio.to_thread(
+                    CPUPieceHasher().hash_batch, [b"x" * 1000], "chunk"
+                )
+
             async with aiohttp.ClientSession() as http:
+                section = asyncio.create_task(hash_mid_capture())
                 async with http.get(
                     f"http://{tracker.addr}/debug/jax-profile",
                     params={"seconds": "0.3", "dir": out},
                 ) as r:
                     assert r.status == 200, await r.text()
                     body = await r.json()
+                await section
             assert body["trace_dir"] == out
+            # Defaults for a loaded server, and what the capture cost.
+            assert (body["python_tracer"], body["host_tracer"]) == (0, 1)
+            assert 0.3 <= body["seconds"] < 5
+            assert body["t_close"] - body["t_open"] == body["seconds"]
+            assert body["held_s"] >= 0 and body["stop_trace_s"] > 0
+            assert body["xplane_bytes"] == os.path.getsize(body["xplane"])
+            # The profiler's own file carries the stretch's two marks
+            # (benchmark/reduce_trace.py windows on them) and names whose
+            # section ran inside it.
+            from jax.profiler import ProfileData
+
+            names = {
+                e.name
+                for plane in ProfileData.from_file(body["xplane"]).planes
+                for line in plane.lines for e in line.events
+            }
+            assert {"bench_trace_open", "bench_trace_close",
+                    "kraken.device.chunk.hashlib"} <= names
             # A plugins/profile/<ts>/*.xplane.pb tree appears.
             found = [
                 p for p in __import__("pathlib").Path(out).rglob("*")
