@@ -781,7 +781,7 @@ def child_dedup(args) -> int:
         for c in chunks
     ])
     # An agent's verify batch: equal-length pieces, which on the chip take
-    # the tile kernel and not the ragged scan the chunks above took.
+    # the uniform tile kernel and not the ragged one the chunks above took.
     plen = 1 * MIB if args.tiny else 4 * MIB
     pieces = [memoryview(buf)[i * plen:(i + 1) * plen] for i in range(3)]
     t0 = time.monotonic()

@@ -20,6 +20,13 @@ of batch i+1 with the compute of batch i.
 
 Shapes are bucketed (N and B rounded up to powers of two) so the jit cache
 stays small across varying blob sizes.
+
+That scan is the portable path: what runs on the CPU backend. On an
+accelerator the same rows go to the Pallas kernels of
+:mod:`kraken_tpu.ops.sha256_pallas` (``JaxPieceHasher`` chooses from
+``jax.default_backend()``): full pieces to the uniform tile kernel, every
+other row to the ragged tile kernel, because one scan iteration is some
+170 device ops whatever it hashes.
 """
 
 from __future__ import annotations
@@ -187,30 +194,52 @@ def _pack_be_u32_np(b: np.ndarray) -> np.ndarray:
 
 
 # Equal-length entries of a hash_batch at least this long go to the tile
-# kernel (JaxPieceHasher._hash_uniform_groups). Torrent pieces are 4 MiB
-# and up; CDC chunks (<= 256 KiB, ops/cdc.py) stay on the ragged scan,
-# which hashes them in milliseconds and needs no compile per length.
+# kernel (JaxPieceHasher._hash_uniform_groups), which compiles once per
+# length. Torrent pieces are 4 MiB and up; everything shorter or of an odd
+# length (CDC chunks <= 256 KiB, tails, blobs under a piece) goes to the
+# ragged tile kernel, whose compiled shape holds no length.
 _TILE_KERNEL_MIN_BYTES = 1 << 20
+
+# Rows left for the ragged tile kernel go out as one tile of 1024 lanes
+# when their blocks fill at least this many lanes of it over its whole
+# block axis (the longest row's); otherwise the longest goes alone, a
+# chain of its own, and the rest are asked again. The kernel's time does
+# not depend on how many lanes are filled, the copy's does: a tile call
+# ships 1024 rows' bytes whatever it holds, 9.1-9.6 us a block on the v5e
+# against 1.0-1.1 us a block for one row's own chain (PERF.md SS5, PR 26
+# sweep), so a tile pays from eight to nine lanes' worth of real blocks.
+_TILE_MIN_LANES = 8
+
+
+def _round_up(x: int, multiple: int) -> int:
+    return -(-x // multiple) * multiple
 
 
 def _tile_rows(rows: int) -> int:
     """Lanes the tile kernel's grid covers for ``rows`` dispatched rows:
     whole tiles of 1024 (``sha256_pallas.N_TILE``), filled or not."""
-    return -(-rows // 1024) * 1024
+    return _round_up(rows, 1024)
+
+
+def _sha_pad_into(row: np.ndarray, piece: memoryview) -> None:
+    """SHA-pad one piece into the flat uint8 ``row``: its bytes, 0x80,
+    zeros, the bit length. Bytes of ``row`` past the piece's own blocks
+    are left as they were."""
+    ln = len(piece)
+    end = sha_blocks(ln) * 64
+    assert end <= len(row)
+    row[:ln] = np.frombuffer(piece, dtype=np.uint8)
+    row[ln] = 0x80
+    row[ln + 1 : end - 8] = 0
+    row[end - 8 : end] = np.frombuffer(
+        (ln * 8).to_bytes(8, "big"), dtype=np.uint8
+    )
 
 
 def _sha_pad_np(piece: memoryview, nblocks_out: int) -> np.ndarray:
     """SHA-pad one piece into [nblocks_out, 64] uint8 (zero-filled beyond)."""
-    ln = len(piece)
-    need = sha_blocks(ln)
-    assert need <= nblocks_out
     out = np.zeros((nblocks_out, 64), dtype=np.uint8)
-    flat = out.reshape(-1)
-    flat[:ln] = np.frombuffer(piece, dtype=np.uint8)
-    flat[ln] = 0x80
-    flat[need * 64 - 8 : need * 64] = np.frombuffer(
-        (ln * 8).to_bytes(8, "big"), dtype=np.uint8
-    )
+    _sha_pad_into(out.reshape(-1), piece)
     return out
 
 
@@ -385,6 +414,56 @@ class JaxPieceHasher(PieceHasher):
                     )[: len(group)]
         return rest
 
+    def _hash_ragged_tiles(
+        self, views: list[memoryview], todo: list[int], out: np.ndarray,
+        purpose: str,
+    ) -> None:
+        """Hash ``views[i]`` for i in ``todo`` -- any lengths, any count --
+        through the ragged tile kernel, digests into ``out[i]``.
+
+        The XLA scan these rows used to take launches ~170 device ops for
+        every 64-byte block (199 us a block at one row on the v5e, PERF.md
+        SS5); this kernel runs the chain inside one call per slab of the
+        block axis, and its two compiled shapes serve every length."""
+        from kraken_tpu.ops.sha256_pallas import (
+            RAGGED_ROW_SHAPE,
+            RAGGED_TILE_SHAPE,
+            sha256_ragged_tiles,
+        )
+
+        # Longest first: a tile's block axis is its longest row's.
+        order = sorted(todo, key=lambda i: -len(views[i]))
+        nblocks = np.array(
+            [sha_blocks(len(views[i])) for i in order], dtype=np.int32
+        )
+        upto = np.concatenate(([0], np.cumsum(nblocks, dtype=np.int64)))
+        n = len(order)
+        s = 0
+        while s < n:
+            lanes, slab = RAGGED_TILE_SHAPE
+            axis = _round_up(int(nblocks[s]), slab)
+            # As many rows as the tile has lanes and the sub-batch budget
+            # has room for, staged on the host at the longest one's length.
+            g = min(lanes, n - s, self._sub_batch_bytes // (axis * 64))
+            if upto[s + g] - upto[s] < _TILE_MIN_LANES * axis:
+                (lanes, slab), g = RAGGED_ROW_SHAPE, 1
+                axis = _round_up(int(nblocks[s]), slab)
+            group = order[s : s + g]
+            # Bytes past a row's own blocks are never folded: no memset.
+            rows = np.empty((g, axis * 64), dtype=np.uint8)
+            for row, idx in zip(rows, group):
+                _sha_pad_into(row, views[idx])
+            with device_section(
+                purpose, "sha256_ragged_tiles", rows=lanes, blocks=axis,
+                useful_blocks=int(upto[s + g] - upto[s]),
+                payload_bytes=sum(len(views[idx]) for idx in group),
+                shape=(lanes, slab),
+            ):
+                out[group] = _digest_bytes(sha256_ragged_tiles(
+                    rows, nblocks[s : s + g], (lanes, slab)
+                ))
+            s += g
+
     def _hash_batch_raw(
         self, pieces: list[bytes | memoryview], purpose: str
     ) -> np.ndarray:
@@ -392,11 +471,13 @@ class JaxPieceHasher(PieceHasher):
             return np.empty((0, DIGEST_SIZE), dtype=np.uint8)
         views = [memoryview(p) for p in pieces]
         out = np.empty((len(views), DIGEST_SIZE), dtype=np.uint8)
-        todo = (
-            self._hash_uniform_groups(views, out, purpose)
-            if self._use_pallas
-            else list(range(len(views)))
-        )
+        if self._use_pallas:
+            self._hash_ragged_tiles(
+                views, self._hash_uniform_groups(views, out, purpose),
+                out, purpose,
+            )
+            return out
+        todo = list(range(len(views)))
         # Sort by size so one large piece doesn't force the whole batch to
         # its block count -- each sub-batch group buckets to its own max.
         order = sorted(todo, key=lambda i: len(views[i]))

@@ -21,7 +21,17 @@ All parallelism is cross-piece: SHA-256's chain serializes blocks within a
 piece, so pieces are the batch axis and the block axis is the grid's inner
 sequential dimension.
 
-Two input layouts (PERF.md has the measured analysis, v5e 2026-07-29):
+Three kernels, all on [8, 128] lanes with the same rounds (``_rounds64``);
+a block below is one 64-byte step of every lane of a tile, v5e:
+
+| kernel | takes | measured |
+|---|---|---|
+| ``sha256_tiles`` | equal-length rows, natural bytes; chain length and padding block compiled in (a Mosaic compile per length) | ~75 GB/s a full tile = 0.87 us a block (r3, 2026-07-29); one 4 MiB piece a dispatch 60 ms = 0.92 us a block (PR 24); a 16 x 4 MiB window 74 ms, copy included = 1.13 us a block (PR 21) |
+| ``sha256_packed_tiles`` | the same, pre-packed word-major tiles | ~92 GB/s = 0.71 us a block (r3, 2026-07-29) |
+| ``sha256_ragged_slab`` (``sha256_ragged_tiles`` drives it) | SHA-padded rows of any lengths, a block count a lane, the state carried from call to call: two compiled shapes for every length and row count | one row, 512 blocks a call: 1.01-1.09 us a block from 1 to 4 MiB, copy, dispatch and read-back included, and ~1.5 ms a chain before the first block; a tile of 1024 rows, 64 blocks a call: 9.1-9.6 us a block, bound by the copy of 64 KiB a block (PR 26, 2026-10-01; the XLA scan it replaced: 199-211 us a block at one row, 30 at 8-16) |
+
+The two uniform kernels' input layouts (docs/PERF_HISTORY.md has the
+measured analysis, v5e 2026-07-29):
 
 - **natural** ``[M, piece_len] uint8`` -- what the store hands over. The
   kernel transposes each [N_TILE, _KB*64] BYTE slab in VMEM (u8
@@ -286,6 +296,170 @@ def sha256_packed_tiles(
         out_shape=jax.ShapeDtypeStruct((t, 8, _SUB, _LANES), jnp.uint32),
     )(packed)
     return out.reshape(t, 8, N_TILE).transpose(0, 2, 1).reshape(t * N_TILE, 8)
+
+
+# -- ragged rows: any length, any row count, one compiled shape ------------
+#
+# sha256_tiles bakes the chain length and the padding block into the
+# kernel, so every distinct length is a Mosaic compile (~9 s on the v5e
+# plus the Python tracing). Short and odd-length rows -- blobs under a
+# piece, tails, CDC chunks -- cannot pay that, and the XLA scan they used
+# instead launches ~170 device ops for every 64-byte block. The ragged
+# kernel takes rows the host has already SHA-padded, a block count for
+# every lane and the running state as an input AND an output: a chain of
+# any length is a sequence of calls of ONE fixed extent over successive
+# slabs of the block axis. Its compile key is (lanes, slab): neither the
+# row count nor the length.
+
+# The two shapes the served path dispatches: one row a call (a blob or a
+# tail is a chain of its own; nothing but its own bytes crosses to the
+# device), or a whole tile of 1024 rows (CDC chunks of a large blob; rows
+# the batch lacks ride as uninitialised lanes with a block count of 0).
+# (lanes, blocks a call): 32 KiB of one row, or 4 KiB of each of 1024.
+RAGGED_ROW_SHAPE = (1, 512)
+RAGGED_TILE_SHAPE = (N_TILE, 64)
+
+
+def _ragged_kernel(scal_ref, nblk_ref, blk_ref, state_ref, out_ref, w_ref):
+    """One _KB-block group of one slab. scal_ref: SMEM [2] int32 = (chain
+    index of the slab's first block, longest lane's block count);
+    nblk_ref: [_SUB, _LANES] int32 block count per lane; blk_ref: natural
+    [N_TILE, _KB*64] uint8 slab (rows past the array's ride the edge block
+    as in sha256_tiles); state_ref/out_ref: [8, _SUB, _LANES] uint32, one
+    HBM buffer (aliased), out_ref revisited across the grid so the running
+    state stays in VMEM; w_ref: [_KB*16, _SUB, _LANES] uint32 scratch."""
+    g = pl.program_id(0)
+
+    @pl.when(g == 0)
+    def _load():
+        out_ref[...] = state_ref[...]
+
+    first = scal_ref[0] + g * _KB
+
+    # A group past the longest lane's chain does nothing: the block axis
+    # is rounded up to the slab, and a 1 KiB row pays for 3 groups of it.
+    @pl.when(first < scal_ref[1])
+    def _fold():
+        # The same u8 transpose + byte-plane combine as the natural tile
+        # kernel, parked in VMEM so the block loop below can be ROLLED:
+        # one traced compression instead of _KB, an eighth of the Python
+        # tracing and of Mosaic's work on every start.
+        t8 = jnp.transpose(blk_ref[...], (1, 0)).reshape(
+            _KB, 16, 4, _SUB, _LANES
+        )
+        for kb in range(_KB):
+            for j in range(16):
+                b0 = t8[kb, j, 0].astype(jnp.uint32)
+                b1 = t8[kb, j, 1].astype(jnp.uint32)
+                b2 = t8[kb, j, 2].astype(jnp.uint32)
+                b3 = t8[kb, j, 3].astype(jnp.uint32)
+                w_ref[kb * 16 + j] = (
+                    (b0 << np.uint32(24))
+                    | (b1 << np.uint32(16))
+                    | (b2 << np.uint32(8))
+                    | b3
+                )
+        nblk = nblk_ref[...]
+
+        def block(kb, state):
+            new = _rounds64(list(state), lambda j: w_ref[kb * 16 + j])
+            # A lane past its own count keeps its state.
+            live = first + kb < nblk
+            return tuple(jnp.where(live, n, s) for n, s in zip(new, state))
+
+        state = jax.lax.fori_loop(
+            0, _KB, block, tuple(out_ref[i] for i in range(8))
+        )
+        for i in range(8):
+            out_ref[i] = state[i]
+
+
+@functools.partial(
+    jax.jit, static_argnames=("interpret",), donate_argnames=("state",)
+)
+def sha256_ragged_slab(
+    state: jax.Array,
+    data_u8: jax.Array,
+    nblocks: jax.Array,
+    scalars: jax.Array,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """Fold one slab of the block axis into the running state.
+
+    state: [8, _SUB, _LANES] uint32 (donated; lane r = row r); data_u8:
+    [R, S*64] uint8, R <= N_TILE SHA-padded rows' bytes for chain blocks
+    [first, first + S), S a multiple of _KB; nblocks: [_SUB, _LANES] int32
+    per-lane block count (0 for a lane with no row); scalars: [2] int32 =
+    (first, max(nblocks)). Returns the new state.
+    """
+    interpret = _resolve_interpret(interpret)
+    groups = data_u8.shape[1] // (_KB * 64)
+    return pl.pallas_call(
+        _ragged_kernel,
+        interpret=interpret,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(groups,),
+            in_specs=[
+                pl.BlockSpec(
+                    (_SUB, _LANES), lambda g, s: (0, 0),
+                    memory_space=pltpu.VMEM,
+                ),
+                pl.BlockSpec(
+                    (N_TILE, _KB * 64), lambda g, s: (0, g),
+                    memory_space=pltpu.VMEM,
+                ),
+                pl.BlockSpec(
+                    (8, _SUB, _LANES), lambda g, s: (0, 0, 0),
+                    memory_space=pltpu.VMEM,
+                ),
+            ],
+            out_specs=pl.BlockSpec(
+                (8, _SUB, _LANES), lambda g, s: (0, 0, 0),
+                memory_space=pltpu.VMEM,
+            ),
+            scratch_shapes=[pltpu.VMEM((_KB * 16, _SUB, _LANES), jnp.uint32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((8, _SUB, _LANES), jnp.uint32),
+        input_output_aliases={3: 0},
+    )(scalars, nblocks, data_u8, state)
+
+
+def sha256_ragged_tiles(
+    rows_u8: np.ndarray,
+    nblocks: np.ndarray,
+    shape: tuple[int, int],
+    interpret: bool | None = None,
+) -> np.ndarray:
+    """Hash n <= lanes SHA-padded rows of any lengths as one chain of slab
+    calls of the compiled ``shape`` = (lanes, slab blocks).
+
+    rows_u8: [n, B*64] uint8 host array, each row SHA-padded (0x80, zeros,
+    bit length) and zero- or garbage-filled past its own blocks, B a
+    multiple of the slab; nblocks: [n] block count per row. Every slab is
+    enqueued without waiting; the one wait is the read of the final state.
+    Returns [n, 8] uint32 digest words.
+    """
+    lanes, slab = shape
+    n, width = rows_u8.shape
+    assert 0 < n <= lanes and width % (slab * 64) == 0
+    longest = int(nblocks.max())
+    per_lane = np.zeros(N_TILE, dtype=np.int32)
+    per_lane[:n] = nblocks
+    nblk = jax.device_put(per_lane.reshape(_SUB, _LANES))
+    state = np.repeat(_H0, N_TILE).reshape(8, _SUB, _LANES)
+    for first in range(0, longest, slab):
+        data = rows_u8[:, first * 64 : (first + slab) * 64]
+        if n < lanes:
+            # Lanes without a row are never read (count 0): no memset.
+            tile = np.empty((lanes, slab * 64), dtype=np.uint8)
+            tile[:n] = data
+            data = tile
+        state = sha256_ragged_slab(
+            state, data, nblk, np.array([first, longest], dtype=np.int32),
+            interpret=interpret,
+        )
+    return np.asarray(state).reshape(8, N_TILE).T[:n]
 
 
 def packed_nb(unpadded_blocks: int) -> int:

@@ -91,6 +91,82 @@ def test_tile_kernel_fits_the_chip_at_shipped_batches(one_chip, rows, piece_mib)
     assert mem.argument_size_in_bytes <= 2 * rows * plen, mem
 
 
+def _ragged_slab_shapes(lanes, slab, sharding=None):
+    """What ``sha256_ragged_slab`` is handed at the compiled shape
+    (lanes, slab blocks): state, data, per-lane counts, scalars."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return (
+        sds((8, 8, 128), jnp.uint32), sds((lanes, slab * 64), jnp.uint8),
+        sds((8, 128), jnp.int32), sds((2,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("shape", ["RAGGED_ROW_SHAPE", "RAGGED_TILE_SHAPE"])
+def test_ragged_tile_kernel_fits_the_chip_at_each_compiled_shape(
+    one_chip, shape
+):
+    """The ragged tile kernel at the two shapes the served path
+    dispatches (one row, or a tile of 1024, a slab of the block axis a
+    call). Device memory is the slab, the state and the counts: the
+    chain's length is not in the program."""
+    from kraken_tpu.ops import sha256_pallas
+
+    lanes, slab = getattr(sha256_pallas, shape)
+    compiled = sha256_pallas.sha256_ragged_slab.lower(
+        *_ragged_slab_shapes(lanes, slab, one_chip), interpret=False
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    # A one-row u8 slab is laid out in tiles of several rows on the device.
+    slab_bytes = max(lanes, 32) * slab * 64
+    assert mem.temp_size_in_bytes <= slab_bytes, mem
+    assert mem.argument_size_in_bytes <= 2 * slab_bytes + (64 << 10), mem
+    assert mem.alias_size_in_bytes == 8 * 8 * 128 * 4, mem  # state in place
+
+
+def test_ragged_batches_of_any_length_and_row_count_share_two_programs(
+    monkeypatch,
+):
+    """The compile key: two batches that differ in every length and in
+    their row counts hand ``sha256_ragged_slab`` the same shapes, and
+    those are the two compiled above. A shape met first under load is
+    a stall of seconds with the GIL held (PERF.md SS6, PR 21)."""
+    import os
+
+    import numpy as np
+
+    from kraken_tpu.ops import sha256 as plane
+    from kraken_tpu.ops import sha256_pallas
+
+    seen = []
+
+    def record(state, data, nblocks, scalars, interpret=None):
+        seen[-1].add(tuple(
+            (tuple(a.shape), np.dtype(a.dtype).name)
+            for a in (state, data, nblocks, scalars)
+        ))
+        return state
+
+    monkeypatch.setattr(sha256_pallas, "sha256_ragged_slab", record)
+    h = plane.JaxPieceHasher(use_pallas=True)
+    for lengths in (
+        [1, 70_000, (1 << 20) + 3] + [5000 + 37 * i for i in range(40)],
+        [0, 33, (1 << 22) + 1] + [10_001 + 200 * i for i in range(200)],
+    ):
+        seen.append(set())
+        h.hash_batch([os.urandom(n) for n in lengths])
+    want = {
+        tuple((s.shape, np.dtype(s.dtype).name)
+              for s in _ragged_slab_shapes(lanes, slab))
+        for lanes, slab in (
+            sha256_pallas.RAGGED_ROW_SHAPE, sha256_pallas.RAGGED_TILE_SHAPE
+        )
+    }
+    assert seen[0] == seen[1] == want
+
+
 def test_ragged_scan_compiles_at_a_verify_batch(one_chip):
     """The ragged scan at 16 rows of (4 MiB + SHA padding), block count
     bucketed to the next power of two (ops/sha256.py _hash_batch_raw):

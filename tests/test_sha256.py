@@ -120,15 +120,15 @@ def test_hash_batch_mixed_sizes_bounded_memory():
     [(64, [(1, 8192), (16, 4096)]), (4, [(1, 4096), (1, 8192), (4, 4096)])],
 )
 def test_hash_batch_sends_piece_sized_uniform_groups_to_the_tile_kernel(
-    monkeypatch, sub_batch_pieces, want_shapes
+    monkeypatch, ragged_kernel, sub_batch_pieces, want_shapes
 ):
     """On an accelerator (``use_pallas``) the equal-length, piece-sized
     entries of a hash_batch -- an agent's verify batch -- go through the
     tile kernel in rows bucketed to powers of four and bounded by the
-    sub-batch budget; everything else stays on the ragged scan, and every
-    digest lands on its own row. The kernel itself only runs on the chip
-    (chip_smoke.py holds it to hashlib there); a stand-in with the same
-    contract records what it was handed."""
+    sub-batch budget; everything else goes to the ragged tile kernel, and
+    every digest lands on its own row. The uniform kernel itself only runs
+    on the chip (chip_smoke.py holds it to hashlib there); a stand-in with
+    the same contract records what it was handed."""
     import jax.numpy as jnp
 
     from kraken_tpu.ops import sha256 as plane
@@ -155,6 +155,156 @@ def test_hash_batch_sends_piece_sized_uniform_groups_to_the_tile_kernel(
     for row, p in zip(got, pieces):
         assert bytes(row) == hashlib.sha256(p).digest()
     assert sorted(shapes) == want_shapes
+
+
+# -- the ragged tile kernel (interpret mode) --------------------------------
+
+def rolled_rounds(state, wget):
+    """``sha256_pallas._rounds64`` as four passes of a sixteen-round loop.
+
+    XLA:CPU does not finish compiling the unrolled 64 rounds in minutes
+    (ops/sha256.py ``_UNROLL``), so the interpret-mode tests of the ragged
+    kernel trace this in their place. The unrolled rounds are the ones
+    sha256_tiles has always had, held to hashlib on the chip; what these
+    tests hold is everything the ragged kernel adds around them: the
+    relayout, the per-lane block counts, the skipped groups, the state
+    carried from call to call."""
+    import jax
+    import jax.numpy as jnp
+
+    from kraken_tpu.ops.sha256_pallas import _K, _rotr
+
+    def sixteen(q, carry):
+        a, b, c, d, e, f, g, h = carry[:8]
+        w = list(carry[8:])
+        for i in range(16):
+            k = jnp.uint32(_K[i])
+            for p in (1, 2, 3):
+                k = jnp.where(q == p, jnp.uint32(_K[16 * p + i]), k)
+            wi = w[i]
+            s1 = _rotr(e, 6) ^ _rotr(e, 11) ^ _rotr(e, 25)
+            t1 = h + s1 + (g ^ (e & (f ^ g))) + k + wi
+            s0 = _rotr(a, 2) ^ _rotr(a, 13) ^ _rotr(a, 22)
+            maj = (a & (b ^ c)) ^ (b & c)
+            a, b, c, d, e, f, g, h = t1 + s0 + maj, a, b, c, d + t1, e, f, g
+            w15, w2 = w[(i + 1) % 16], w[(i + 14) % 16]
+            e0 = _rotr(w15, 7) ^ _rotr(w15, 18) ^ (w15 >> np.uint32(3))
+            e1 = _rotr(w2, 17) ^ _rotr(w2, 19) ^ (w2 >> np.uint32(10))
+            w[i] = wi + e0 + w[(i + 9) % 16] + e1
+        return (a, b, c, d, e, f, g, h, *w)
+
+    out = jax.lax.fori_loop(
+        0, 4, sixteen, (*state, *[wget(j) for j in range(16)])
+    )
+    return [s + v for s, v in zip(state, out[:8])]
+
+
+@pytest.fixture(scope="module")
+def ragged_kernel():
+    """``sha256_pallas`` with the ragged kernel runnable on the CPU."""
+    from kraken_tpu.ops import sha256_pallas
+
+    real = sha256_pallas._rounds64
+    sha256_pallas._rounds64 = rolled_rounds
+    sha256_pallas.sha256_ragged_slab.clear_cache()
+    yield sha256_pallas
+    sha256_pallas._rounds64 = real
+    sha256_pallas.sha256_ragged_slab.clear_cache()
+
+
+def _ragged_digests(kernel, msgs, shape):
+    from kraken_tpu.core.hasher import sha_blocks
+    from kraken_tpu.ops.sha256 import _digest_bytes, _round_up, _sha_pad_np
+
+    nblocks = np.array([sha_blocks(len(m)) for m in msgs], dtype=np.int32)
+    axis = _round_up(int(nblocks.max()), shape[1])
+    rows = np.stack(
+        [_sha_pad_np(memoryview(m), axis).reshape(-1) for m in msgs]
+    )
+    return _digest_bytes(kernel.sha256_ragged_tiles(rows, nblocks, shape))
+
+
+_SLAB = 16  # blocks a call in these tests: 1 KiB of a row
+_SLAB_BYTES = _SLAB * 64
+
+
+@pytest.mark.parametrize(
+    "length",
+    [
+        0, 1, 55, 56, 63, 64, 65, 119, 120, 1024,
+        pytest.param(_SLAB_BYTES - 64 - 9, id="one-slab-less-a-block"),
+        pytest.param(_SLAB_BYTES - 9, id="one-slab"),
+        pytest.param(_SLAB_BYTES - 8, id="one-slab-and-a-block"),
+        pytest.param(3 * _SLAB_BYTES + 7, id="three-slabs-and-7-bytes"),
+    ],
+)
+def test_ragged_tile_kernel_lengths(ragged_kernel, length):
+    """One row, one lane: every padding edge, and chains of one, two and
+    four calls whose state is carried from each call into the next."""
+    data = os.urandom(length)
+    got = _ragged_digests(ragged_kernel, [data], (1, _SLAB))
+    assert bytes(got[0]) == hashlib.sha256(data).digest()
+
+
+@pytest.mark.parametrize("lanes", [1024, 8])
+def test_ragged_tile_kernel_rows_ending_in_different_slabs(
+    ragged_kernel, lanes
+):
+    """Rows of one batch end in the first, second, third and fourth call:
+    a lane past its own count keeps its state through the later calls,
+    and lanes without a row are never read."""
+    lengths = [0, 100, _SLAB_BYTES - 9, _SLAB_BYTES, 2 * _SLAB_BYTES + 5,
+               3 * _SLAB_BYTES + 7, 700]
+    msgs = [os.urandom(n) for n in lengths]
+    got = _ragged_digests(ragged_kernel, msgs, (lanes, _SLAB))
+    for row, m in zip(got, msgs):
+        assert bytes(row) == hashlib.sha256(m).digest()
+
+
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_hash_batch_sends_short_and_odd_rows_to_the_ragged_tile_kernel(
+    ragged_kernel, use_pallas
+):
+    """On an accelerator (``use_pallas``) whatever the uniform tile kernel
+    does not take -- short rows, odd lengths -- goes through the ragged
+    tile kernel at its two shipped shapes (rows enough to fill a tile's
+    copy as one tile, an outlier or a few rows each as a chain of its
+    own) and the XLA scan books nothing; on the CPU backend it is the
+    reverse."""
+    from test_device_ledger import counts, delta
+
+    from kraken_tpu.ops import sha256 as plane
+    from kraken_tpu.utils.metrics import REGISTRY
+
+    tiles = {"purpose": "verify", "kernel": "sha256_ragged_tiles"}
+    scan = {"purpose": "verify", "kernel": "sha256_ragged"}
+    before = counts(REGISTRY, **tiles), counts(REGISTRY, **scan)
+    h = plane.JaxPieceHasher(use_pallas=use_pallas)
+    rng = np.random.default_rng(7)
+    run = [os.urandom(int(n)) for n in rng.integers(6000, 9000, size=40)]
+    few = [os.urandom(70_001), b"", os.urandom(4097)]
+    for pieces in (run + few[:1], few[1:]):
+        got = h.hash_batch(pieces)
+        for row, p in zip(got, pieces):
+            assert bytes(row) == hashlib.sha256(p).digest()
+    d_tiles = delta(before[0], counts(REGISTRY, **tiles))
+    d_scan = delta(before[1], counts(REGISTRY, **scan))
+    useful = sum(plane.sha_blocks(len(p)) for p in run + few)
+    if use_pallas:
+        assert d_scan["sections"] == 0
+        # The long row first and alone, one tile for the run of 40 alike,
+        # and the batch of two row by row.
+        assert d_tiles["sections"] == 4 and d_tiles["rows"] == 1024 + 3
+        assert d_tiles["useful_blocks"] == useful
+        row_axes = sum(
+            -(-plane.sha_blocks(len(p)) // 512) * 512 for p in few
+        )
+        tile_axis = -(-max(plane.sha_blocks(len(p)) for p in run) // 64) * 64
+        assert d_tiles["blocks"] == 1024 * tile_axis + row_axes
+        assert d_tiles["first_use"] <= 2  # the compiled shapes, not lengths
+    else:
+        assert d_tiles["sections"] == 0
+        assert d_scan["sections"] == 2 and d_scan["useful_blocks"] == useful
 
 
 @pytest.mark.skipif(
