@@ -214,7 +214,11 @@ class IngestPipeline:
         self._pack_pool_width = 0
         # Staging buffers: retained budget sized to the steady state
         # (windows_in_flight leases cycling) so the pool serves every
-        # window after the first lap without allocator traffic.
+        # window after the first lap without allocator traffic. Every
+        # session leases a whole window for its first bytes, and more
+        # concurrent sessions than retained windows miss: a window is
+        # mapped, not filled (utils/bufpool.py), so a miss costs the
+        # pages the blob lands in and a 1 KiB push does not pay for 64 MiB.
         self._bufpool = BufferPool(
             budget_bytes=self.config.window_bytes
             * (self.config.windows_in_flight + 1),

@@ -19,6 +19,14 @@ budget + what is concurrently leased (which the piece pipeline limit
 already bounds). Gauges ``bufpool_leased`` / ``bufpool_hit_ratio``
 (utils/metrics.py) say whether the pool is actually recycling.
 
+A miss costs what the lease will hold, not what its class could: classes
+of ``MAP_CLASS`` (1 MiB) and up are private anonymous mappings, which the
+kernel fills with zero pages on first touch, where ``bytearray(size)``
+allocates and zero-fills the whole class with the interpreter lock held
+(tens of milliseconds for the ingest plane's 64 MiB window, whatever the
+blob; a 100 KB push touches 25 pages of it). ``bufpool_miss_bytes_total``
+counts the bytes misses asked the allocator for.
+
 Thread-safe: leases happen on the event loop, but releases can arrive
 from task done-callbacks racing teardown, and tests drive the pool from
 plain sync code.
@@ -30,6 +38,7 @@ import mmap
 import threading
 
 MIN_CLASS = 1 << 12  # 4 KiB: below this, pooling costs more than malloc
+MAP_CLASS = 1 << 20  # 1 MiB: from here up a miss maps pages, fills none
 
 
 def _class_for(n: int) -> int:
@@ -39,9 +48,21 @@ def _class_for(n: int) -> int:
     return size
 
 
+def _allocate(size: int) -> bytearray | mmap.mmap:
+    """A zeroed, writable buffer of one class. Mappings are MAP_PRIVATE:
+    like the heap, and unlike ``mmap.mmap(-1, size)``'s MAP_SHARED, a
+    forked worker (p2p/shardpool.py) gets its own copy of a retained
+    buffer and not a window into the parent's."""
+    if size >= MAP_CLASS:
+        return mmap.mmap(
+            -1, size, flags=mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS
+        )
+    return bytearray(size)
+
+
 class Lease:
     """One leased buffer. ``view`` is a length-``n`` writable memoryview
-    over the (possibly larger) class-sized backing ``bytearray``.
+    over the (possibly larger) class-sized backing buffer.
     :meth:`release` is idempotent -- the happy path, the corrupt-piece ban
     path, and teardown callbacks may all race to return one buffer, and
     exactly one return must win (a double return would hand the same
@@ -49,7 +70,9 @@ class Lease:
 
     __slots__ = ("_pool", "_buf", "view", "_lock")
 
-    def __init__(self, pool: "BufferPool", buf: bytearray, n: int):
+    def __init__(
+        self, pool: "BufferPool", buf: bytearray | mmap.mmap, n: int
+    ):
         self._pool = pool
         self._buf = buf
         self.view = memoryview(buf)[:n]
@@ -86,13 +109,14 @@ class BufferPool:
         self.name = name
         self._budget = budget_bytes
         self._lock = threading.Lock()
-        self._free: dict[int, list[bytearray]] = {}
+        self._free: dict[int, list[bytearray | mmap.mmap]] = {}
         self._retained = 0
         # Stats (read by tests/bench; rendered as gauges on /metrics).
         self.leased = 0
         self.hits = 0
         self.misses = 0
         self.allocated = 0  # lifetime buffers created (reuse => stays flat)
+        self.miss_bytes = 0  # lifetime bytes of those buffers' classes
         # Gauge refs resolved ONCE: this plane exists to shave per-piece
         # CPU, so the per-op metrics update must be three plain sets, not
         # three registry name lookups (metrics.py locks + dict probes).
@@ -107,6 +131,10 @@ class BufferPool:
         )
         self._g_retained = REGISTRY.gauge(
             "bufpool_retained_bytes", "Free bytes retained for reuse"
+        )
+        self._c_miss_bytes = REGISTRY.counter(
+            "bufpool_miss_bytes_total",
+            "Bytes allocated for leases the free list could not serve",
         )
 
     def set_budget(self, budget_bytes: int) -> None:
@@ -127,27 +155,39 @@ class BufferPool:
             else:
                 buf = None
                 self.misses += 1
+                self.allocated += 1
+                self.miss_bytes += size
             self.leased += 1
         if buf is None:
-            buf = bytearray(size)
-            with self._lock:
-                self.allocated += 1
-        self._record()
+            buf = _allocate(size)
+            self._record(missed=size)
+        else:
+            self._record()
         return Lease(self, buf, n)
 
-    def _give_back(self, buf: bytearray) -> None:
+    def _give_back(self, buf: bytearray | mmap.mmap) -> None:
         size = len(buf)
         with self._lock:
             self.leased -= 1
-            if self._retained + size <= self._budget:
+            keep = self._retained + size <= self._budget
+            if keep:
                 self._free.setdefault(size, []).append(buf)
                 self._retained += size
-            # else: over budget -- drop to the allocator.
+        if not keep and isinstance(buf, mmap.mmap):
+            # Over budget -- back to the allocator, now and not when the
+            # collector gets to it. A slice of the lease's view that is
+            # still alive (the window worker's frame) keeps the pages
+            # mapped until it dies; dropping our reference is enough then.
+            try:
+                buf.close()
+            except BufferError:
+                pass
         self._record()
 
-    def _drop(self, buf: bytearray) -> None:
+    def _drop(self, buf: bytearray | mmap.mmap) -> None:
         """Lease ends but the buffer is still exported by a reader: count
-        the lease back without pooling the bytes."""
+        the lease back without pooling the bytes (and without unmapping
+        them: the mapping goes with its last view)."""
         with self._lock:
             self.leased -= 1
         self._record()
@@ -161,7 +201,7 @@ class BufferPool:
         total = self.hits + self.misses
         return self.hits / total if total else 0.0
 
-    def _record(self) -> None:
+    def _record(self, missed: int = 0) -> None:
         with self._lock:
             leased, retained = self.leased, self._retained
             total = self.hits + self.misses
@@ -169,6 +209,8 @@ class BufferPool:
         self._g_leased.set(leased, pool=self.name)
         self._g_hit.set(ratio, pool=self.name)
         self._g_retained.set(retained, pool=self.name)
+        if missed:
+            self._c_miss_bytes.inc(missed, pool=self.name)
 
 
 class SlabRing:

@@ -324,13 +324,14 @@ def record_data_plane_shard(
 
 
 # Wire-plane buffer pool gauges -- bufpool_leased / bufpool_hit_ratio /
-# bufpool_retained_bytes (label `pool`) -- are registered and maintained
-# by utils/bufpool.py, which caches the Gauge refs at pool construction:
-# the per-lease update must be three plain sets on the hot path, not
-# three registry name lookups. Semantics: `leased` is bounded by conns x
-# pipeline depth (a climb past that is a leak); `hit_ratio` near 1.0
-# means the pool recycles (persistently low => raise the byte budget --
-# docs/OPERATIONS.md "Wire plane").
+# bufpool_retained_bytes -- and the counter bufpool_miss_bytes_total
+# (label `pool`) are registered and maintained by utils/bufpool.py,
+# which caches the metric refs at pool construction: the per-lease
+# update must be three plain sets on the hot path (a miss adds one
+# increment), not three registry name lookups. Semantics: `leased` is
+# bounded by conns x pipeline depth (a climb past that is a leak);
+# `hit_ratio` near 1.0 means the pool recycles (persistently low =>
+# raise the byte budget -- docs/OPERATIONS.md "Wire plane").
 
 
 class FailureMeter:

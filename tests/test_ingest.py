@@ -800,6 +800,66 @@ def test_pipeline_abort_returns_every_lease(tmp_path):
     assert pipe._bufpool.leased == 0
 
 
+def test_ten_concurrent_small_sessions_on_shipped_windows():
+    """The small-push shape (ten pushers, one window each, 1 KiB-1 MiB)
+    on the shipped window: three buffers are retained, so seven of ten
+    concurrent first windows miss, each asking for a whole 64 MiB class
+    to hold <= 1 MiB. Digests are hashlib's, and the pool's leak audit
+    holds: nothing leased, nothing retained past the budget."""
+    import threading
+
+    import numpy as np
+
+    from kraken_tpu.configutil import load_config
+    from kraken_tpu.core.ingest import IngestConfig, IngestPipeline
+    from kraken_tpu.utils.metrics import REGISTRY
+
+    cfg = IngestConfig.from_dict(load_config("config/origin/base.yaml")["ingest"])
+    assert (cfg.window_bytes, cfg.windows_in_flight) == (64 << 20, 2)
+    pipe = IngestPipeline(get_hasher("cpu"), cfg)
+    pool = pipe._bufpool
+    plen = 256 * 1024
+    rng = np.random.default_rng(29)
+    sizes = [int(s) for s in np.geomspace(1 << 10, 1 << 20, 10)]
+    blobs = [rng.integers(0, 256, size=n, dtype=np.uint8).tobytes() for n in sizes]
+    all_leased = threading.Barrier(len(blobs))
+    got: list = [None] * len(blobs)
+
+    def push(i: int) -> None:
+        ses = pipe.session(plen)
+        buf = ses.begin_window()
+        assert len(buf) == cfg.window_bytes
+        all_leased.wait(timeout=30)  # ten windows out at once
+        buf[: len(blobs[i])] = blobs[i]
+        ses.submit(len(blobs[i]))
+        got[i] = ses.finish()
+
+    threads = [threading.Thread(target=push, args=(i,)) for i in range(len(blobs))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert not any(t.is_alive() for t in threads)
+
+    for blob, digests in zip(blobs, got):
+        want = [
+            hashlib.sha256(blob[o : o + plen]).digest()
+            for o in range(0, len(blob), plen)
+        ]
+        assert [bytes(d) for d in digests] == want
+    budget = cfg.window_bytes * (cfg.windows_in_flight + 1)
+    assert pool.leased == 0
+    assert REGISTRY.gauge("bufpool_leased").value(pool="ingest") == 0
+    assert 0 < pool.retained_bytes <= budget
+    assert pool.misses == len(blobs) and pool.hits == 0
+    assert pool.miss_bytes == len(blobs) * cfg.window_bytes
+    # Another lap is served from what was kept.
+    ses = pipe.session(plen)
+    ses.begin_window()
+    ses.abort()
+    assert pool.hits == 1 and pool.leased == 0
+
+
 def test_upload_digest_ttl_purge_and_capacity_eviction(tmp_path):
     """Satellite (b): idle trackers purge on the TTL tick (not only past
     a size watermark) and the hard cap evicts the OLDEST idle tracker,
