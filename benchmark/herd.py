@@ -31,9 +31,17 @@ class HerdError(Exception):
 
 
 def free_port() -> int:
+    """A port that was free when asked. The socket is closed before the child
+    binds it, so anything that connects out in between (the tracker is up and
+    dialling by then) can be handed the same number: ``Herd.start`` boots once
+    more on new ports when a child dies of that."""
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         return s.getsockname()[1]
+
+
+PORT_NAMES = ("tracker", "origin", "origin_p2p", "agent", "agent_p2p", "control")
+ADDRESS_IN_USE = re.compile(r"address already in use|Errno 98\b", re.IGNORECASE)
 
 
 def child_env(chip: bool) -> dict:
@@ -100,6 +108,11 @@ class Child:
         with open(self.log_path, "rb") as f:
             f.seek(max(0, os.path.getsize(self.log_path) - nbytes))
             return f.read().decode(errors="replace")
+
+    def died_of_taken_address(self) -> bool:
+        """The child has exited and its log says that it could not bind."""
+        return self.proc.poll() is not None and bool(
+            ADDRESS_IN_USE.search(self.log_tail(4000)))
 
     def stop(self) -> None:
         """SIGINT is the CLI's immediate stop; the process is gone, and the
@@ -168,11 +181,7 @@ class Herd:
         os.makedirs(work)
         os.makedirs(logs)
         self.backend_root = os.path.join(work, "backend")
-        self.ports = {
-            k: free_port() for k in (
-                "tracker", "origin", "origin_p2p", "agent", "agent_p2p", "control",
-            )
-        }
+        self.ports = {k: free_port() for k in PORT_NAMES}
         self.children: dict[str, Child] = {}
         self.ready: dict[str, dict] = {}
         self.control: Control | None = None
@@ -223,6 +232,30 @@ class Herd:
         self.children[role] = Child(role, argv, self.logs, chip)
 
     def start(self, timeout: float) -> None:
+        """Boot the herd. When a child exits at boot because a port it was
+        given had been taken since ``free_port`` chose it, draw new ports and
+        boot once more, and say so on standard error (the run's log). Any
+        other failure, and a second one of this kind, is the run's."""
+        try:
+            self._boot(timeout)
+        except HerdError as e:
+            taken = sorted(name for name, child in self.children.items()
+                           if child.died_of_taken_address())
+            if not taken:
+                raise
+            print(json.dumps({"event": "herd_reboot", "address_in_use": taken,
+                              "ports": self.ports, "error": str(e)[:400]}),
+                  file=sys.stderr, flush=True)
+            self._stop_children()
+            for name in os.listdir(self.logs):  # the first boot's logs stay beside
+                os.replace(os.path.join(self.logs, name),
+                           os.path.join(self.logs, name + ".boot1"))
+            shutil.rmtree(self.work, ignore_errors=True)
+            os.makedirs(self.work)
+            self.ports = {k: free_port() for k in PORT_NAMES}
+            self._boot(timeout)
+
+    def _boot(self, timeout: float) -> None:
         """Tracker first (the others announce to it), then origin and agent
         side by side."""
         self.spawn("tracker")
@@ -233,11 +266,14 @@ class Herd:
             self.ready[role] = self.children[role].wait_ready(timeout)
         self.control = Control(self.ports["control"])
 
-    def stop(self) -> None:
+    def _stop_children(self) -> None:
         if self.control is not None:
             self.control.close()
             self.control = None
         for child in self.children.values():
             child.stop()
         self.children.clear()
+
+    def stop(self) -> None:
+        self._stop_children()
         shutil.rmtree(self.work, ignore_errors=True)

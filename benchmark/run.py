@@ -92,14 +92,18 @@ def counter_checks(config: dict, ctx: dict, payload: dict) -> dict:
 async def traced_stretch(herd: Herd, plan: dict, trace_dir: str,
                          t_open: float, seconds: float, into: dict) -> None:
     """Trace the window's last ``plan["stretch_s"]`` seconds of measured
-    traffic from inside the process that holds the chip: one steady
-    stretch under the full load, asked to stop just before the close, so
-    that the tracer writes its file while the window's stragglers drain
-    and not inside the window. The ragged scan gives the device tracer
-    0.8-3 million events for every second it runs, and ``stop_trace``
-    then needs about two minutes for each million (PERF.md section 3); the
-    window's own numbers are taken before that."""
-    stretch_s = plan["stretch_s"]
+    traffic (never more than half the window: the rehearsal's is 6 s) from
+    inside the process that holds the chip: one steady stretch under the
+    full load, asked to stop just before the close, so that the tracer
+    writes its file while the window's stragglers drain and not inside the
+    window. The stretch has to hold the work its readers divide by: on a
+    chip that the host leaves idle 85-99% of the time 0.3 s held anything
+    from none to fifteen pushes, and a stretch that holds no device work
+    fails the run. Five seconds of the push cell hold about 230 pushes and
+    12,000 device events (a slab call is one event), and ``stop_trace``
+    then takes about 6 s, half a millisecond an event (PERF.md section 3).
+    The window's own numbers are taken before the tracer writes."""
+    stretch_s = min(plan["stretch_s"], seconds / 2)
     await asyncio.sleep(max(0.0, t_open + seconds - stretch_s - 0.25 - time.monotonic()))
     into["dir"] = trace_dir
     into.update(await asyncio.to_thread(
