@@ -3,7 +3,7 @@
 The round-3 bench measured the cross-layer dedup ratio to 0.81 GB; this
 one streams a deterministic synthetic Docker-layer corpus of STREAM_GB
 (default 100) through the HOST chunking plane (native C FastCDC,
-`kraken_tpu/native/hostpack.c:kt_cdc_chunk`) with nothing ever written
+`kraken_tpu/native/hostcdc.c:kt_cdc_chunk`) with nothing ever written
 to disk, and reports the sustained pipeline rate plus the dedup-ratio
 curve vs corpus size.
 
@@ -83,7 +83,7 @@ def layer_stream(rng: np.random.Generator):
 
 
 def main() -> None:
-    from kraken_tpu.native import have_native_packer
+    from kraken_tpu.native import have_native_chunker
     from kraken_tpu.ops.cdc import CDCParams, chunk_host
 
     params = CDCParams()  # 16/64/256 KiB -- BASELINE config #4
@@ -133,7 +133,7 @@ def main() -> None:
         "chunks": chunks,
         "avg_chunk_kb": round(total / max(1, chunks) / 1024, 1),
         "ratio_curve": curve,
-        "native_chunker": have_native_packer(),
+        "native_chunker": have_native_chunker(),
         "unique_chunk_index_mb": round(len(seen) * 85 / 1e6),
         "peak_rss_mb": resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
         // 1024,

@@ -264,8 +264,8 @@ def main() -> None:
     # Delta-transfer cash-in: what a real pull MOVES, on vs off.
     delta_rows = delta_moved_rows(rng)
 
-    # Device gear-pass rate with the data resident (marginal method, as
-    # bench.py); the chunk wall clock above includes the host->device copy.
+    # Device gear-pass rate with the data resident (marginal method);
+    # the chunk wall clock above includes the host->device copy.
     import jax
     import jax.numpy as jnp
 

@@ -1083,8 +1083,8 @@ def main(argv: list[str] | None = None) -> None:
             chunkstore=cfg.get("chunkstore"),
             # YAML: slo: -- the burn-rate SLO plane ("SLO & canary").
             slo=cfg.get("slo"),
-            # YAML: ingest: {window_bytes, windows_in_flight,
-            # pack_workers, pack_mode} -- the pipelined zero-copy ingest
+            # YAML: ingest: {window_bytes, windows_in_flight, resume,
+            # serve_while_ingest} -- the pipelined zero-copy ingest
             # plane (docs/OPERATIONS.md "Pipelined ingest"). SIGHUP
             # live-reloads (and live-enables).
             ingest=cfg.get("ingest"),

@@ -5,27 +5,23 @@ then read the next -- which leaves the chip idle while the host reads and
 the host idle while the chip hashes. This module turns that into a
 multi-window stream:
 
-    read -> pack -> transfer -> hash        (per window)
+    read -> transfer -> hash        (per window)
 
 with ``windows_in_flight`` windows overlapped: while window k hashes on
 the device (or the host pool), window k+1 is being read into its own
 staging buffer. Staging buffers are bufpool-backed (``utils/bufpool``)
 and reused across windows -- the read lands bytes DIRECTLY in the buffer
-the pack/transfer consumes (``readinto`` / stream-chunk copy), which is
+the transfer/hash consumes (``readinto`` / stream-chunk copy), which is
 the only host copy the window ever takes.
 
 Stage semantics per window:
 
 - **read**: filling the staging buffer (spool ``readinto`` on the
   re-generate path; request-body chunk copy on the stream path).
-- **pack**: producing the device layout. ``pack: host`` is a zero-copy
-  reshape (the natural-layout kernel relayouts in VMEM); ``pack:
-  native`` runs the C packer cooperatively over ``pack_workers``
-  HashPool threads (ctypes drops the GIL per call); ``pack: device``
-  relays out on-chip (ops/sha256_pallas.pack_tiles_device).
-- **transfer**: ``jax.device_put`` of the window onto the mesh (device
-  hashers only; the buffer is free for reuse as soon as the put returns,
-  which is the donation point of the double-buffer scheme).
+- **transfer**: ``jax.device_put`` of the window onto the mesh (hashers
+  with ``stage_window`` only; the buffer is free for reuse as soon as
+  the put returns, which is the donation point of the double-buffer
+  scheme).
 - **hash**: the device dispatch + digest readback, or the CPU HashPool
   piece pass -- the automatic fallback when no device hasher is
   configured.
@@ -55,20 +51,12 @@ import numpy as np
 
 from kraken_tpu.core.hasher import (
     DIGEST_SIZE,
-    HashPool,
     PieceHasher,
-    device_section,
     profiler_annotation,
-    record_hash_metrics,
-    sha_blocks,
 )
 from kraken_tpu.utils import failpoints
 
 _log = logging.getLogger("kraken.ingest")
-
-STAGES = ("read", "pack", "transfer", "hash", "commit")
-
-PACK_MODES = ("host", "native", "device")
 
 # Stage walls span ~100 us (a reshape) to ~10 s (a multi-GiB window on a
 # cold page cache): wider-than-default log-spaced buckets.
@@ -129,25 +117,10 @@ class IngestConfig:
     # smaller windows bound staging RAM: peak staging is roughly
     # window_bytes * windows_in_flight.
     window_bytes: int = 64 * 1024 * 1024
-    # Windows concurrently in flight (read overlapping pack/transfer/
-    # hash). 2 = classic double buffering, the shipped default; 1
-    # degenerates to the serial path (useful to price the overlap).
+    # Windows concurrently in flight (read overlapping transfer/hash).
+    # 2 = classic double buffering, the shipped default; 1 degenerates
+    # to the serial path (useful to price the overlap).
     windows_in_flight: int = 2
-    # HashPool workers for the ``pack: native`` cooperative pack (the C
-    # packer's 16-piece groups split across them, GIL-free). 0 = pack on
-    # the window worker itself.
-    pack_workers: int = 1
-    # host   -- natural layout; the device kernel relayouts in VMEM
-    #           (shipped default: no host cores spent, mesh-sharded).
-    # native -- AVX-512 host pack to the word-major layout, then the
-    #           pure-rounds packed kernel (~92 vs ~75 GB/s/chip on v5e);
-    #           needs spare feeder cores.
-    # device -- on-chip Pallas relayout kernel feeding the packed
-    #           kernel: packed-kernel rate without host pack cores.
-    # Modes other than host need tile-quantum windows (1024 pieces) and a
-    # single-chip device hasher; non-conforming windows fall back to
-    # host-mode handling, bit-identically.
-    pack_mode: str = "host"
     # Resumable upload sessions: journal per-upload durable progress to a
     # ``upload/<uid>.session`` sidecar so a crashed/drained origin
     # re-adopts live sessions after restart and clients resume from the
@@ -171,15 +144,6 @@ class IngestConfig:
             raise ValueError(
                 "ingest.windows_in_flight must be >= 1: "
                 f"{self.windows_in_flight}"
-            )
-        if self.pack_workers < 0:
-            raise ValueError(
-                f"ingest.pack_workers must be >= 0: {self.pack_workers}"
-            )
-        if self.pack_mode not in PACK_MODES:
-            raise ValueError(
-                f"ingest.pack_mode must be one of {PACK_MODES}: "
-                f"{self.pack_mode!r}"
             )
 
     @classmethod
@@ -210,8 +174,6 @@ class IngestPipeline:
         self._lock = threading.Lock()
         self._executor: Optional[ThreadPoolExecutor] = None
         self._executor_width = 0
-        self._pack_pool: Optional[HashPool] = None
-        self._pack_pool_width = 0
         # Staging buffers: retained budget sized to the steady state
         # (windows_in_flight leases cycling) so the pool serves every
         # window after the first lap without allocator traffic. Every
@@ -251,16 +213,6 @@ class IngestPipeline:
                 )
             return self._executor
 
-    def _get_pack_pool(self) -> Optional[HashPool]:
-        with self._lock:
-            want = self.config.pack_workers
-            if want < 1:
-                return None
-            if self._pack_pool is None or self._pack_pool_width != want:
-                self._pack_pool = HashPool(want, name="pack")
-                self._pack_pool_width = want
-            return self._pack_pool
-
     def session(self, piece_length: int) -> "IngestSession":
         if piece_length <= 0:
             raise ValueError(f"piece_length must be positive: {piece_length}")
@@ -276,7 +228,7 @@ class IngestSession:
         while bytes remain:
             buf = ses.begin_window()     # memoryview to fill
             n = fill(buf)                # readinto / chunk copies
-            ses.submit(n)                # queues pack/transfer/hash
+            ses.submit(n)                # queues transfer/hash
         digests = ses.finish()           # [N, 32] uint8, piece order
 
     ``submit`` blocks once ``windows_in_flight`` windows are queued or
@@ -289,13 +241,7 @@ class IngestSession:
         self.pipeline = pipeline
         self.piece_length = piece_length
         pieces = max(1, cfg.window_bytes // piece_length)
-        if cfg.pack_mode != "host" and pieces >= 1024:
-            # Packed layouts move in 1024-piece device tiles; a tile-
-            # quantum window lets every full window take the packed path
-            # instead of falling back on alignment.
-            pieces -= pieces % 1024
         self.window_bytes = pieces * piece_length
-        self._cfg = cfg
         self._sem = threading.Semaphore(cfg.windows_in_flight)
         self._futs: list[Future] = []
         self._lease = None
@@ -307,7 +253,7 @@ class IngestSession:
         # host pass. Benign cross-thread bool.
         self._fell_back = False
         self.stage_seconds: dict[str, float] = dict.fromkeys(
-            ("read", "pack", "transfer", "hash"), 0.0
+            ("read", "transfer", "hash"), 0.0
         )
         # Submit -> a worker picks the window up, summed over windows.
         # Not a stage of the window's own work: out of overlap_ratio.
@@ -505,30 +451,20 @@ class IngestSession:
             self._sem.release()
 
     def _hasher_window(self, view, plen: int) -> np.ndarray:
-        """The configured hasher's path for one window (device packed,
-        device staged, or the hasher's own batch call)."""
-        nbytes = len(view)
-        m, ragged = divmod(nbytes, plen)
+        """The configured hasher's path for one window (device staged,
+        or the hasher's own batch call)."""
+        m, ragged = divmod(len(view), plen)
         hasher = self.pipeline.hasher
-        uniform = m > 0 and ragged == 0
-        if uniform:
+        if m > 0 and ragged == 0 and hasattr(hasher, "stage_window"):
             arr = np.frombuffer(view, dtype=np.uint8).reshape(m, plen)
-            if (
-                self._cfg.pack_mode != "host"
-                and m % 1024 == 0
-                and plen % 64 == 0
-                and hasher.name.startswith("tpu")
-            ):
-                return self._packed_window(arr, plen)
-            if hasattr(hasher, "stage_window"):
-                if failpoints.fire("ingest.window.transfer"):
-                    raise failpoints.FailpointError("ingest.window.transfer")
-                with timed_stage("transfer", self._bill):
-                    handle = hasher.stage_window(arr, plen)
-                if failpoints.fire("ingest.window.hash"):
-                    raise failpoints.FailpointError("ingest.window.hash")
-                with timed_stage("hash", self._bill):
-                    return hasher.hash_staged_window(handle)
+            if failpoints.fire("ingest.window.transfer"):
+                raise failpoints.FailpointError("ingest.window.transfer")
+            with timed_stage("transfer", self._bill):
+                handle = hasher.stage_window(arr, plen)
+            if failpoints.fire("ingest.window.hash"):
+                raise failpoints.FailpointError("ingest.window.hash")
+            with timed_stage("hash", self._bill):
+                return hasher.hash_staged_window(handle)
         # CPU HashPool path, ragged final window, hashers without the
         # staged protocol: one batch call, billed to hash. Bit-identical
         # by definition -- same boundaries.
@@ -552,47 +488,4 @@ class IngestSession:
                 out[i] = np.frombuffer(
                     hashlib.sha256(piece).digest(), dtype=np.uint8
                 )
-        return out
-
-    def _packed_window(self, arr: np.ndarray, plen: int) -> np.ndarray:
-        """``pack: native|device`` window: explicit relayout + the
-        pure-rounds packed kernel (single-chip)."""
-        import jax
-
-        from kraken_tpu.ops.sha256 import _digest_bytes
-        from kraken_tpu.ops.sha256_pallas import (
-            pack_tiles_device,
-            packed_nb,
-            sha256_packed_tiles,
-        )
-
-        if failpoints.fire("ingest.window.pack"):
-            raise failpoints.FailpointError("ingest.window.pack")
-        nb = packed_nb(plen // 64)
-        packed = None
-        if self._cfg.pack_mode == "native":
-            from kraken_tpu import native
-
-            with timed_stage("pack", self._bill):
-                packed = native.pack_tiles_pooled(
-                    arr, nb, self.pipeline._get_pack_pool()
-                ).reshape(-1, nb, 16, 8, 128)
-        m = arr.shape[0]
-        # Transfer, on-chip relayout and hash are one device section: the
-        # result is on the host only after the last.
-        with device_section(
-            "piece", "sha256_packed", rows=m, blocks=sha_blocks(plen),
-            useful_blocks=m * sha_blocks(plen), payload_bytes=arr.size,
-        ):
-            if packed is not None:
-                with timed_stage("transfer", self._bill):
-                    xdev = jax.device_put(packed)
-            else:  # device: transfer natural bytes, relayout on-chip
-                with timed_stage("transfer", self._bill):
-                    xdev_nat = jax.device_put(arr)
-                with timed_stage("pack", self._bill):
-                    xdev = pack_tiles_device(xdev_nat, plen // 64)
-            with timed_stage("hash", self._bill):
-                out = _digest_bytes(sha256_packed_tiles(xdev, plen // 64))
-        record_hash_metrics(self.pipeline.hasher.name, arr.size, m)
         return out

@@ -21,35 +21,26 @@ All parallelism is cross-piece: SHA-256's chain serializes blocks within a
 piece, so pieces are the batch axis and the block axis is the grid's inner
 sequential dimension.
 
-Three kernels, all on [8, 128] lanes with the same rounds (``_rounds64``);
+Two kernels, both on [8, 128] lanes with the same rounds (``_rounds64``);
 a block below is one 64-byte step of every lane of a tile, v5e:
 
 | kernel | takes | measured |
 |---|---|---|
 | ``sha256_tiles`` | equal-length rows, natural bytes; chain length and padding block compiled in (a Mosaic compile per length) | ~75 GB/s a full tile = 0.87 us a block (r3, 2026-07-29); one 4 MiB piece a dispatch 60 ms = 0.92 us a block (PR 24); a 16 x 4 MiB window 74 ms, copy included = 1.13 us a block (PR 21) |
-| ``sha256_packed_tiles`` | the same, pre-packed word-major tiles | ~92 GB/s = 0.71 us a block (r3, 2026-07-29) |
 | ``sha256_ragged_slab`` (``sha256_ragged_tiles`` drives it) | SHA-padded rows of any lengths, a block count a lane, the state carried from call to call: two compiled shapes for every length and row count | one row, 512 blocks a call: 1.01-1.09 us a block from 1 to 4 MiB, copy, dispatch and read-back included, and ~1.5 ms a chain before the first block; a tile of 1024 rows, 64 blocks a call: 9.1-9.6 us a block, bound by the copy of 64 KiB a block (PR 26, 2026-10-01; the XLA scan it replaced: 199-211 us a block at one row, 30 at 8-16) |
 
-The two uniform kernels' input layouts (docs/PERF_HISTORY.md has the
-measured analysis, v5e 2026-07-29):
-
-- **natural** ``[M, piece_len] uint8`` -- what the store hands over. The
-  kernel transposes each [N_TILE, _KB*64] BYTE slab in VMEM (u8
-  granularity) and recombines the four byte planes into big-endian words
-  with vector shifts -- the BE combine is the byteswap, for free.
-  **~75 GB/s/chip** measured (median of repeated runs, r3). The round-2
-  u32-word transpose managed only ~18: Mosaic's 32-bit transpose was the
-  binding constraint; the u8 transpose of the same bytes runs ~4x faster
-  and the u16 variant sits between (~22). Older alternatives -- per-
-  sublane-group square transposes (14), MXU byte-plane transpose via
-  identity matmul (13.8), XLA pre-transpose (10.7), two-pass repack
-  kernel (15.6) -- all slower still.
-- **packed** ``[T, NB, 16, 8, 128] uint32`` big-endian word-major tiles,
-  produced at feed time by the native host packer
-  (:mod:`kraken_tpu.native`, AVX-512 blocked transpose). The kernel then
-  does pure rounds: **~92 GB/s/chip** measured. Worth it only when the
-  feeder host has the cores to pack at line rate; the u8 natural path
-  made this optional rather than mandatory for >=20 GB/s.
+The uniform kernel's input layout (docs/PERF_HISTORY.md has the measured
+analysis, v5e 2026-07-29) is the **natural** ``[M, piece_len] uint8`` the
+store hands over. The kernel transposes each [N_TILE, _KB*64] BYTE slab in
+VMEM (u8 granularity) and recombines the four byte planes into big-endian
+words with vector shifts -- the BE combine is the byteswap, for free.
+**~75 GB/s/chip** measured (median of repeated runs, r3). The round-2
+u32-word transpose managed only ~18: Mosaic's 32-bit transpose was the
+binding constraint; the u8 transpose of the same bytes runs ~4x faster and
+the u16 variant sits between (~22). Older alternatives -- per-sublane-group
+square transposes (14), MXU byte-plane transpose via identity matmul
+(13.8), XLA pre-transpose (10.7), two-pass repack kernel (15.6) -- all
+slower still.
 """
 
 from __future__ import annotations
@@ -118,16 +109,14 @@ def _rounds64(state, wget):
     return [s + v for s, v in zip(state, (a, b, c, d, e, f, g, h))]
 
 
-def _make_kernel(nb_real: int, pad_words: np.ndarray, packed: bool):
+def _make_kernel(nb_real: int, pad_words: np.ndarray):
     """Grid-step kernel for a chain of ``nb_real`` data blocks.
 
     The shared SHA padding block is folded from compile-time constants
     (``pad_words``) after the last real block -- it never exists in HBM.
-    ``packed=False``: blk_ref is a natural [N_TILE, _KB*64] uint8 BYTE
-    slab, transposed in VMEM at u8 granularity. ``packed=True``: blk_ref
-    is pre-packed [1, _KB, 16, _SUB, _LANES] BE words -- no relayout.
-    out_ref: [1, 8, _SUB, _LANES], revisited across the block-group axis
-    (carries the running state in VMEM).
+    blk_ref is a natural [N_TILE, _KB*64] uint8 BYTE slab, transposed in
+    VMEM at u8 granularity. out_ref: [1, 8, _SUB, _LANES], revisited
+    across the block-group axis (carries the running state in VMEM).
     """
     ngroups = (nb_real + _KB - 1) // _KB
 
@@ -140,38 +129,30 @@ def _make_kernel(nb_real: int, pad_words: np.ndarray, packed: bool):
                 out_ref[0, i, :, :] = jnp.full((_SUB, _LANES), _H0[i], jnp.uint32)
 
         state = [out_ref[0, i, :, :] for i in range(8)]
-        if not packed:
-            # Piece-major -> word-major as ONE up-front BYTE transpose.
-            # Granularity matters enormously on v5e (measured r3, same
-            # kernel otherwise): u8 transpose ~68 GB/s end-to-end, u16
-            # ~22, u32 ~18. Recombining the four byte planes into
-            # big-endian words costs 3 shifts + 3 ors per word and IS the
-            # byteswap -- the LE->BE conversion falls out of plane order.
-            t8 = jnp.transpose(blk_ref[...], (1, 0)).reshape(
-                _KB, 16, 4, _SUB, _LANES
+        # Piece-major -> word-major as ONE up-front BYTE transpose.
+        # Granularity matters enormously on v5e (measured r3, same
+        # kernel otherwise): u8 transpose ~68 GB/s end-to-end, u16
+        # ~22, u32 ~18. Recombining the four byte planes into
+        # big-endian words costs 3 shifts + 3 ors per word and IS the
+        # byteswap -- the LE->BE conversion falls out of plane order.
+        t8 = jnp.transpose(blk_ref[...], (1, 0)).reshape(
+            _KB, 16, 4, _SUB, _LANES
+        )
+
+        def _word(kb, j):
+            b0 = t8[kb, j, 0].astype(jnp.uint32)
+            b1 = t8[kb, j, 1].astype(jnp.uint32)
+            b2 = t8[kb, j, 2].astype(jnp.uint32)
+            b3 = t8[kb, j, 3].astype(jnp.uint32)
+            return (
+                (b0 << np.uint32(24))
+                | (b1 << np.uint32(16))
+                | (b2 << np.uint32(8))
+                | b3
             )
 
-            def _word(kb, j):
-                b0 = t8[kb, j, 0].astype(jnp.uint32)
-                b1 = t8[kb, j, 1].astype(jnp.uint32)
-                b2 = t8[kb, j, 2].astype(jnp.uint32)
-                b3 = t8[kb, j, 3].astype(jnp.uint32)
-                return (
-                    (b0 << np.uint32(24))
-                    | (b1 << np.uint32(16))
-                    | (b2 << np.uint32(8))
-                    | b3
-                )
-
         for kb in range(_KB):
-            if packed:
-                new = _rounds64(
-                    state, lambda j, kb=kb: blk_ref[0, kb, j, :, :]
-                )
-            else:
-                new = _rounds64(
-                    state, lambda j, kb=kb: _word(kb, j)
-                )
+            new = _rounds64(state, lambda j, kb=kb: _word(kb, j))
             if (nb_real % _KB) and kb >= nb_real % _KB:
                 # A position past the real chain only occurs in the final
                 # (ragged) group; elsewhere the static bound keeps it free.
@@ -242,7 +223,7 @@ def sha256_tiles(
     pad_words = np.asarray(_pad_block_for(nb * 64), dtype=np.uint32)
 
     out = pl.pallas_call(
-        _make_kernel(nb, pad_words, packed=False),
+        _make_kernel(nb, pad_words),
         interpret=interpret,
         grid=(t, ngroups),
         in_specs=[
@@ -258,44 +239,6 @@ def sha256_tiles(
         out_shape=jax.ShapeDtypeStruct((t, 8, _SUB, _LANES), jnp.uint32),
     )(data_u8)
     return out.reshape(t, 8, N_TILE).transpose(0, 2, 1).reshape(-1, 8)[:m]
-
-
-@functools.partial(jax.jit, static_argnames=("unpadded_blocks", "interpret"))
-def sha256_packed_tiles(
-    packed: jax.Array,
-    unpadded_blocks: int,
-    interpret: bool | None = None,
-):
-    """Hash pieces already in the PACKED word-major layout.
-
-    packed: [T, NB, 16, 8, 128] uint32 big-endian words from
-    :func:`kraken_tpu.native.pack_tiles` with NB = ceil(unpadded_blocks /
-    _KB) * _KB (trailing blocks ignored). Returns [T*N_TILE, 8] uint32.
-    Pure rounds, no relayout: ~92 GB/s/chip measured on v5e.
-    """
-    interpret = _resolve_interpret(interpret)
-    t = packed.shape[0]
-    nb = unpadded_blocks
-    ngroups = (nb + _KB - 1) // _KB
-    pad_words = np.asarray(_pad_block_for(nb * 64), dtype=np.uint32)
-
-    out = pl.pallas_call(
-        _make_kernel(nb, pad_words, packed=True),
-        interpret=interpret,
-        grid=(t, ngroups),
-        in_specs=[
-            pl.BlockSpec(
-                (1, _KB, 16, _SUB, _LANES), lambda ti, bi: (ti, bi, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            )
-        ],
-        out_specs=pl.BlockSpec(
-            (1, 8, _SUB, _LANES), lambda ti, bi: (ti, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct((t, 8, _SUB, _LANES), jnp.uint32),
-    )(packed)
-    return out.reshape(t, 8, N_TILE).transpose(0, 2, 1).reshape(t * N_TILE, 8)
 
 
 # -- ragged rows: any length, any row count, one compiled shape ------------
@@ -460,85 +403,6 @@ def sha256_ragged_tiles(
             interpret=interpret,
         )
     return np.asarray(state).reshape(8, N_TILE).T[:n]
-
-
-def packed_nb(unpadded_blocks: int) -> int:
-    """Block-axis extent of the packed layout for a given chain length."""
-    return ((unpadded_blocks + _KB - 1) // _KB) * _KB
-
-
-def _make_pack_kernel():
-    """Relayout-only grid step: natural [1, N_TILE, _KB*64] uint8 slab ->
-    packed [1, _KB, 16, _SUB, _LANES] big-endian words. The same in-VMEM
-    u8 transpose + byte-plane recombine the natural hash kernel performs,
-    emitted as data instead of consumed by rounds -- the ``pack: device``
-    alternative to the AVX-512 host packer (kraken_tpu/native)."""
-
-    def kernel(blk_ref, out_ref):
-        t8 = jnp.transpose(blk_ref[0], (1, 0)).reshape(
-            _KB, 16, 4, _SUB, _LANES
-        )
-        for kb in range(_KB):
-            for j in range(16):
-                b0 = t8[kb, j, 0].astype(jnp.uint32)
-                b1 = t8[kb, j, 1].astype(jnp.uint32)
-                b2 = t8[kb, j, 2].astype(jnp.uint32)
-                b3 = t8[kb, j, 3].astype(jnp.uint32)
-                out_ref[0, kb, j, :, :] = (
-                    (b0 << np.uint32(24))
-                    | (b1 << np.uint32(16))
-                    | (b2 << np.uint32(8))
-                    | b3
-                )
-
-    return kernel
-
-
-@functools.partial(jax.jit, static_argnames=("unpadded_blocks", "interpret"))
-def pack_tiles_device(
-    data_u8: jax.Array,
-    unpadded_blocks: int,
-    interpret: bool | None = None,
-):
-    """On-device pack: natural [M, P] uint8 pieces (M % N_TILE == 0,
-    P = unpadded_blocks * 64) -> the packed word-major
-    [T, NB, 16, _SUB, _LANES] uint32 layout of
-    :func:`kraken_tpu.native.pack_tiles`, with NB = packed_nb(...). Bytes
-    transfer to the device in natural layout; the relayout (and the LE->BE
-    byteswap it implies) happens on-chip, so the host never spends pack
-    cores and the hash pass still runs the pure-rounds packed kernel."""
-    interpret = _resolve_interpret(interpret)
-    m = data_u8.shape[0]
-    t = m // N_TILE
-    nb = unpadded_blocks
-    ngroups = (nb + _KB - 1) // _KB
-
-    slabs = data_u8.reshape(t, N_TILE, nb * 64)
-    if nb % _KB:
-        # Zero-pad the block axis: zero bytes pack to zero words, which
-        # matches the host packer's zero-filled trailing blocks exactly.
-        slabs = jnp.pad(
-            slabs, ((0, 0), (0, 0), (0, (ngroups * _KB - nb) * 64))
-        )
-
-    return pl.pallas_call(
-        _make_pack_kernel(),
-        interpret=interpret,
-        grid=(t, ngroups),
-        in_specs=[
-            pl.BlockSpec(
-                (1, N_TILE, _KB * 64), lambda ti, bi: (ti, 0, bi),
-                memory_space=pltpu.VMEM,
-            )
-        ],
-        out_specs=pl.BlockSpec(
-            (1, _KB, 16, _SUB, _LANES), lambda ti, bi: (ti, bi, 0, 0, 0),
-            memory_space=pltpu.VMEM,
-        ),
-        out_shape=jax.ShapeDtypeStruct(
-            (t, ngroups * _KB, 16, _SUB, _LANES), jnp.uint32
-        ),
-    )(slabs)
 
 
 def hash_pieces_device(

@@ -78,14 +78,11 @@ class Generator:
         self.piece_lengths = piece_lengths or PieceLengthConfig()
         # Blobs are hashed through a sliding window of whole pieces, so
         # generation memory is O(window), not O(blob). The window is the
-        # hasher's batch: TPU origins with RAM to spare should raise it
-        # toward N_TILE * piece_length (4 GiB at 4 MiB pieces) for full
-        # dispatch occupancy; the default trades ~piece-batch occupancy
-        # for a bounded footprint.
+        # hasher's batch.
         self.window_bytes = window_bytes
         # core.ingest.IngestPipeline, when the origin runs the pipelined
         # ingest plane: re-generates stream spool windows through it
-        # (read overlapping pack/transfer/hash) instead of the serial
+        # (read overlapping transfer/hash) instead of the serial
         # read-then-hash loop below. None = serial path.
         self.pipeline = pipeline
 
@@ -149,7 +146,7 @@ class Generator:
         """Stream the blob through the ingest pipeline: ``readinto`` lands
         each window's bytes DIRECTLY in the staging buffer the hasher
         consumes (the zero-copy read stage), and the pipeline overlaps
-        window k+1's read with window k's pack/transfer/hash. Digests are
+        window k+1's read with window k's transfer/hash. Digests are
         bit-identical to the serial loop -- same piece boundaries."""
         ses = self.pipeline.session(piece_length)
         try:

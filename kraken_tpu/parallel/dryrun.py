@@ -59,9 +59,9 @@ def run_dryrun(n_devices: int) -> None:
 
     # Pallas is deliberately NOT run here: XLA:CPU takes >5 min to compile
     # its ~6k-op unrolled round body in any CPU mode (measured 2026-07-29);
-    # its correctness home is the real chip (entry() + bench.py digest
-    # cross-check). The XLA-scan path exercises the identical shard_map +
-    # all-gather sharding.
+    # its correctness home is the real chip (entry() + chip_smoke.py's
+    # digests against hashlib). The XLA-scan path exercises the identical
+    # shard_map + all-gather sharding.
     with jax.transfer_guard_host_to_device("disallow"):
         out = sharded_hash_pieces(
             mesh,

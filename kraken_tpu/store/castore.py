@@ -69,8 +69,7 @@ class CAStore:
           before the data hits the platter).
         - ``"fsync"``: fsync the file before rename and the directory
           after, on every blob commit and sidecar write. Power-loss
-          durable; costs one fdatasync+dirsync per commit (measured in
-          bench_ingest.py).
+          durable; costs one fdatasync+dirsync per commit.
         """
         if durability not in ("rename", "fsync"):
             raise ValueError(f"unknown durability mode: {durability!r}")

@@ -72,7 +72,6 @@ KNOWN_FAILPOINTS = frozenset({
     "httputil.request.truncate_body",
     "ingest.abort",
     "ingest.window.hash",
-    "ingest.window.pack",
     "ingest.window.read",
     "ingest.window.transfer",
     "origin.commit.slow",

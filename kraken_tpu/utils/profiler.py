@@ -100,11 +100,7 @@ _PLANE_RULES: tuple[tuple[str, str], ...] = (
     ("shardpool.py:", "serve"),
     ("dispatch.py:", "dispatch"),
     ("scheduler.py:", "dispatch"),
-    # Pipelined ingest plane (core/ingest.py): pack-worker threads show
-    # as "pack" (the host relayout feeding the packed kernel -- the
-    # function-qualified rule catches both native/__init__.py entries and
-    # the C call's Python frame), window workers as "ingest".
-    ("__init__.py:pack_tiles", "pack"),
+    # Pipelined ingest plane (core/ingest.py): window workers.
     ("ingest.py:", "ingest"),
 )
 

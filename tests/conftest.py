@@ -84,6 +84,27 @@ def kt_sanitize(request, monkeypatch):
     )
 
 
+@pytest.fixture
+def tile_kernel_shapes(monkeypatch):
+    """The uniform tile kernel only runs on the chip (chip_smoke.py holds
+    it to hashlib there): a stand-in with the same contract takes its
+    place and the fixture is the list of the shapes it was handed."""
+    import jax.numpy as jnp
+
+    from kraken_tpu.ops import sha256 as plane
+    from kraken_tpu.ops import sha256_pallas
+
+    shapes: list[tuple[int, int]] = []
+
+    def tile_kernel(data_u8, piece_length, interpret=None):
+        shapes.append(tuple(data_u8.shape))
+        pad = jnp.asarray(plane._pad_block_for(piece_length))
+        return plane._sha256_uniform(data_u8, pad, piece_length // 64)
+
+    monkeypatch.setattr(sha256_pallas, "hash_pieces_device", tile_kernel)
+    return shapes
+
+
 @pytest.fixture(autouse=True)
 def no_leaked_asyncio_tasks(request, monkeypatch):
     import asyncio

@@ -160,13 +160,18 @@ def test_env_arming_is_self_acknowledging():
         )
 
 
-def test_env_arming_rejects_undeclared_names():
+@pytest.mark.parametrize(
+    "name",
+    # A typo, and a site that left with its code (PR 31).
+    ["trcker.announce.error", "ingest.window.pack"],
+)
+def test_env_arming_rejects_undeclared_names(name):
     # The silent-typo hole: an env entry naming a site that is not in
     # KNOWN_FAILPOINTS would inject nothing and still report the chaos
     # run green. Base names validate; @host variants validate by base.
     with pytest.raises(ValueError, match="KNOWN_FAILPOINTS"):
         failpoints.load_from_env(
-            {"KRAKEN_FAILPOINTS": "trcker.announce.error=once"}
+            {"KRAKEN_FAILPOINTS": f"{name}=once"}
         )
     n = failpoints.load_from_env(
         {"KRAKEN_FAILPOINTS": "rpc.brownout.slow@10.0.0.1:7610=once"}
@@ -179,11 +184,11 @@ def test_env_arming_rejects_undeclared_names():
     reg.allowed = True
     reg.assert_safe("test")  # api-sourced: fine
     with pytest.raises(ValueError, match="KNOWN_FAILPOINTS"):
-        reg.arm("trcker.announce.error", "once", source="env")
+        reg.arm(name, "once", source="env")
     # Belt-and-braces: an env/yaml-sourced unknown that somehow got
     # armed (older pickle, direct mutation) still fails the boot guard.
-    reg.arm("trcker.announce.error", "once")
-    reg._armed["trcker.announce.error"].source = "env"
+    reg.arm(name, "once")
+    reg._armed[name].source = "env"
     with pytest.raises(failpoints.FailpointConfigError, match="undeclared"):
         reg.assert_safe("test")
 

@@ -68,13 +68,15 @@ def _compile(fn, *shapes):
         pytest.param(8, 8, id="window-8MiB"),
         pytest.param(4, 16, id="window-16MiB"),
         pytest.param(64, 4, id="verify-batch-64x4MiB"),
+        pytest.param(1024, 4, id="whole-tile-1024x4MiB"),
     ],
 )
 def test_tile_kernel_fits_the_chip_at_shipped_batches(one_chip, rows, piece_mib):
     """The natural-layout SHA kernel at the batches the served path hands
     it: one shipped 64 MiB window per piece tier (JaxPieceHasher.
-    hash_pieces) and the agent's largest verify batch (hash_batch, bounded
-    by sub_batch_bytes). Device memory is of the order of the batch.
+    hash_pieces), the agent's largest verify batch (hash_batch, bounded
+    by sub_batch_bytes) and a whole 1024-row tile, where no lane rides
+    the edge block. Device memory is of the order of the batch.
     Padding the rows up to the kernel's 1024-piece tile took 1024 x
     piece_length of temp -- 4 GiB, 8 GiB, and more than the chip has at
     16 MiB pieces."""
@@ -194,35 +196,6 @@ def test_gear_kernel_compiles_at_dispatch_size(one_chip):
         lambda s: _gear_pallas(s, p.mask_strict, p.mask_loose), segs
     )
     assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_packed_route_compiles_at_a_full_tile(one_chip):
-    """``pack_mode: device`` engages only on whole 1024-piece tiles
-    (core/ingest.py _packed_window): the relayout kernel and the
-    pure-rounds kernel at one tile of 4 MiB pieces."""
-    from kraken_tpu.ops.sha256_pallas import (
-        N_TILE,
-        pack_tiles_device,
-        packed_nb,
-        sha256_packed_tiles,
-    )
-
-    plen = 4 * MIB
-    nb = plen // 64
-    natural = jax.ShapeDtypeStruct((N_TILE, plen), jnp.uint8, sharding=one_chip)
-    packed = jax.ShapeDtypeStruct(
-        (1, packed_nb(nb), 16, 8, 128), jnp.uint32, sharding=one_chip
-    )
-    pack = _compile(
-        lambda d: pack_tiles_device(d, nb, interpret=False), natural
-    )
-    rounds = _compile(
-        lambda d: sha256_packed_tiles(d, nb, interpret=False), packed
-    )
-    for compiled in (pack, rounds):
-        assert "tpu_custom_call" in compiled.as_text()
-        mem = compiled.memory_analysis()
-        assert mem.temp_size_in_bytes <= 2 * WINDOW, mem
 
 
 def test_sharded_window_hash_compiles_for_four_chips(topo):

@@ -390,9 +390,8 @@ def test_ingest_sections_construct_ingest_config():
     """Every shipped `ingest:` section must map onto IngestConfig
     through the same from_dict the CLI/assembly use -- a typo'd knob
     must fail here, not at production boot. The shipped defaults must
-    stay SAFE: host pack mode (no feeder cores claimed, mesh-sharded)
-    and classic double buffering, so a config refresh never silently
-    changes the pack path or balloons staging RAM."""
+    stay SAFE: classic double buffering, so a config refresh never
+    silently balloons staging RAM."""
     from kraken_tpu.core.ingest import IngestConfig
 
     seen = 0
@@ -401,16 +400,11 @@ def test_ingest_sections_construct_ingest_config():
         if ic is None:
             continue
         cfg = IngestConfig.from_dict(ic)  # raises on unknown keys
-        assert cfg.pack_mode == "host", (
-            f"{path}: shipped pack_mode must stay 'host' (native/device"
-            " are per-rig opt-ins -- PERF.md 'Pipelined ingest plane')"
-        )
         assert cfg.windows_in_flight == 2, (
             f"{path}: shipped windows_in_flight must stay 2 (double"
             " buffering; staging RAM scales with it)"
         )
         assert 1 << 20 <= cfg.window_bytes <= 1 << 30, path
-        assert cfg.pack_workers >= 0, path
         assert cfg.resume is True, (
             f"{path}: shipped resume must stay ON (pure robustness --"
             " journaled sessions survive origin crashes; flipping it off"

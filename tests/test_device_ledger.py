@@ -353,6 +353,7 @@ def test_queue_stage_is_observed_when_a_window_waits_for_a_worker():
     assert waits[2] >= 0.2 > waits[1]  # one window waited, two did not
     # Out of the overlap ratio: only the window's own stages are summed.
     assert "queue" not in sessions[0].stage_seconds
+    assert "pack" not in sessions[0].stage_seconds
 
 
 # -- one trace from the commit down to the device -----------------------------

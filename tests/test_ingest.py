@@ -412,15 +412,76 @@ def test_ingest_config_validation():
     from kraken_tpu.core.ingest import IngestConfig
 
     cfg = IngestConfig.from_dict(None)
-    assert cfg.pack_mode == "host" and cfg.windows_in_flight == 2
-    with pytest.raises(ValueError):
-        IngestConfig.from_dict({"widow_bytes": 1 << 20})  # typo'd key
+    assert cfg.window_bytes == 64 << 20 and cfg.windows_in_flight == 2
     with pytest.raises(ValueError):
         IngestConfig(windows_in_flight=0)
     with pytest.raises(ValueError):
-        IngestConfig(pack_mode="avx")
-    with pytest.raises(ValueError):
         IngestConfig(window_bytes=4096)
+
+
+@pytest.mark.parametrize(
+    "doc",
+    [
+        pytest.param({"widow_bytes": 1 << 20}, id="typo"),
+        pytest.param({"pack_mode": "host"}, id="pack_mode"),
+        pytest.param({"pack_workers": 1}, id="pack_workers"),
+        pytest.param(
+            {
+                "window_bytes": 67108864, "windows_in_flight": 2,
+                "pack_workers": 1, "pack_mode": "host",
+                "resume": True, "serve_while_ingest": False,
+            },
+            id="section-shipped-before-PR31",
+        ),
+    ],
+)
+def test_ingest_config_rejects_unknown_keys(doc):
+    """A key the section does not have -- a typo, or a knob of the packed
+    route that left with PR 31 -- fails the parse (boot and SIGHUP alike);
+    it is never silently accepted."""
+    from kraken_tpu.core.ingest import IngestConfig
+
+    with pytest.raises(ValueError, match="unknown ingest config keys"):
+        IngestConfig.from_dict(doc)
+
+
+@pytest.mark.parametrize(
+    "rows,dispatched", [(1024, 1024), (2048, 2048), (1025, 2048)]
+)
+def test_whole_tile_window_takes_the_hashers_own_route(
+    tile_kernel_shapes, rows, dispatched
+):
+    """A window of whole 1024-row tiles is no special case: through
+    ``hasher: tpu`` (``use_pallas``) it is one dispatch of the
+    natural-layout tile kernel, and a 1025th row rides the bucket
+    ``hash_pieces`` pads it to (the kernel is the ``tile_kernel_shapes``
+    stand-in)."""
+    import numpy as np
+
+    from kraken_tpu.core.ingest import IngestConfig, IngestPipeline
+    from kraken_tpu.ops.sha256 import JaxPieceHasher
+
+    plen = 1024
+    pipe = IngestPipeline(
+        JaxPieceHasher(use_pallas=True),
+        IngestConfig(window_bytes=rows * plen),
+    )
+    blob = np.random.default_rng(rows).integers(
+        0, 256, size=rows * plen, dtype=np.uint8
+    ).tobytes()
+    ses = pipe.session(plen)
+    buf = ses.begin_window()
+    assert len(buf) == len(blob)
+    buf[:] = blob
+    ses.submit(len(blob))
+    got = ses.finish()
+    assert [bytes(r) for r in got] == [
+        hashlib.sha256(blob[i : i + plen]).digest()
+        for i in range(0, len(blob), plen)
+    ]
+    assert tile_kernel_shapes == [(dispatched, plen)]
+    assert set(ses.stage_seconds) == {"read", "transfer", "hash"}
+    assert ses.stage_seconds["transfer"] == 0.0  # no stage_window here
 
 
 def test_ingest_session_bit_identity():

@@ -74,7 +74,7 @@ def test_sharded_hasher_says_where_its_rows_went(caplog):
 
 # The Pallas variant is opt-in: XLA:CPU needs >5 min to compile the
 # kernel's unrolled body in any CPU mode (see dryrun_multichip docstring);
-# the kernel's correctness home is the real chip (entry() + bench.py).
+# the kernel's correctness home is the real chip (entry() + chip_smoke.py).
 _PALLAS = (
     [False, True] if os.environ.get("RUN_PALLAS_INTERPRET") else [False]
 )

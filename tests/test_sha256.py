@@ -120,28 +120,17 @@ def test_hash_batch_mixed_sizes_bounded_memory():
     [(64, [(1, 8192), (16, 4096)]), (4, [(1, 4096), (1, 8192), (4, 4096)])],
 )
 def test_hash_batch_sends_piece_sized_uniform_groups_to_the_tile_kernel(
-    monkeypatch, ragged_kernel, sub_batch_pieces, want_shapes
+    monkeypatch, ragged_kernel, tile_kernel_shapes, sub_batch_pieces,
+    want_shapes,
 ):
     """On an accelerator (``use_pallas``) the equal-length, piece-sized
     entries of a hash_batch -- an agent's verify batch -- go through the
     tile kernel in rows bucketed to powers of four and bounded by the
     sub-batch budget; everything else goes to the ragged tile kernel, and
-    every digest lands on its own row. The uniform kernel itself only runs
-    on the chip (chip_smoke.py holds it to hashlib there); a stand-in with
-    the same contract records what it was handed."""
-    import jax.numpy as jnp
-
+    every digest lands on its own row (the uniform kernel is the
+    ``tile_kernel_shapes`` stand-in)."""
     from kraken_tpu.ops import sha256 as plane
-    from kraken_tpu.ops import sha256_pallas
 
-    shapes = []
-
-    def tile_kernel(data_u8, piece_length, interpret=None):
-        shapes.append(tuple(data_u8.shape))
-        pad = jnp.asarray(plane._pad_block_for(piece_length))
-        return plane._sha256_uniform(data_u8, pad, piece_length // 64)
-
-    monkeypatch.setattr(sha256_pallas, "hash_pieces_device", tile_kernel)
     monkeypatch.setattr(plane, "_TILE_KERNEL_MIN_BYTES", 4096)
     h = plane.JaxPieceHasher(
         use_pallas=True, sub_batch_bytes=sub_batch_pieces * 4096
@@ -154,7 +143,7 @@ def test_hash_batch_sends_piece_sized_uniform_groups_to_the_tile_kernel(
     got = h.hash_batch(pieces)
     for row, p in zip(got, pieces):
         assert bytes(row) == hashlib.sha256(p).digest()
-    assert sorted(shapes) == want_shapes
+    assert sorted(tile_kernel_shapes) == want_shapes
 
 
 # -- the ragged tile kernel (interpret mode) --------------------------------
