@@ -35,6 +35,7 @@ from kraken_tpu.origin.client import ClusterClient
 from kraken_tpu.placement import HostList, Ring
 from kraken_tpu.placement.healthcheck import PassiveFilter
 from kraken_tpu.store.cleanup import CleanupConfig
+from kraken_tpu.utils import pushsteps
 from kraken_tpu.utils.structlog import setup_json_logging
 
 
@@ -73,6 +74,10 @@ async def _run_until_signal(node, describe: dict,
     loop.add_signal_handler(signal.SIGTERM, on_sigterm)
     loop.add_signal_handler(signal.SIGHUP, reload_config)
 
+    # Before anything is started: every to_thread call and the HTTP
+    # server's own work are then steps of the push-step ledger.
+    pushsteps.install(loop)
+    pushsteps.name_http_server()
     await node.start()
     describe["addr"] = node.addr
     # Agents with the docker-registry read endpoint enabled bind it on its

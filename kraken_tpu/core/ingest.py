@@ -55,6 +55,7 @@ from kraken_tpu.core.hasher import (
     profiler_annotation,
 )
 from kraken_tpu.utils import failpoints
+from kraken_tpu.utils.pushsteps import push_step, stepped
 
 _log = logging.getLogger("kraken.ingest")
 
@@ -81,14 +82,21 @@ class timed_stage:
     """``with timed_stage("hash", bill):`` -- one clock reading serves the
     histogram, the session's stage wall (``bill(stage, seconds)``) and a
     ``kraken.<plane>.<stage>`` annotation in the profiler's file. A stage
-    that raises is not billed. One thread, no ``await`` inside."""
+    that raises is not billed. One thread, no ``await`` inside. The
+    stage is also a step of the push-step ledger (utils/pushsteps.py),
+    ``<plane>.<stage>`` unless ``step`` names it: that is where its cpu
+    clock goes, and a raise is booked there."""
 
-    __slots__ = ("_stage", "_bill", "_plane", "_annotation", "_t0", "seconds")
+    __slots__ = (
+        "_stage", "_bill", "_plane", "_annotation", "_step", "_t0", "seconds",
+    )
 
-    def __init__(self, stage: str, bill=record_stage, plane: str = "ingest"):
+    def __init__(self, stage: str, bill=record_stage, plane: str = "ingest",
+                 step: str | None = None):
         self._stage = stage
         self._bill = bill
         self._plane = plane
+        self._step = push_step(step or f"{plane}.{stage}")
         self.seconds = 0.0
 
     def __enter__(self) -> "timed_stage":
@@ -96,6 +104,7 @@ class timed_stage:
             f"kraken.{self._plane}.{self._stage}"
         )
         self._annotation.__enter__()
+        self._step.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -103,6 +112,7 @@ class timed_stage:
         if exc_type is None:
             self.seconds = time.perf_counter() - self._t0
             self._bill(self._stage, self.seconds)
+        self._step.__exit__(exc_type, exc, tb)
         self._annotation.__exit__(exc_type, exc, tb)
         return False
 
@@ -403,6 +413,7 @@ class IngestSession:
         self.stage_seconds[stage] += seconds
         record_stage(stage, seconds)
 
+    @stepped("ingest.window")
     def _process(self, lease, nbytes: int, t_submit: float) -> np.ndarray:
         queue_s = time.perf_counter() - t_submit
         self.queue_seconds += queue_s

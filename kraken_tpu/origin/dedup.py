@@ -41,6 +41,7 @@ from kraken_tpu.ops.minhash import (
 )
 from kraken_tpu.store import CAStore, Metadata, register_metadata
 from kraken_tpu.utils.metrics import REGISTRY
+from kraken_tpu.utils.pushsteps import push_await, stepped
 
 
 def _record_dedup_stage(stage: str, seconds: float) -> None:
@@ -349,6 +350,7 @@ class DedupIndex:
         except ValueError:
             return None
 
+    @stepped("dedup.pass")
     def add_blob_sync(self, d: Digest) -> ChunkSketchMetadata:
         """Chunk + sketch + index blob ``d`` (idempotent; loads the sidecar
         if one exists). Raises KeyError if the blob is not in cache."""
@@ -452,8 +454,11 @@ class DedupIndex:
             self._publish_stats()
 
     async def add_blob(self, d: Digest) -> None:
-        await asyncio.to_thread(self.add_blob_sync, d)
+        await push_await(
+            "dedup.pass", asyncio.to_thread(self.add_blob_sync, d)
+        )
 
+    @stepped("dedup.remove")
     def remove_sync(self, d: Digest) -> bool:
         """Drop blob ``d`` from the index and the corpus accounting (called
         on DELETE and on cache eviction). The sidecar may already be gone

@@ -22,6 +22,7 @@ from kraken_tpu.core.digest import Digest
 from kraken_tpu.core.hasher import PieceHasher, get_hasher
 from kraken_tpu.core.metainfo import MetaInfo
 from kraken_tpu.store import CAStore, Metadata, register_metadata
+from kraken_tpu.utils.pushsteps import stepped
 
 
 @register_metadata
@@ -86,6 +87,7 @@ class Generator:
         # read-then-hash loop below. None = serial path.
         self.pipeline = pipeline
 
+    @stepped("metainfo.read")
     def get_cached(self, d: Digest) -> MetaInfo | None:
         md = self.store.get_metadata(d, TorrentMetaMetadata)
         return md.metainfo if md else None
@@ -166,6 +168,7 @@ class Generator:
         """Off-loop :meth:`generate_sync` (reads + hashes a whole blob)."""
         return await asyncio.to_thread(self.generate_sync, d)
 
+    @stepped("commit.adopt")
     def adopt(
         self, d: Digest, size: int, piece_length: int, piece_hashes: bytes
     ) -> MetaInfo:

@@ -52,6 +52,9 @@ from kraken_tpu.utils import failpoints
 from kraken_tpu.utils.deadline import Deadline
 from kraken_tpu.utils.lameduck import LameduckMixin
 from kraken_tpu.utils.metrics import REGISTRY, FailureMeter
+from kraken_tpu.utils.pushsteps import (
+    handler_step, push_await, push_call, push_step,
+)
 
 _log = logging.getLogger("kraken.origin")
 
@@ -706,47 +709,53 @@ class OriginServer(LameduckMixin):
 
     # -- upload flow -------------------------------------------------------
 
+    @handler_step("create")
     async def _start_upload(self, req: web.Request) -> web.Response:
         if self.lameduck:
             # New write sessions are new WORK; a draining node refuses
             # them so the pusher retries a healthy replica now instead
             # of losing a half-streamed upload at the hard stop.
             raise self.drain_unavailable()
-        uid = self.store.create_upload()
-        # Running digest over sequentially-streamed upload bytes: when the
-        # whole upload arrives in offset order (the overwhelmingly common
-        # case -- docker pushes and our own clients stream one PATCH),
-        # commit verifies against THIS digest instead of re-reading and
-        # re-hashing the entire blob. Out-of-order or concurrent PATCHes
-        # just invalidate the tracker and commit falls back to the
-        # re-read. Entries are removed at commit; ABANDONED uploads
-        # (client crashed before committing) age out on the purge timer
-        # (_purge_upload_digests_loop), so they can't permanently eat the
-        # cap and silently disable the fast path for every future upload.
-        # At the hard cap the OLDEST idle tracker is evicted (metered,
-        # never a silent drop). Falling back is always correct.
-        if len(self._upload_digests) >= self.UPLOAD_DIGEST_CAP:
-            victims = sorted(
-                (
-                    (t.created, k)
-                    for k, t in self._upload_digests.items()
-                    if not t.active
-                ),
-            )
-            if victims:
-                self._drop_upload_digest(victims[0][1], reason="capacity")
-        if len(self._upload_digests) < self.UPLOAD_DIGEST_CAP:
-            self._upload_digests[uid] = _UploadDigest(
-                piece_length=self._stream_piece_length,
-                pool=self._stream_hash_pool,
-                pipeline=self._ingest_pipeline,
-            )
+        with push_step("create"):
+            uid = self.store.create_upload()
+            # Running digest over sequentially-streamed upload bytes: when
+            # the whole upload arrives in offset order (the overwhelmingly
+            # common case -- docker pushes and our own clients stream one
+            # PATCH), commit verifies against THIS digest instead of
+            # re-reading and re-hashing the entire blob. Out-of-order or
+            # concurrent PATCHes just invalidate the tracker and commit
+            # falls back to the re-read. Entries are removed at commit;
+            # ABANDONED uploads (client crashed before committing) age out
+            # on the purge timer (_purge_upload_digests_loop), so they
+            # can't permanently eat the cap and silently disable the fast
+            # path for every future upload. At the hard cap the OLDEST idle
+            # tracker is evicted (metered, never a silent drop). Falling
+            # back is always correct.
+            if len(self._upload_digests) >= self.UPLOAD_DIGEST_CAP:
+                victims = sorted(
+                    (
+                        (t.created, k)
+                        for k, t in self._upload_digests.items()
+                        if not t.active
+                    ),
+                )
+                if victims:
+                    self._drop_upload_digest(
+                        victims[0][1], reason="capacity"
+                    )
+            if len(self._upload_digests) < self.UPLOAD_DIGEST_CAP:
+                self._upload_digests[uid] = _UploadDigest(
+                    piece_length=self._stream_piece_length,
+                    pool=self._stream_hash_pool,
+                    pipeline=self._ingest_pipeline,
+                )
         return web.Response(text=uid)
 
     UPLOAD_DIGEST_TTL_SECONDS = 6 * 3600.0  # matches upload-spool lifetime
     UPLOAD_DIGEST_PURGE_SECONDS = 300.0  # timer tick for the TTL sweep
     UPLOAD_DIGEST_CAP = 4096  # hard bound on tracked sessions
 
+    @handler_step("patch")
     async def _patch_upload(self, req: web.Request) -> web.Response:
         uid = req.match_info["uid"]
         try:
@@ -765,12 +774,15 @@ class OriginServer(LameduckMixin):
         # at or below the size stay allowed (duplicate retry of a PATCH
         # whose response was lost: same bytes, commit re-reads).
         if offset > 0 and self.resume_enabled:
-            doc = await asyncio.to_thread(self.store.read_upload_session, uid)
+            doc = await push_await("patch.guard", asyncio.to_thread(
+                push_call, "patch.guard",
+                self.store.read_upload_session, uid,
+            ))
             if doc is not None:
                 try:
-                    size = await asyncio.to_thread(
-                        self.store.upload_size, uid
-                    )
+                    size = await push_await("patch.guard", asyncio.to_thread(
+                        push_call, "patch.guard", self.store.upload_size, uid,
+                    ))
                 except UploadNotFoundError:
                     raise web.HTTPNotFound(text="unknown upload")
                 if offset > size:
@@ -781,7 +793,8 @@ class OriginServer(LameduckMixin):
         # handle): one PATCH may carry an arbitrarily large body without
         # O(body) RAM or per-chunk reopen syscalls.
         try:
-            f = self.store.open_upload_file(uid)
+            with push_step("patch.open"):
+                f = self.store.open_upload_file(uid)
         except UploadNotFoundError:
             raise web.HTTPNotFound(text="unknown upload")
         tracker = self._upload_digests.get(uid)
@@ -825,16 +838,21 @@ class OriginServer(LameduckMixin):
                     # bytes just written are pushed out of the userspace
                     # buffer FIRST, so the journaled offset never claims
                     # bytes a process crash could lose.
-                    self._journal_upload(uid, tracker, f)
+                    with push_step("patch.journal"):
+                        self._journal_upload(uid, tracker, f)
 
             async for chunk in req.content.iter_chunked(1 << 20):
                 pending.append(chunk)
                 pending_bytes += len(chunk)
                 if pending_bytes >= (8 << 20):
                     bufs, pending, pending_bytes = pending, [], 0
-                    await asyncio.to_thread(flush, bufs)
+                    await push_await("patch.flush", asyncio.to_thread(
+                        push_call, "patch.flush", flush, bufs,
+                    ))
             if pending:
-                await asyncio.to_thread(flush, pending)
+                await push_await("patch.flush", asyncio.to_thread(
+                    push_call, "patch.flush", flush, pending,
+                ))
         except BaseException:
             # A failed PATCH (client disconnect, write error) leaves the
             # tracker's position ahead of -- or ambiguous against -- the
@@ -854,7 +872,8 @@ class OriginServer(LameduckMixin):
                     import errno
 
                     raise OSError(errno.ENOSPC, "failpoint origin.patch.close")
-                f.close()
+                with push_step("patch.close"):
+                    f.close()
             except BaseException:
                 # Deferred write error surfacing at close (ENOSPC on a
                 # buffered file): the hashed byte count exceeds what the
@@ -1010,6 +1029,7 @@ class OriginServer(LameduckMixin):
             raise _SessionUnadoptable(f"replay failed: {e}")
         return tracker
 
+    @handler_step("commit")
     async def _commit(self, req: web.Request) -> web.Response:
         from kraken_tpu.utils.slo import CANARY_NAMESPACE, SLO
 
@@ -1064,7 +1084,8 @@ class OriginServer(LameduckMixin):
         with trace.span("origin.ingest.commit", digest=d.hex[:12]) as sp:
             if tracker is not None:
                 try:
-                    size = self.store.upload_size(uid)
+                    with push_step("commit.size"):
+                        size = self.store.upload_size(uid)
                 except UploadNotFoundError:
                     raise web.HTTPNotFound(text="unknown upload")
                 precomputed = tracker.result(size)
@@ -1076,9 +1097,12 @@ class OriginServer(LameduckMixin):
                     # "join": what the client's commit waits for here --
                     # the queue and the hash of the blob's last window.
                     t_join = time.perf_counter()
-                    piece_hashes = await asyncio.to_thread(
-                        tracker.piece_hashes,
-                        size, self.generator.piece_lengths.piece_length(size),
+                    piece_hashes = await push_await(
+                        "commit.join", asyncio.to_thread(
+                            push_call, "commit.join", tracker.piece_hashes,
+                            size,
+                            self.generator.piece_lengths.piece_length(size),
+                        ),
                     )
                     record_stage("join", time.perf_counter() - t_join)
             early_metainfo = None
@@ -1095,10 +1119,12 @@ class OriginServer(LameduckMixin):
                 # before the commit finishes; promote_partial() below
                 # repoints the torrent at the cache path once it does.
                 try:
-                    early_metainfo = await asyncio.to_thread(
-                        self.generator.adopt, d, size,
-                        self.generator.piece_lengths.piece_length(size),
-                        piece_hashes,
+                    early_metainfo = await push_await(
+                        "commit.adopt", asyncio.to_thread(
+                            self.generator.adopt, d, size,
+                            self.generator.piece_lengths.piece_length(size),
+                            piece_hashes,
+                        ),
                     )
                     self.scheduler.seed_partial(
                         early_metainfo, ns, self.store.upload_path(uid)
@@ -1122,12 +1148,14 @@ class OriginServer(LameduckMixin):
             def commit_stage() -> float:
                 # Timed on the worker thread, where the profiler
                 # annotation can wrap exactly the verify + rename.
-                with timed_stage("commit") as stage:
+                with timed_stage("commit", step="commit.rename") as stage:
                     self.store.commit_upload(uid, d, precomputed=precomputed)
                 return stage.seconds
 
             try:
-                commit_s = await asyncio.to_thread(commit_stage)
+                commit_s = await push_await(
+                    "commit.rename", asyncio.to_thread(commit_stage)
+                )
             except UploadNotFoundError:
                 await self._abort_quorum_push(quorum_push)
                 await self._retract_early_publish(d, early_metainfo)
@@ -1165,10 +1193,12 @@ class OriginServer(LameduckMixin):
                         "cpu", size, len(piece_hashes) // 32
                     )
                 if metainfo is None:  # early publish already adopted
-                    metainfo = await asyncio.to_thread(
-                        self.generator.adopt, d, size,
-                        self.generator.piece_lengths.piece_length(size),
-                        piece_hashes,
+                    metainfo = await push_await(
+                        "commit.adopt", asyncio.to_thread(
+                            self.generator.adopt, d, size,
+                            self.generator.piece_lengths.piece_length(size),
+                            piece_hashes,
+                        ),
                     )
             await self._post_commit(ns, d, metainfo=metainfo)
             record_stage("publish", time.perf_counter() - t_publish)
@@ -1202,13 +1232,15 @@ class OriginServer(LameduckMixin):
         # Remember the namespace beside the blob: the repair path
         # re-replicates long after the upload request (and its namespace)
         # is gone (store/metadata.py NamespaceMetadata).
-        await asyncio.to_thread(
-            self.store.set_metadata, d, NamespaceMetadata(ns)
-        )
+        await push_await("commit.namespace", asyncio.to_thread(
+            push_call, "commit.namespace",
+            self.store.set_metadata, d, NamespaceMetadata(ns),
+        ))
         if metainfo is None:
             metainfo = await self.generator.generate(d)
         if self.scheduler is not None:
-            self.scheduler.seed(metainfo, ns)
+            with push_step("commit.seed"):
+                self.scheduler.seed(metainfo, ns)
         # Canary probes (utils/canary.py) are EPHEMERAL by contract:
         # TTL-reaped minutes later, never durable.  Writeback would
         # accumulate ~360 MB/day/agent of permanent backend residue,
@@ -1219,9 +1251,11 @@ class OriginServer(LameduckMixin):
         if ns == CANARY_NAMESPACE:
             return
         if self.writeback is not None:
-            self.writeback.enqueue(ns, d)
-        self._enqueue_replication(ns, d)
-        self._schedule_dedup(d)
+            self.writeback.enqueue(ns, d)  # commit.pin, commit.retry_add
+        with push_step("commit.replicate"):
+            self._enqueue_replication(ns, d)
+        with push_step("commit.dedup_schedule"):
+            self._schedule_dedup(d)
 
     async def _adopt(self, req: web.Request) -> web.Response:
         """Associate an EXISTING blob with a (new) namespace -- the server
@@ -1981,6 +2015,7 @@ class OriginServer(LameduckMixin):
 
         return await blob_response(req, self.store, d)
 
+    @handler_step("metainfo")
     async def _metainfo(self, req: web.Request) -> web.Response:
         await self._brownout_gate()
         ns = urllib.parse.unquote(req.match_info["ns"])
@@ -1989,7 +2024,9 @@ class OriginServer(LameduckMixin):
         # serve-while-ingest window the metainfo is published (and the
         # torrent seeding from the spool) while the blob is NOT yet in
         # the cache -- agents must be able to start their pull now.
-        metainfo = await asyncio.to_thread(self.generator.get_cached, d)
+        metainfo = await push_await(
+            "metainfo.read", asyncio.to_thread(self.generator.get_cached, d)
+        )
         if metainfo is not None and self.scheduler is not None:
             try:
                 # Metainfo fetch precedes a swarm download: make sure we
@@ -2069,13 +2106,16 @@ class OriginServer(LameduckMixin):
             body=recipe.serialize(), content_type="application/json"
         )
 
+    @handler_step("delete")
     async def _delete(self, req: web.Request) -> web.Response:
         d = self._digest(req)
         if self.dedup is not None:
             # Before the blob goes: the sidecar must still be readable for
             # the ledger adjustment.
-            await self.dedup.remove(d)
-        await asyncio.to_thread(self.store.delete_cache_file, d)
+            await push_await("dedup.remove", self.dedup.remove(d))
+        await push_await("delete.unlink", asyncio.to_thread(
+            push_call, "delete.unlink", self.store.delete_cache_file, d,
+        ))
         if self.scheduler is not None:
             # AFTER the unlink: unseeding first would leave a window where
             # an inbound handshake resurrects the control while the blob

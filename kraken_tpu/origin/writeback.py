@@ -16,6 +16,7 @@ from kraken_tpu.core.digest import Digest
 from kraken_tpu.persistedretry import Manager as RetryManager, Task
 from kraken_tpu.store import CAStore
 from kraken_tpu.store.metadata import pin, unpin
+from kraken_tpu.utils.pushsteps import push_await, push_step, stepped
 
 KIND = "writeback"
 
@@ -44,15 +45,18 @@ class WritebackExecutor:
         """Queue a blob for backend upload; pin it against eviction."""
         if self.backends.try_get_client(namespace) is None:
             return  # namespace has no durable backend configured
-        pin(self.store, d, KIND)
+        with push_step("commit.pin"):
+            pin(self.store, d, KIND)
         # Digest-first key: the unpin logic prefix-scans for other pending
         # writebacks of the same blob (a cross-repo mount enqueues a second
         # namespace's writeback for the same bytes).
-        self.retry.add(
-            Task(kind=KIND, key=f"{d.hex}:{namespace}",
-                 payload={"namespace": namespace, "digest": d.hex})
-        )
+        with push_step("commit.retry_add"):
+            self.retry.add(
+                Task(kind=KIND, key=f"{d.hex}:{namespace}",
+                     payload={"namespace": namespace, "digest": d.hex})
+            )
 
+    @stepped("writeback.execute")
     async def _execute(self, task: Task) -> None:
         namespace = task.payload["namespace"]
         d = Digest.from_hex(task.payload["digest"])
@@ -64,9 +68,14 @@ class WritebackExecutor:
         # in the upload spool (the export escape hatch), upload, drop it.
         path = self.store.cache_path(d)
         uploaded = False
-        if os.path.exists(path):
+        with push_step("writeback.exists"):
+            exists = os.path.exists(path)
+        if exists:
             try:
-                await client.upload_file(namespace, d.hex, path)
+                await push_await(
+                    "writeback.copy",
+                    client.upload_file(namespace, d.hex, path),
+                )
                 uploaded = True
             except FileNotFoundError:
                 # A chunk-tier conversion unlinked the flat file between
@@ -87,5 +96,8 @@ class WritebackExecutor:
         # expose the bytes to eviction while a second namespace's -- from
         # a cross-repo mount -- is still queued). The current task counts
         # until the retry manager marks it done, hence <= 1.
-        if self.retry.store.count_pending(KIND, f"{d.hex}:") <= 1:
-            unpin(self.store, d, KIND)
+        with push_step("writeback.count_pending"):
+            last = self.retry.store.count_pending(KIND, f"{d.hex}:") <= 1
+        if last:
+            with push_step("writeback.unpin"):
+                unpin(self.store, d, KIND)

@@ -59,6 +59,7 @@ from kraken_tpu.utils.bandwidth import BandwidthLimiter
 from kraken_tpu.utils.bufpool import BufferPool
 from kraken_tpu.utils.dedup import RequestCoalescer
 from kraken_tpu.utils.metrics import REGISTRY, FailureMeter
+from kraken_tpu.utils.pushsteps import stepped
 from kraken_tpu.utils.slo import CANARY_NAMESPACE, SLO
 
 _log = logging.getLogger("kraken.p2p")
@@ -928,6 +929,7 @@ class Scheduler:
                 t.add_done_callback(self._announce_tasks.discard)
             await asyncio.sleep(cfg.announce_tick)
 
+    @stepped("announce")
     async def _announce_once(self, ctl: _TorrentControl) -> None:
         h = ctl.torrent.info_hash
         complete = ctl.torrent.complete()

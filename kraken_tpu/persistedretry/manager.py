@@ -12,6 +12,7 @@ import time
 from typing import Awaitable, Callable, Optional
 
 from kraken_tpu.utils.backoff import Backoff
+from kraken_tpu.utils.pushsteps import stepped
 
 _log = logging.getLogger("kraken.persistedretry")
 
@@ -219,6 +220,7 @@ class Manager:
         depths.update(self.store.count_by_kind())
         return depths
 
+    @stepped("retry.poll")
     async def run_once(self, now: float | None = None) -> int:
         """One poll cycle; returns number of tasks that succeeded."""
         now = time.time() if now is None else now

@@ -14,6 +14,7 @@ device-section ledger's ``hasher_device_*`` (core/hasher.py).
 from __future__ import annotations
 
 import logging
+import os
 import threading
 import time
 from typing import Callable, Iterable
@@ -67,6 +68,12 @@ class Counter(_Metric):
         key = self._key(labels)
         with self._lock:
             self._values[key] = self._values.get(key, 0.0) + value
+
+    def set(self, value: float, **labels: str) -> None:
+        """For a total that something else keeps (the kernel's bill of
+        this process) and a scrape hook mirrors here."""
+        with self._lock:
+            self._values[self._key(labels)] = float(value)
 
     def value(self, **labels: str) -> float:
         with self._lock:
@@ -256,6 +263,88 @@ class Registry:
 REGISTRY = Registry()
 
 
+def thread_class(name: str) -> str:
+    """loop | worker | ingest | other, from a Python thread's name."""
+    if name == "MainThread":
+        return "loop"
+    if name.startswith("asyncio_"):
+        return "worker"  # the loop's default executor: asyncio.to_thread
+    if name.startswith("ingest"):
+        return "ingest"
+    return "other"
+
+
+def collect_process(
+    registry: Registry = REGISTRY, proc: str = "/proc/self",
+) -> None:
+    """The process's own bill, read when ``/metrics`` is rendered and never
+    on a request's path: CPU seconds and context switches from
+    ``getrusage``, and CPU seconds by thread class from
+    ``<proc>/task/<tid>/stat`` of the live Python threads (``MainThread``
+    is ``loop``, ``asyncio_*`` ``worker``, ``ingest*`` ``ingest``;
+    ``other`` is the process total less those three, so it holds the
+    runtime's own threads and every thread that has exited). With the
+    push-step ledger (utils/pushsteps.py) over the same two scrapes: CPU a
+    push, times a push gave the interpreter lock up, and the share of the
+    CPU that the named steps cover. Where ``<proc>`` cannot be read the
+    thread family is absent, not zero."""
+    import resource
+
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    cpu = registry.counter(
+        "process_cpu_seconds_total",
+        "CPU seconds of this process (mode=user|system), getrusage",
+    )
+    cpu.set(ru.ru_utime, mode="user")
+    cpu.set(ru.ru_stime, mode="system")
+    switches = registry.counter(
+        "process_context_switches_total",
+        "Context switches of this process (kind=voluntary: a thread gave"
+        " the CPU up, as when it waits for the interpreter lock;"
+        " involuntary: it was preempted), getrusage",
+    )
+    switches.set(ru.ru_nvcsw, kind="voluntary")
+    switches.set(ru.ru_nivcsw, kind="involuntary")
+    tick = os.sysconf("SC_CLK_TCK")
+    classes = {c: [0.0, 0.0] for c in ("loop", "worker", "ingest")}
+    try:
+        for t in threading.enumerate():
+            if t.native_id is None:
+                continue
+            try:
+                with open(f"{proc}/task/{t.native_id}/stat") as f:
+                    # comm may hold spaces: the fields after its ")".
+                    fields = f.read().rsplit(")", 1)[1].split()
+            except FileNotFoundError:
+                if os.path.isdir(f"{proc}/task"):
+                    continue  # the thread ended since enumerate()
+                raise
+            row = classes.get(thread_class(t.name))
+            if row is not None:
+                row[0] += int(fields[11]) / tick  # utime
+                row[1] += int(fields[12]) / tick  # stime
+    except (OSError, IndexError, ValueError):
+        return
+    by_class = registry.counter(
+        "process_thread_cpu_seconds_total",
+        "CPU seconds of this process by thread class (class=loop|worker|"
+        "ingest|other, mode=user|system), /proc/self/task/*/stat; other ="
+        " the process total less the named classes",
+    )
+    for i, (mode, total) in enumerate(
+        (("user", ru.ru_utime), ("system", ru.ru_stime))
+    ):
+        for name, row in classes.items():
+            by_class.set(row[i], **{"class": name}, mode=mode)
+        by_class.set(
+            max(0.0, total - sum(row[i] for row in classes.values())),
+            **{"class": "other"}, mode=mode,
+        )
+
+
+REGISTRY.add_scrape_hook(collect_process)
+
+
 def record_hash_pool_metrics(
     pool: str, workers: int, running: int, queued: int,
     registry: Registry = REGISTRY,
@@ -379,6 +468,8 @@ def instrument_app(app, component: str, registry: Registry = REGISTRY):
     digests in URLs would explode cardinality)."""
     from aiohttp import web
 
+    from kraken_tpu.utils.pushsteps import push_loop
+
     requests = registry.counter(
         "http_requests_total", "HTTP requests by endpoint and status")
     latency = registry.histogram(
@@ -388,6 +479,13 @@ def instrument_app(app, component: str, registry: Registry = REGISTRY):
 
     @web.middleware
     async def middleware(request, handler):
+        # The push-step ledger's "<handler>.rest": what this request costs
+        # the loop outside the steps its handler names (span and ids, the
+        # counters below, the handler's own glue).
+        step = getattr(request.match_info.handler, "push_step", "http")
+        return await push_loop(step + ".rest", handle(request, handler))
+
+    async def handle(request, handler):
         from kraken_tpu.utils import trace
 
         resource = request.match_info.route.resource
@@ -540,7 +638,6 @@ def instrument_app(app, component: str, registry: Registry = REGISTRY):
         # second (PERF.md section 3): ask for tenths of a second there.
         import asyncio
         import glob
-        import os
         import tempfile
 
         try:
@@ -797,8 +894,6 @@ def instrument_app(app, component: str, registry: Registry = REGISTRY):
         # without the gate one curl could arm castore.commit=always on a
         # production origin. Disarming is always allowed (it only ever
         # makes a node healthier).
-        import os
-
         from kraken_tpu.utils.failpoints import FAILPOINTS, allow
 
         try:
