@@ -9,10 +9,19 @@ rate. Here the whole block chain runs inside one ``pallas_call``:
 - grid = (piece_tiles, block_groups). Pallas revisits the same output
   block for every ``b`` step of a tile, so the running [8, N] hash state
   lives in VMEM for the whole chain -- written back to HBM once per tile.
-- the 48 schedule extensions + 64 rounds are fully unrolled straight-line
-  vector ops on [N]-wide uint32 lanes (N=1024 = a full 8x128 VPU tile per
-  op). Unlike XLA:CPU, Mosaic compiles the ~6k-op body without
-  pathological simplification passes.
+- within one compression the 48 schedule extensions + 64 rounds are fully
+  unrolled straight-line vector ops on [N]-wide uint32 lanes (N=1024 = a
+  full 8x128 VPU tile per op; ``_rounds64``, ~3.4k equations). Unlike
+  XLA:CPU, Mosaic compiles the body without pathological simplification
+  passes.
+- the blocks of a grid step are a ROLLED loop (``jax.lax.fori_loop``) over
+  message words parked in a VMEM scratch, in both kernels: a shape traces
+  ONE compression (~5.9k equations with the relayout), not one a block.
+  What a shape costs a start is Python tracing and lowering under the
+  interpreter lock, which no compile cache holds: with the uniform
+  kernel's eight blocks and its padding block unrolled (nine copies,
+  ~32k equations) that was 7.1-8.0 s a shape on an idle chip machine;
+  rolled it is 1.2-1.5 s (PR 34).
 - the message schedule runs as a 16-word ring (w[i+16] computed in place
   right after round i consumes w[i]), keeping ~24 vector registers live
   instead of 72 -- a fully materialized 64-entry schedule spills.
@@ -26,7 +35,7 @@ a block below is one 64-byte step of every lane of a tile, v5e:
 
 | kernel | takes | measured |
 |---|---|---|
-| ``sha256_tiles`` | equal-length rows, natural bytes; chain length and padding block compiled in (a Mosaic compile per length) | ~75 GB/s a full tile = 0.87 us a block (r3, 2026-07-29); one 4 MiB piece a dispatch 60 ms = 0.92 us a block (PR 24); a 16 x 4 MiB window 74 ms, copy included = 1.13 us a block (PR 21) |
+| ``sha256_tiles`` | equal-length rows, natural bytes; chain length and padding block compiled in (a Mosaic compile per length) | block loop rolled (PR 34, 2026-10-04, idle chip): one 4 MiB piece a dispatch 65.8 ms = 1.00 us a block; a 16 x 4 MiB window 64.2 ms = 0.98 us a block, 76.9 ms = 1.17 with the copy in and the read back; 8 x 8 MiB and 4 x 16 MiB 0.97 and 0.98; a full tile of 1 MiB rows 17.1 ms = 1.04 us a block (~63 GB/s); a shape's first use 1.7-2.4 s (trace 0.9-1.2, lower 0.3, Mosaic 0.4-0.8 cold). Unrolled, same day and chip: 0.94, 0.92 (1.13), 0.91, 0.93 and 0.98 us a block, first use 8.4-9.3 s; ~75 GB/s a full tile = 0.87 us a block (r3, 2026-07-29) |
 | ``sha256_ragged_slab`` (``sha256_ragged_tiles`` drives it) | SHA-padded rows of any lengths, a block count a lane, the state carried from call to call: two compiled shapes for every length and row count | one row, 512 blocks a call: 1.01-1.09 us a block from 1 to 4 MiB, copy, dispatch and read-back included, and ~1.5 ms a chain before the first block; a tile of 1024 rows, 64 blocks a call: 9.1-9.6 us a block, bound by the copy of 64 KiB a block (PR 26, 2026-10-01; the XLA scan it replaced: 199-211 us a block at one row, 30 at 8-16) |
 
 The uniform kernel's input layout (docs/PERF_HISTORY.md has the measured
@@ -34,7 +43,9 @@ analysis, v5e 2026-07-29) is the **natural** ``[M, piece_len] uint8`` the
 store hands over. The kernel transposes each [N_TILE, _KB*64] BYTE slab in
 VMEM (u8 granularity) and recombines the four byte planes into big-endian
 words with vector shifts -- the BE combine is the byteswap, for free.
-**~75 GB/s/chip** measured (median of repeated runs, r3). The round-2
+**~75 GB/s/chip** measured (median of repeated runs, r3, the block loop
+unrolled; a tile of 1,024 x 1 MiB on PR 34's chip: 67 GB/s unrolled, 63
+rolled). The round-2
 u32-word transpose managed only ~18: Mosaic's 32-bit transpose was the
 binding constraint; the u8 transpose of the same bytes runs ~4x faster and
 the u16 variant sits between (~22). Older alternatives -- per-sublane-group
@@ -57,7 +68,8 @@ from kraken_tpu.ops.sha256 import _H0, _K, _pad_block_for
 
 # Pieces per grid tile, laid out as an explicit (sublane, lane) = (8, 128)
 # VPU tile so every round op maps to whole vector registers. VMEM per grid
-# step: in block KB*16*N*4 = 512 KiB (x2 double buffer) + state 32 KiB.
+# step: in block N*KB*64 = 512 KiB (x2 double buffer) + state 32 KiB + the
+# parked words' scratch (KB+1)*16*N*4 = 576 KiB (512 in the ragged kernel).
 _SUB = 8
 _LANES = 128
 N_TILE = _SUB * _LANES
@@ -109,72 +121,80 @@ def _rounds64(state, wget):
     return [s + v for s, v in zip(state, (a, b, c, d, e, f, g, h))]
 
 
-def _make_kernel(nb_real: int, pad_words: np.ndarray):
-    """Grid-step kernel for a chain of ``nb_real`` data blocks.
+def _park_words(blk_ref, w_ref):
+    """Relayout a natural [N_TILE, _KB*64] uint8 slab into w_ref's first
+    _KB*16 [_SUB, _LANES] big-endian message words, block-major.
 
-    The shared SHA padding block is folded from compile-time constants
-    (``pad_words``) after the last real block -- it never exists in HBM.
-    blk_ref is a natural [N_TILE, _KB*64] uint8 BYTE slab, transposed in
-    VMEM at u8 granularity. out_ref: [1, 8, _SUB, _LANES], revisited
-    across the block-group axis (carries the running state in VMEM).
+    Piece-major -> word-major as ONE up-front BYTE transpose. Granularity
+    matters enormously on v5e (measured r3, same kernel otherwise): u8
+    transpose ~68 GB/s end-to-end, u16 ~22, u32 ~18. Recombining the four
+    byte planes into big-endian words costs 3 shifts + 3 ors per word and
+    IS the byteswap -- the LE->BE conversion falls out of plane order.
+    The words are parked in VMEM so that the block loop over them can be
+    ROLLED: a kernel traces one compression instead of one a block, a
+    fifth of the Python tracing and of Mosaic's work on every start.
     """
-    ngroups = (nb_real + _KB - 1) // _KB
-
-    def kernel(blk_ref, out_ref):
-        b = pl.program_id(1)
-
-        @pl.when(b == 0)
-        def _init():
-            for i in range(8):
-                out_ref[0, i, :, :] = jnp.full((_SUB, _LANES), _H0[i], jnp.uint32)
-
-        state = [out_ref[0, i, :, :] for i in range(8)]
-        # Piece-major -> word-major as ONE up-front BYTE transpose.
-        # Granularity matters enormously on v5e (measured r3, same
-        # kernel otherwise): u8 transpose ~68 GB/s end-to-end, u16
-        # ~22, u32 ~18. Recombining the four byte planes into
-        # big-endian words costs 3 shifts + 3 ors per word and IS the
-        # byteswap -- the LE->BE conversion falls out of plane order.
-        t8 = jnp.transpose(blk_ref[...], (1, 0)).reshape(
-            _KB, 16, 4, _SUB, _LANES
-        )
-
-        def _word(kb, j):
+    t8 = jnp.transpose(blk_ref[...], (1, 0)).reshape(
+        _KB, 16, 4, _SUB, _LANES
+    )
+    for kb in range(_KB):
+        for j in range(16):
             b0 = t8[kb, j, 0].astype(jnp.uint32)
             b1 = t8[kb, j, 1].astype(jnp.uint32)
             b2 = t8[kb, j, 2].astype(jnp.uint32)
             b3 = t8[kb, j, 3].astype(jnp.uint32)
-            return (
+            w_ref[kb * 16 + j] = (
                 (b0 << np.uint32(24))
                 | (b1 << np.uint32(16))
                 | (b2 << np.uint32(8))
                 | b3
             )
 
-        for kb in range(_KB):
-            new = _rounds64(state, lambda j, kb=kb: _word(kb, j))
-            if (nb_real % _KB) and kb >= nb_real % _KB:
-                # A position past the real chain only occurs in the final
-                # (ragged) group; elsewhere the static bound keeps it free.
-                valid = (b + 1) * _KB <= nb_real
-                state = [jnp.where(valid, nv, s) for nv, s in zip(new, state)]
-            else:
-                state = new
 
-        @pl.when(b == ngroups - 1)
-        def _fold_pad():
-            st = _rounds64(
-                state,
-                lambda j: jnp.full((_SUB, _LANES), np.uint32(pad_words[j]),
-                                   jnp.uint32),
-            )
-            for i in range(8):
-                out_ref[0, i, :, :] = st[i]
+def _make_kernel(nb_real: int, pad_words: np.ndarray):
+    """Grid-step kernel for a chain of ``nb_real`` data blocks.
 
-        @pl.when(b != ngroups - 1)
-        def _store():
+    The shared SHA padding block is compile-time constants
+    (``pad_words``) -- it never exists in HBM: the chain's last group
+    writes it into the scratch right after its last real block and the
+    one rolled loop folds it with them. blk_ref is a natural
+    [N_TILE, _KB*64] uint8 BYTE slab; out_ref: [1, 8, _SUB, _LANES],
+    revisited across the block-group axis (carries the running state in
+    VMEM); w_ref: [(_KB+1)*16, _SUB, _LANES] uint32 scratch, the group's
+    message words and room for the padding block after a full group.
+    """
+    ngroups = (nb_real + _KB - 1) // _KB
+    last_real = nb_real - (ngroups - 1) * _KB  # blocks of the last group
+
+    def kernel(blk_ref, out_ref, w_ref):
+        b = pl.program_id(1)
+        last = b == ngroups - 1
+
+        @pl.when(b == 0)
+        def _init():
             for i in range(8):
-                out_ref[0, i, :, :] = state[i]
+                out_ref[0, i] = jnp.full((_SUB, _LANES), _H0[i], jnp.uint32)
+
+        _park_words(blk_ref, w_ref)
+
+        @pl.when(last)
+        def _park_pad():
+            # Right after the last real block: over what the jnp.pad of a
+            # ragged block axis left, or in the spare slots after a full group.
+            for j in range(16):
+                w_ref[last_real * 16 + j] = jnp.full(
+                    (_SUB, _LANES), np.uint32(pad_words[j]), jnp.uint32
+                )
+
+        def block(kb, state):
+            return tuple(_rounds64(list(state), lambda j: w_ref[kb * 16 + j]))
+
+        state = jax.lax.fori_loop(
+            0, jnp.where(last, last_real + 1, _KB), block,
+            tuple(out_ref[0, i] for i in range(8)),
+        )
+        for i in range(8):
+            out_ref[0, i] = state[i]
 
     return kernel
 
@@ -216,8 +236,8 @@ def sha256_tiles(
     # no XLA-side data movement (an XLA pre-transpose was the v1
     # bottleneck: ~12 GB/s); the kernel does the u8 relayout in VMEM.
     if nb % _KB:
-        # Pad the block axis so the final (masked) grid group has a real
-        # slab to DMA; the kernel's validity mask ignores the content.
+        # Pad the block axis so the final grid group has a real slab to
+        # DMA; the kernel's loop ends before it folds the content.
         data_u8 = jnp.pad(data_u8, ((0, 0), (0, (ngroups * _KB - nb) * 64)))
 
     pad_words = np.asarray(_pad_block_for(nb * 64), dtype=np.uint32)
@@ -237,6 +257,9 @@ def sha256_tiles(
             memory_space=pltpu.VMEM,
         ),
         out_shape=jax.ShapeDtypeStruct((t, 8, _SUB, _LANES), jnp.uint32),
+        scratch_shapes=[
+            pltpu.VMEM(((_KB + 1) * 16, _SUB, _LANES), jnp.uint32)
+        ],
     )(data_u8)
     return out.reshape(t, 8, N_TILE).transpose(0, 2, 1).reshape(-1, 8)[:m]
 
@@ -283,25 +306,7 @@ def _ragged_kernel(scal_ref, nblk_ref, blk_ref, state_ref, out_ref, w_ref):
     # is rounded up to the slab, and a 1 KiB row pays for 3 groups of it.
     @pl.when(first < scal_ref[1])
     def _fold():
-        # The same u8 transpose + byte-plane combine as the natural tile
-        # kernel, parked in VMEM so the block loop below can be ROLLED:
-        # one traced compression instead of _KB, an eighth of the Python
-        # tracing and of Mosaic's work on every start.
-        t8 = jnp.transpose(blk_ref[...], (1, 0)).reshape(
-            _KB, 16, 4, _SUB, _LANES
-        )
-        for kb in range(_KB):
-            for j in range(16):
-                b0 = t8[kb, j, 0].astype(jnp.uint32)
-                b1 = t8[kb, j, 1].astype(jnp.uint32)
-                b2 = t8[kb, j, 2].astype(jnp.uint32)
-                b3 = t8[kb, j, 3].astype(jnp.uint32)
-                w_ref[kb * 16 + j] = (
-                    (b0 << np.uint32(24))
-                    | (b1 << np.uint32(16))
-                    | (b2 << np.uint32(8))
-                    | b3
-                )
+        _park_words(blk_ref, w_ref)
         nblk = nblk_ref[...]
 
         def block(kb, state):

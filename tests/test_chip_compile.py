@@ -93,6 +93,47 @@ def test_tile_kernel_fits_the_chip_at_shipped_batches(one_chip, rows, piece_mib)
     assert mem.argument_size_in_bytes <= 2 * rows * plen, mem
 
 
+def _equations(jaxpr) -> int:
+    """Equations of a jaxpr and of every jaxpr inside its equations'
+    parameters (a pallas_call's kernel, a loop's body, a cond's arms)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else (param,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _equations(sub)
+    return n
+
+
+# What the kernel whose block loop was unrolled traced at this window: nine
+# compressions, 7-8 s of Python a shape a start on an idle chip machine
+# with the interpreter lock held, 1.2-1.5 s rolled (PERF.md SS5, PR 34).
+UNROLLED_EQUATIONS = 32_259
+
+
+def test_tile_kernel_traces_one_compression_a_shape():
+    """What a uniform shape costs a start is tracing and lowering, and that
+    is as long as what is traced: the block loop rolled over words parked
+    in VMEM is one ``_rounds64`` and the relayout, whatever the chain's
+    length. No clock: the count of equations at the shipped window."""
+    from kraken_tpu.ops.sha256_pallas import hash_pieces_device
+
+    plen = 4 * MIB
+    x = jax.ShapeDtypeStruct((WINDOW // plen, plen), jnp.uint8)
+    traced = jax.jit(
+        lambda d: hash_pieces_device(d, plen, interpret=False)
+    ).trace(x)
+    got = _equations(traced.jaxpr.jaxpr)
+    limit = UNROLLED_EQUATIONS // 3
+    assert got <= limit, (
+        f"sha256_tiles traces {got} equations at 16 x 4 MiB; the limit is "
+        f"{limit}, a third of the {UNROLLED_EQUATIONS} its unrolled block "
+        f"loop traced"
+    )
+
+
 def _ragged_slab_shapes(lanes, slab, sharding=None):
     """What ``sha256_ragged_slab`` is handed at the compiled shape
     (lanes, slab blocks): state, data, per-lane counts, scalars."""

@@ -120,7 +120,7 @@ def test_hash_batch_mixed_sizes_bounded_memory():
     [(64, [(1, 8192), (16, 4096)]), (4, [(1, 4096), (1, 8192), (4, 4096)])],
 )
 def test_hash_batch_sends_piece_sized_uniform_groups_to_the_tile_kernel(
-    monkeypatch, ragged_kernel, tile_kernel_shapes, sub_batch_pieces,
+    monkeypatch, tile_kernels, tile_kernel_shapes, sub_batch_pieces,
     want_shapes,
 ):
     """On an accelerator (``use_pallas``) the equal-length, piece-sized
@@ -146,18 +146,19 @@ def test_hash_batch_sends_piece_sized_uniform_groups_to_the_tile_kernel(
     assert sorted(tile_kernel_shapes) == want_shapes
 
 
-# -- the ragged tile kernel (interpret mode) --------------------------------
+# -- the two tile kernels (interpret mode) ----------------------------------
 
 def rolled_rounds(state, wget):
     """``sha256_pallas._rounds64`` as four passes of a sixteen-round loop.
 
     XLA:CPU does not finish compiling the unrolled 64 rounds in minutes
-    (ops/sha256.py ``_UNROLL``), so the interpret-mode tests of the ragged
-    kernel trace this in their place. The unrolled rounds are the ones
+    (ops/sha256.py ``_UNROLL``), so the interpret-mode tests of the tile
+    kernels trace this in their place. The unrolled rounds are the ones
     sha256_tiles has always had, held to hashlib on the chip; what these
-    tests hold is everything the ragged kernel adds around them: the
-    relayout, the per-lane block counts, the skipped groups, the state
-    carried from call to call."""
+    tests hold is everything the kernels put around them: the relayout,
+    the rolled block loop, the padding block folded from constants, the
+    per-lane block counts, the skipped groups, the state carried from
+    call to call."""
     import jax
     import jax.numpy as jnp
 
@@ -189,16 +190,19 @@ def rolled_rounds(state, wget):
 
 
 @pytest.fixture(scope="module")
-def ragged_kernel():
-    """``sha256_pallas`` with the ragged kernel runnable on the CPU."""
+def tile_kernels():
+    """``sha256_pallas`` with both tile kernels runnable on the CPU."""
     from kraken_tpu.ops import sha256_pallas
 
+    jitted = (sha256_pallas.sha256_ragged_slab, sha256_pallas.sha256_tiles)
     real = sha256_pallas._rounds64
     sha256_pallas._rounds64 = rolled_rounds
-    sha256_pallas.sha256_ragged_slab.clear_cache()
+    for fn in jitted:
+        fn.clear_cache()
     yield sha256_pallas
     sha256_pallas._rounds64 = real
-    sha256_pallas.sha256_ragged_slab.clear_cache()
+    for fn in jitted:
+        fn.clear_cache()
 
 
 def _ragged_digests(kernel, msgs, shape):
@@ -227,17 +231,17 @@ _SLAB_BYTES = _SLAB * 64
         pytest.param(3 * _SLAB_BYTES + 7, id="three-slabs-and-7-bytes"),
     ],
 )
-def test_ragged_tile_kernel_lengths(ragged_kernel, length):
+def test_ragged_tile_kernel_lengths(tile_kernels, length):
     """One row, one lane: every padding edge, and chains of one, two and
     four calls whose state is carried from each call into the next."""
     data = os.urandom(length)
-    got = _ragged_digests(ragged_kernel, [data], (1, _SLAB))
+    got = _ragged_digests(tile_kernels, [data], (1, _SLAB))
     assert bytes(got[0]) == hashlib.sha256(data).digest()
 
 
 @pytest.mark.parametrize("lanes", [1024, 8])
 def test_ragged_tile_kernel_rows_ending_in_different_slabs(
-    ragged_kernel, lanes
+    tile_kernels, lanes
 ):
     """Rows of one batch end in the first, second, third and fourth call:
     a lane past its own count keeps its state through the later calls,
@@ -245,14 +249,14 @@ def test_ragged_tile_kernel_rows_ending_in_different_slabs(
     lengths = [0, 100, _SLAB_BYTES - 9, _SLAB_BYTES, 2 * _SLAB_BYTES + 5,
                3 * _SLAB_BYTES + 7, 700]
     msgs = [os.urandom(n) for n in lengths]
-    got = _ragged_digests(ragged_kernel, msgs, (lanes, _SLAB))
+    got = _ragged_digests(tile_kernels, msgs, (lanes, _SLAB))
     for row, m in zip(got, msgs):
         assert bytes(row) == hashlib.sha256(m).digest()
 
 
 @pytest.mark.parametrize("use_pallas", [True, False])
 def test_hash_batch_sends_short_and_odd_rows_to_the_ragged_tile_kernel(
-    ragged_kernel, use_pallas
+    tile_kernels, use_pallas
 ):
     """On an accelerator (``use_pallas``) whatever the uniform tile kernel
     does not take -- short rows, odd lengths -- goes through the ragged
@@ -296,23 +300,34 @@ def test_hash_batch_sends_short_and_odd_rows_to_the_ragged_tile_kernel(
         assert d_scan["sections"] == 2 and d_scan["useful_blocks"] == useful
 
 
-@pytest.mark.skipif(
-    not os.environ.get("RUN_PALLAS_INTERPRET"),
-    reason="interpret-mode kernel execution takes minutes on CPU; the "
-    "kernel is golden-tested on real TPU (set RUN_PALLAS_INTERPRET=1)",
+@pytest.mark.parametrize(
+    "rows,blocks",
+    [
+        pytest.param(3, 1, id="1-block"),
+        pytest.param(2, 7, id="7-blocks-one-short-group"),
+        pytest.param(5, 8, id="8-blocks-one-full-group"),
+        pytest.param(5, 9, id="9-blocks"),
+        pytest.param(2, 16, id="16-blocks-two-full-groups"),
+        pytest.param(1, 17, id="17-blocks"),
+        pytest.param(1, 8, id="one-row"),
+        pytest.param(1025, 1, id="1025-rows-two-tiles"),
+        pytest.param(1025, 9, id="1025-rows-9-blocks"),
+    ],
 )
-def test_pallas_kernel_interpret_mode():
-    """The Pallas kernel (interpret mode on CPU) matches hashlib, including
-    block-group padding (chains not a multiple of the kernel's _KB)."""
-    import jax.numpy as jnp
+def test_uniform_tile_kernel_chains_and_rows(tile_kernels, rows, blocks):
+    """``sha256_tiles`` in interpret mode against hashlib: chains that end
+    on a group's edge and inside one (``blocks % _KB`` 0 and not), in one
+    group and in several, so the padding block is folded right after the
+    last real block wherever that falls; a batch under a tile rides the
+    edge block, and the 1,025th row is a second tile's only lane."""
+    from kraken_tpu.ops.sha256 import _digest_bytes
 
-    from kraken_tpu.ops.sha256_pallas import hash_pieces_device
-
-    for pl_len, n in ((64, 3), (576, 5), (1024, 2)):
-        data = np.frombuffer(os.urandom(n * pl_len), dtype=np.uint8).reshape(n, pl_len)
-        out = hash_pieces_device(jnp.asarray(data), pl_len)
-        from kraken_tpu.ops.sha256 import _digest_bytes
-
-        got = _digest_bytes(out)
-        for i in range(n):
-            assert bytes(got[i]) == hashlib.sha256(data[i].tobytes()).digest()
+    plen = blocks * 64
+    data = np.frombuffer(os.urandom(rows * plen), dtype=np.uint8).reshape(
+        rows, plen
+    )
+    got = _digest_bytes(
+        tile_kernels.hash_pieces_device(data, plen, interpret=True)
+    )
+    for i in range(rows):
+        assert bytes(got[i]) == hashlib.sha256(data[i]).digest(), i
