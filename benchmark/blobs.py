@@ -49,6 +49,7 @@ class SeededBlob:
                                    dtype=np.uint64)[:len(self._base)]
         self.hex = ""            # sha256 of the whole blob
         self.piece_hashes = b""  # 32 bytes a piece
+        self._kept: tuple[int, bytes] | None = None  # differs_at's last chunk
 
     def chunk(self, k: int) -> bytes:
         key = np.uint64(((k + 1) * 0x9E3779B97F4A7C15) % (1 << 64))
@@ -80,14 +81,22 @@ class SeededBlob:
         self.piece_hashes = b"".join(pieces)
 
     def differs_at(self, offset: int, data: bytes) -> bool:
-        """Whether ``data``, received at ``offset``, differs from the blob."""
+        """Whether ``data``, received at ``offset``, differs from the blob.
+        A response arrives in pieces far smaller than a chunk, in order, so
+        the chunk under comparison is kept until the next one is asked for:
+        made anew for every piece, it cost more than the pull it checked
+        (PERF.md section 6, PR 35: half of every pull's span on the chip's host)."""
         pos = 0
         while pos < len(data):
             k, within = divmod(offset + pos, CHUNK)
             if k >= self.n_chunks:
                 return True
-            want = memoryview(self.chunk(k))[within:within + len(data) - pos]
+            if self._kept is None or self._kept[0] != k:
+                self._kept = (k, self.chunk(k))
+            want = memoryview(self._kept[1])[within:within + len(data) - pos]
             if not want or data[pos:pos + len(want)] != want:
                 return True
             pos += len(want)
+        if offset + len(data) >= self.size:
+            self._kept = None  # the blob's last bytes: nothing left to keep it for
         return False

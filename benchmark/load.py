@@ -113,16 +113,30 @@ class Load:
                "ok": False, "why": "", "fault": ""}
         got = 0
         differs = False
+        held = bytearray()  # delivered and counted, not yet compared
+
+        async def compare() -> None:
+            nonlocal got, differs, held
+            t = _now()
+            if not differs:
+                differs = await asyncio.to_thread(blob.differs_at, got, held)
+            rec["gen_s"] += _now() - t
+            got += len(held)
+            held = bytearray()
+
         path = f"/namespace/{NS}/blobs/sha256:{blob.hex}"
         async with self.http.get(self._url("agent", path)) as r:
             r.raise_for_status()
+            # A response arrives in reads of some 64 KiB; a hop to a thread
+            # for each cost a pull a fifth of its span (PERF.md section 6,
+            # PR 35). Bytes are counted as they arrive, compared 4 MiB at a time.
             async for data in r.content.iter_chunked(4 << 20):
-                t = _now()
-                if not differs:
-                    differs = await asyncio.to_thread(blob.differs_at, got, data)
-                rec["gen_s"] += _now() - t
-                got += len(data)
+                held += data
                 self.bytes_moved += len(data)
+                if len(held) >= 4 << 20:
+                    await compare()
+            if held:
+                await compare()
         rec["t_end"] = _now()
         if differs or got != blob.size:
             rec["why"] = f"delivered {got} of {blob.size} bytes, differs={differs}"

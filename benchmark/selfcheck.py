@@ -9,6 +9,9 @@
 - the traffic generator gives every seed the same sizes in the same order,
   the same bytes for the same seed, and for another seed another head
   (``salt_bytes``) before the same body;
+- the comparison that decides a pull's ``correct`` (``SeededBlob.differs_at``),
+  fed a blob in pieces of any size, passes the blob's own bytes and sees one
+  altered byte wherever it lies, a byte too many and a byte past the end;
 - the trace reduction, on the recorded one-upload trace kept beside it,
   gives 0 < busy_s <= window_s and names ``sha256_tiles``;
 - the validator that ``run.py`` holds its own last line to takes a sound
@@ -28,7 +31,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 import contract  # noqa: E402
 import traffic  # noqa: E402
-from blobs import SeededBlob, piece_length_for  # noqa: E402
+from blobs import CHUNK, SeededBlob, piece_length_for  # noqa: E402
 
 FAILED: list[str] = []
 
@@ -116,6 +119,24 @@ def main() -> int:
             check(all(contract.check_last_line(json.dumps(b), bench, cell["name"],
                                                trace, cell["chips"]) for b in broken),
                   f"{cell['name']} trace={int(trace)}: {len(broken)} broken lines are refused")
+
+    blob = SeededBlob(2147483659, 0, CHUNK + 300_001, 4 << 20, {"draw": 1, "salt_bytes": 64})
+    whole = blob.chunk(0) + blob.chunk(1)
+
+    def fed_in_pieces(data: bytes, step: int) -> bool:
+        return any(blob.differs_at(off, data[off:off + step])
+                   for off in range(0, len(data), step))
+
+    check(len(whole) == blob.size
+          and not any(fed_in_pieces(whole, step) for step in (65_536, 4 << 20, len(whole))),
+          "differs_at passes the blob's own bytes, in pieces of 64 KiB, 4 MiB and whole")
+    altered = []
+    for at in (0, 63, CHUNK - 1, CHUNK, blob.size - 1):
+        bad = bytearray(whole)
+        bad[at] ^= 1
+        altered.append(fed_in_pieces(bytes(bad), 65_536) and fed_in_pieces(bad, 4 << 20))
+    check(all(altered) and blob.differs_at(0, whole + b"x") and blob.differs_at(blob.size, b"x"),
+          "differs_at sees one altered byte at either end of either chunk, and a byte past the end")
 
     import reduce_trace
 

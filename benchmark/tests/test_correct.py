@@ -5,10 +5,12 @@ for each fault a cell can have: an answer altered where it is produced.
 
     python3 -m pytest benchmark/tests -q        (about three minutes)
 
-The pull cases run a cell that BENCHMARK.json does not hold yet, made here
-the way a later PR would make it: entries in a copy of BENCHMARK.json that
-name files already under ``benchmark/`` and edit none. They keep the pull
-driver rehearsed until such a cell is proven on the chip.
+The pull cases run the configuration ``agent-tpu`` (in BENCHMARK.json since
+PR 35) under the small mix, a cell BENCHMARK.json does not hold, made here
+the way a later PR would make it: one entry in ``workloads`` of a copy of
+BENCHMARK.json, and its name in the ``workloads`` lists of the metrics it
+reports; no file under ``benchmark/`` is edited. (The committed pull cell's
+own rehearsal is ``test_pull_cell.py``.)
 
 The benchmark's own runs do not run these.
 """
@@ -33,23 +35,15 @@ PULL = "agent-tpu.pull-small"
 
 @pytest.fixture(scope="module")
 def with_pull_cell(tmp_path_factory):
-    """BENCHMARK.json plus one configuration, one cell and its metrics."""
+    """BENCHMARK.json plus one cell of the configuration ``agent-tpu``,
+    named in the lists of the pull side's metrics."""
     bench = contract.load_benchmark()
-    bench["configs"].append({
-        "name": "agent-tpu", "source": "https://github.com/uber/kraken README",
-        "file": "benchmark/configs/agent-tpu.json",
-        "reduced": ["origins", "trackers", "agents"], "why": "the pull side"})
     bench["workloads"].append({
         "name": PULL, "config": "agent-tpu", "traffic": "small-1k-1m", "chips": 1,
         "why": "3 closed-loop pullers of small blobs through the agent"})
-    for name, unit, better in (("pull_rate", "MB/s", "higher"), ("pull_p90", "s", "lower")):
-        bench["end_to_end"].append({
-            "name": name, "unit": unit, "better": better, "bound": 0.1,
-            "source": "host_clock", "workloads": [PULL]})
-    bench["per_layer"].append({
-        "name": "verify_rows_mean", "unit": "pieces/batch", "better": "higher",
-        "source": "program_counter", "layer": "verify batching",
-        "moves": "pull_rate", "workloads": [PULL]})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in ("pull_rate", "pull_p90", "verify_rows_mean"):
+            m["workloads"].append(PULL)
     assert contract.check_benchmark(bench) == []
     path = tmp_path_factory.mktemp("bench") / "BENCHMARK.json"
     path.write_text(json.dumps(bench))
@@ -78,7 +72,7 @@ def test_sound_run_is_correct(cell, with_pull_cell):
     assert doc["correct"] is True and doc["failed"] == 0 and doc["attempted"] > 0
     assert all(c["value"] <= c["limit"] for c in doc["checks"].values())
     assert set(doc["metrics"]) == (
-        {"push_p90", "setup_s"} if cell == PUSH else {"pull_rate", "pull_p90", "setup_s"})
+        {"push_p90", "setup_s"} if cell == PUSH else {"pull_rate", "setup_s"})
 
 
 @pytest.mark.parametrize("cell", [PUSH, PULL])
