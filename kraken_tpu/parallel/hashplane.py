@@ -37,6 +37,7 @@ from kraken_tpu.ops.sha256 import (
     _sha256_uniform,
     JaxPieceHasher,
 )
+from kraken_tpu.utils.metrics import REGISTRY
 
 _log = logging.getLogger("kraken.hashplane")
 
@@ -173,23 +174,37 @@ class ShardedPieceHasher(PieceHasher):
         # much as a `tpu` agent does.
         self._fallback = JaxPieceHasher(use_pallas=use_pallas)
         self._dispatched = False
+        self._mesh_rows = REGISTRY.counter(
+            "hasher_mesh_rows_total",
+            "Rows of sharded dispatches each mesh device took, padding"
+            " rows included",
+        )
+        self._mesh_pad_rows = REGISTRY.counter(
+            "hasher_mesh_pad_rows_total",
+            "Rows of zeros sharded dispatches sent to fill the mesh's"
+            " device quantum",
+        )
 
     def devices(self) -> list:
         return list(self._mesh.devices.flat)
 
     def _hash_staged(self, staged: jax.Array, m: int, piece_length: int):
+        # Which devices really hold rows. A mesh that collapsed onto one
+        # device hashes just as correctly.
+        rows_per_device = {
+            str(s.device.id): int(s.data.shape[0])
+            for s in staged.addressable_shards
+        }
         if not self._dispatched:
-            # Once per process: which devices really hold rows. A mesh
-            # that collapsed onto one device hashes just as correctly.
-            self._dispatched = True
+            self._dispatched = True  # once per process
             _log.info(
                 "sharded hasher first dispatch",
-                extra={"rows_per_device": {
-                    str(s.device.id): int(s.data.shape[0])
-                    for s in staged.addressable_shards
-                }},
+                extra={"rows_per_device": rows_per_device},
             )
         with self._section("sha256_sharded", staged.shape[0], m, piece_length):
+            for device, rows in rows_per_device.items():
+                self._mesh_rows.inc(rows, device=device)
+            self._mesh_pad_rows.inc(staged.shape[0] - m)
             return _digest_bytes(
                 hash_sharded_staged(
                     self._mesh, staged, m, piece_length,

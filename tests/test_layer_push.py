@@ -13,6 +13,15 @@ to the blob: ``hasher_bytes_total`` / ``hasher_pieces_total`` of the device
 hasher grow by the blob's bytes and pieces, the host hasher's and
 ``ingest_fallbacks_total`` stay, and ``ingest_stage_seconds{stage="read"}``
 counts at least the blob's windows.
+
+The same six shapes go through ``hasher: tpu-sharded`` (cell
+``origin-tpu-sharded.push-layers``) on a mesh of four of the session's
+virtual CPU devices, the cell's four chips: a window of whole pieces takes
+the ``transfer`` stage and is one ``sha256_sharded`` dispatch of its rows
+padded to the mesh's four, a last window with a tail sends its whole pieces
+the same way from ``hash_pieces`` and its tail to the single-chip fallback,
+and ``hasher_mesh_rows_total{device}`` / ``hasher_mesh_pad_rows_total`` say
+which device took how many rows and how many of them were padding.
 """
 
 import asyncio
@@ -44,17 +53,31 @@ SHAPES = [
 ]
 
 
+MESH = 4  # devices of the sharded cases' mesh: the cell's four chips
+
+
 def _counters() -> dict:
     c = REGISTRY.counter
-    return {
-        "tpu_bytes": c("hasher_bytes_total").value(hasher="tpu"),
-        "tpu_pieces": c("hasher_pieces_total").value(hasher="tpu"),
+    stages = REGISTRY.histogram("ingest_stage_seconds")
+    sharded = {"purpose": "piece", "kernel": "sha256_sharded"}
+    out = {
         "cpu_bytes": c("hasher_bytes_total").value(hasher="cpu"),
         "cpu_pieces": c("hasher_pieces_total").value(hasher="cpu"),
         "fallbacks": c("ingest_fallbacks_total").total(),
-        "reads": REGISTRY.histogram("ingest_stage_seconds").count(stage="read"),
-        "hashes": REGISTRY.histogram("ingest_stage_seconds").count(stage="hash"),
+        "reads": stages.count(stage="read"),
+        "hashes": stages.count(stage="hash"),
+        "transfers": stages.count(stage="transfer"),
+        "sharded_sections": c("hasher_device_sections_total").value(**sharded),
+        "sharded_rows": c("hasher_device_rows_total").value(**sharded),
+        "mesh_rows": c("hasher_mesh_rows_total").total(),
+        "mesh_pad_rows": c("hasher_mesh_pad_rows_total").total(),
     }
+    for name in ("tpu", "tpu-sharded"):
+        out[name + "_bytes"] = c("hasher_bytes_total").value(hasher=name)
+        out[name + "_pieces"] = c("hasher_pieces_total").value(hasher=name)
+    for d in range(MESH):
+        out[f"mesh_rows_{d}"] = c("hasher_mesh_rows_total").value(device=str(d))
+    return out
 
 
 async def _push(addr: str, blob: bytes, patches: int) -> dict:
@@ -82,9 +105,24 @@ async def _push(addr: str, blob: bytes, patches: int) -> dict:
             return json.loads(await r.read())
 
 
+@pytest.fixture
+def four_device_mesh(monkeypatch):
+    """``get_hasher("tpu-sharded")`` builds its mesh over every device of
+    the platform (the session has eight); the node is handed the cell's
+    four through the registry's own cache of instances."""
+    from kraken_tpu.core import hasher as registry
+    from kraken_tpu.parallel import ShardedPieceHasher, piece_mesh
+
+    monkeypatch.setitem(
+        registry._INSTANCES, "tpu-sharded",
+        ShardedPieceHasher(mesh=piece_mesh(MESH)),
+    )
+
+
+@pytest.mark.parametrize("hasher", ["tpu", "tpu-sharded"])
 @pytest.mark.parametrize("windows,whole,tail,patches", SHAPES)
 def test_layer_shape_matches_hashlib_and_moves_the_cells_counters(
-    tmp_path, windows, whole, tail, patches,
+    tmp_path, four_device_mesh, windows, whole, tail, patches, hasher,
 ):
     size = windows * WINDOW + whole * PIECE + tail
     rng = np.random.default_rng([33, windows, whole, tail])
@@ -93,7 +131,7 @@ def test_layer_shape_matches_hashlib_and_moves_the_cells_counters(
 
     async def main():
         node = OriginNode(
-            store_root=str(tmp_path / "o"), hasher="tpu", dedup=False,
+            store_root=str(tmp_path / "o"), hasher=hasher, dedup=False,
             piece_lengths=PieceLengthConfig(table=((0, PIECE),)),
             ingest={"window_bytes": WINDOW, "windows_in_flight": 2},
         )
@@ -119,7 +157,10 @@ def test_layer_shape_matches_hashlib_and_moves_the_cells_counters(
 
     # What the cell's counter checks read: the device hasher covered the
     # payload, exactly once, and nothing went through the host.
-    assert grew["tpu_bytes"] == size and grew["tpu_pieces"] == n_pieces
+    other = "tpu-sharded" if hasher == "tpu" else "tpu"
+    assert grew[hasher + "_bytes"] == size
+    assert grew[hasher + "_pieces"] == n_pieces
+    assert grew[other + "_bytes"] == 0 and grew[other + "_pieces"] == 0
     assert grew["cpu_bytes"] == 0 and grew["cpu_pieces"] == 0
     assert grew["fallbacks"] == 0
     # What ingest_read_s and ingest_hash_s divide by: a read a window
@@ -127,3 +168,21 @@ def test_layer_shape_matches_hashlib_and_moves_the_cells_counters(
     held = windows + (1 if whole or tail else 0)
     assert grew["reads"] >= held
     assert grew["hashes"] == held
+
+    if hasher == "tpu":
+        for name in ("transfers", "sharded_sections", "mesh_rows", "mesh_pad_rows"):
+            assert grew[name] == 0, name
+        return
+    # What ingest_transfer_s divides by: the windows that held whole pieces
+    # only (a last window with a tail goes through hash_pieces whole).
+    assert grew["transfers"] == windows + (1 if whole and not tail else 0)
+    # What mesh_rows_mean.push reads: a dispatch a window that held a whole
+    # piece, its rows padded to the mesh's device quantum.
+    pad = (-whole) % MESH
+    rows = windows * (WINDOW // PIECE) + whole + pad
+    assert grew["sharded_sections"] == windows + (1 if whole else 0)
+    assert grew["sharded_rows"] == rows
+    # Every device took its equal share, and the padding is counted apart.
+    assert grew["mesh_rows"] == rows and grew["mesh_pad_rows"] == pad
+    for d in range(MESH):
+        assert grew[f"mesh_rows_{d}"] == rows // MESH, f"device {d}"

@@ -97,6 +97,27 @@ def test_sharded_hash_matches_hashlib(use_pallas):
         assert got[i].tobytes() == want[i], f"piece {i} (pallas={use_pallas})"
 
 
+# The whole pieces a layer's last window holds in the deck of the cells
+# `origin-tpu*-*.push-layers` (benchmark/traffic/layers-100m-1g.json), and
+# the full window's 16, on the four-chip cell's mesh: each is padded to the
+# mesh's quantum (4, 12 or 16 rows), hashed a quarter a device, and the
+# padding's digests are cut off again.
+@pytest.mark.parametrize("rows", [1, 2, 4, 9, 10, 11, 12, 13, 16])
+def test_last_window_row_counts_on_four_devices_match_hashlib(rows):
+    piece_len = 4096
+    hasher = ShardedPieceHasher(mesh=piece_mesh(4))
+    blob = np.random.default_rng([36, rows]).integers(
+        0, 256, size=rows * piece_len, dtype=np.uint8).tobytes()
+    want = [hashlib.sha256(blob[i * piece_len:(i + 1) * piece_len]).digest()
+            for i in range(rows)]
+    staged = hasher.hash_staged_window(hasher.stage_window(
+        np.frombuffer(blob, dtype=np.uint8).reshape(rows, piece_len), piece_len))
+    assert [bytes(d) for d in staged] == want
+    # hash_pieces sends the same rows the same way, then a tail of its own.
+    tailed = hasher.hash_pieces(blob + b"tail", piece_len)
+    assert [bytes(d) for d in tailed] == want + [hashlib.sha256(b"tail").digest()]
+
+
 def test_sharded_output_replicated():
     mesh = piece_mesh(8)
     data = np.zeros((16, 128), dtype=np.uint8)
