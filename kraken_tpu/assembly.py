@@ -1455,11 +1455,10 @@ class AgentNode:
         self.chunkstore_config = _chunkstore_config(chunkstore)
         self.chunk_gc: Optional[ChunkGC] = None
         _sync_chunkstore(self)
-        # CPU verify: one-tick batching (per-piece hashlib is cheap; a
-        # fixed window only adds latency). TPU verify: keep a 2 ms window
-        # so arrivals coalesce into real device batches -- a batch-of-1
-        # blocking dispatch per piece is what BatchedVerifier exists to
-        # avoid.
+        # The verifier takes its batching rule from the hasher's kind:
+        # CPU verify flushes every tick, any number at once; device verify
+        # keeps one section in flight and sends what arrived meanwhile as
+        # the next (BatchedVerifier).
         # hash_workers: the same host hash pool the origin uses, here
         # feeding BatchedVerifier.hash_batch -- a multi-core agent
         # verifies a piece batch across cores instead of one. Only >= 2
@@ -1471,7 +1470,6 @@ class AgentNode:
             hasher=get_hasher(
                 hasher, workers=hash_workers if hash_workers >= 2 else 0
             ),
-            max_delay_seconds=0.0 if hasher == "cpu" else 0.002,
         )
         self.cleanup = (
             CleanupManager(self.store, cleanup, after_evict=self._after_evict)

@@ -38,29 +38,28 @@ class BatchedVerifier:
     """Verifies received pieces against their expected digests, batching
     concurrent arrivals into one ``PieceHasher.hash_batch`` dispatch.
 
-    Under swarm load many pieces land within a few ms; each ``verify``
-    parks on a future while a single flusher task drains the queue --
-    one TPU dispatch per drain instead of one per piece. An idle swarm
-    pays only ``max_delay`` extra latency (default 2 ms).
+    Each ``verify`` parks on a future while a flush hashes its batch off
+    the event loop. How flushes are taken follows the hasher's kind:
+
+    * host (``cpu``): a flush one event-loop tick after the first arrival,
+      any number hashing at once -- hashlib runs across cores, and
+      serialising host verify cost goodput 2.4x (test_data_plane_band.py).
+    * device (any other hasher): at most one section in flight. Pieces
+      that arrive while it runs are held, and when it ends everything held
+      goes as the next section: a batch is what arrived while the chip was
+      busy, so its size follows the arrival rate and the section time. The
+      chip runs sections one after another whatever the host does, and the
+      tile kernel takes as long over one piece as over sixteen.
+
+    ``max_batch`` caps a flush; what is left over goes next.
     """
 
-    def __init__(
-        self,
-        hasher: PieceHasher | None = None,
-        max_batch: int = 1024,
-        max_delay_seconds: float = 0.0,
-    ):
-        # max_delay 0 = one event-loop tick: every _on_payload task already
-        # scheduled this tick enqueues before the flusher runs, so a burst
-        # (pipeline-depth frames landing in one recv buffer) still batches,
-        # while a trickle no longer pays a fixed 2 ms per piece -- at
-        # 1 MiB pieces that tax alone capped a pair at ~500 MB/s (round-5
-        # pair profile). Raise it only to build bigger TPU batches.
+    def __init__(self, hasher: PieceHasher | None = None, max_batch: int = 1024):
         # Public: the agent's scrubber reuses this hasher's pool for its
         # digest work (assembly wiring) -- renaming it must break loudly.
         self.hasher = hasher or get_hasher("cpu")
         self._max_batch = max_batch
-        self._max_delay = max_delay_seconds
+        self._device = getattr(self.hasher, "name", "cpu") != "cpu"
         self._queue: list[tuple[bytes, bytes, asyncio.Future]] = []
         self._flusher: Optional[asyncio.Task] = None
         self._inflight: set[asyncio.Task] = set()  # strong refs to hash tasks
@@ -81,9 +80,12 @@ class BatchedVerifier:
             "verify_batches_total",
             "Verify flushes dispatched, by hash path (host|tpu)",
         )
-        self._path_label = (
-            "host" if getattr(self.hasher, "name", "cpu") == "cpu" else "tpu"
+        self._c_held = REGISTRY.counter(
+            "verify_held_pieces_total",
+            "Pieces that found a device verify section in flight and were "
+            "held for the next one",
         )
+        self._path_label = "tpu" if self._device else "host"
 
     async def verify(self, data: bytes | memoryview, expected: bytes) -> bool:
         # ``data`` may be a pooled memoryview (zero-copy recv path): the
@@ -92,18 +94,28 @@ class BatchedVerifier:
         loop = asyncio.get_running_loop()
         fut: asyncio.Future[bool] = loop.create_future()
         self._queue.append((data, expected, fut))
-        if self._flusher is None or self._flusher.done():
-            self._flusher = asyncio.create_task(self._flush_soon())
-        if len(self._queue) >= self._max_batch:
+        if self._device and self._inflight:
+            self._c_held.inc()  # the section in flight flushes it as it ends
+        elif len(self._queue) >= self._max_batch:
             self._flush_now()
+        elif self._flusher is None or self._flusher.done():
+            self._flusher = asyncio.create_task(self._flush_soon())
         return await fut
 
     async def _flush_soon(self) -> None:
-        await asyncio.sleep(self._max_delay)
+        # One event-loop tick: every _on_payload task already scheduled
+        # this tick enqueues before the flush, so a burst (pipeline-depth
+        # frames landing in one recv buffer) still batches, while a
+        # trickle pays no fixed delay -- a 2 ms window capped a host pair
+        # at ~500 MB/s (round-5 pair profile).
+        await asyncio.sleep(0)
         self._flush_now()
 
     def _flush_now(self) -> None:
-        batch, self._queue = self._queue, []
+        if self._device and self._inflight:
+            return  # held: the section in flight flushes the queue as it ends
+        batch = self._queue[: self._max_batch]
+        self._queue = self._queue[self._max_batch :]
         if not batch:
             return
         from kraken_tpu.utils.metrics import REGISTRY
@@ -121,12 +133,19 @@ class BatchedVerifier:
         # of MBs (CPU: ~100+ ms; TPU: a blocking device round-trip), and an
         # on-loop hash stalls every conn pump, announce, and accept for the
         # duration. hashlib releases the GIL for large buffers, so the
-        # loop genuinely keeps running. Multiple flushes may hash
-        # concurrently; each resolves only its own batch's futures, so
-        # ordering doesn't matter.
+        # loop genuinely keeps running. Each flush resolves only its own
+        # batch's futures, so host flushes hashing at once need no order.
         t = asyncio.create_task(self._hash_off_loop(batch))
         self._inflight.add(t)
-        t.add_done_callback(self._inflight.discard)
+        t.add_done_callback(self._section_done)
+
+    def _section_done(self, t: asyncio.Task) -> None:
+        # Runs however the section ended -- hashed, raised or cancelled --
+        # so a failed batch never wedges the device slot and every pull
+        # behind it.
+        self._inflight.discard(t)
+        if self._device and self._queue:
+            self._flush_now()
 
     async def _hash_off_loop(
         self, batch: list[tuple[bytes, bytes, asyncio.Future]]

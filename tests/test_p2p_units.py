@@ -263,7 +263,7 @@ def test_batched_verifier_correct_and_batches():
     async def main():
         import hashlib
 
-        v = BatchedVerifier(max_delay_seconds=0.01)
+        v = BatchedVerifier()
         pieces = [os.urandom(500) for _ in range(20)]
         oks = await asyncio.gather(
             *(v.verify(p, hashlib.sha256(p).digest()) for p in pieces)
@@ -275,6 +275,156 @@ def test_batched_verifier_correct_and_batches():
     asyncio.run(main())
 
 
+class _GatedHasher:
+    """A hasher whose ``hash_batch`` blocks until ``gate`` is set, so a test
+    holds a verify section in flight; ``name`` picks the verifier's rule.
+    A batch holding ``b"bad"`` raises, as a released pooled buffer does."""
+
+    def __init__(self, name: str):
+        import threading
+
+        self.name = name
+        self.gate = threading.Event()
+        self.batches: list[int] = []
+        self.inflight = self.max_inflight = 0
+        self._lock = threading.Lock()
+
+    def hash_batch(self, pieces, purpose="verify"):
+        import hashlib
+
+        import numpy as np
+
+        with self._lock:
+            self.batches.append(len(pieces))
+            self.inflight += 1
+            self.max_inflight = max(self.max_inflight, self.inflight)
+        try:
+            assert self.gate.wait(timeout=10)
+            if any(bytes(p) == b"bad" for p in pieces):
+                raise ValueError("released buffer")
+            return np.stack([
+                np.frombuffer(hashlib.sha256(p).digest(), dtype=np.uint8)
+                for p in pieces
+            ])
+        finally:
+            with self._lock:
+                self.inflight -= 1
+
+
+def _sha(p: bytes) -> bytes:
+    import hashlib
+
+    return hashlib.sha256(p).digest()
+
+
+async def _until(cond, timeout: float = 5.0) -> None:
+    deadline = asyncio.get_running_loop().time() + timeout
+    while not cond():
+        assert asyncio.get_running_loop().time() < deadline
+        await asyncio.sleep(0.001)
+
+
+def _held() -> float:
+    from kraken_tpu.utils.metrics import REGISTRY
+
+    return REGISTRY.counter("verify_held_pieces_total").value()
+
+
+def test_device_verifier_holds_arrivals_for_one_next_section():
+    """On a device hasher one section is in flight; the N pieces that
+    arrive meanwhile are held and go as ONE next section of N."""
+
+    async def main():
+        h = _GatedHasher("tpu")
+        v = BatchedVerifier(h)
+        held0 = _held()
+        first = asyncio.create_task(v.verify(b"p0", _sha(b"p0")))
+        await _until(lambda: h.batches == [1])
+        pieces = [b"p%d" % i for i in range(1, 8)]
+        rest = [asyncio.create_task(v.verify(p, _sha(p))) for p in pieces]
+        for _ in range(5):
+            await asyncio.sleep(0.01)
+        assert h.batches == [1]  # held: no second section while one runs
+        assert _held() - held0 == len(pieces)
+        h.gate.set()
+        assert all(await asyncio.wait_for(asyncio.gather(first, *rest), 10))
+        assert h.batches == [1, len(pieces)]
+        assert h.max_inflight == 1
+        # Idle again: the next arrival goes alone, on the next tick.
+        assert await asyncio.wait_for(v.verify(b"x", _sha(b"x")), 10)
+        assert h.batches == [1, len(pieces), 1]
+        assert _held() - held0 == len(pieces)
+
+    asyncio.run(main())
+
+
+def test_device_verifier_failed_section_releases_the_slot():
+    """A section that raises retries its entries one by one, fails only
+    the bad one, and frees the slot: later verifies still complete."""
+
+    async def main():
+        h = _GatedHasher("tpu")
+        v = BatchedVerifier(h)
+        first = asyncio.create_task(v.verify(b"p0", _sha(b"p0")))
+        await _until(lambda: h.batches == [1])
+        good = [asyncio.create_task(v.verify(p, _sha(p))) for p in (b"a", b"b")]
+        bad = asyncio.create_task(v.verify(b"bad", _sha(b"bad")))
+        await asyncio.sleep(0.01)
+        h.gate.set()
+        assert all(await asyncio.wait_for(asyncio.gather(first, *good), 10))
+        with pytest.raises(ValueError):
+            await asyncio.wait_for(bad, 10)
+        assert h.batches == [1, 3, 1, 1, 1]  # the batch, then one by one
+        assert await asyncio.wait_for(v.verify(b"y", _sha(b"y")), 10)
+        assert h.max_inflight == 1
+
+    asyncio.run(main())
+
+
+def test_device_verifier_drops_cancelled_waiters():
+    """A waiter cancelled while held is dropped before its buffer is read;
+    its batch-mates still verify."""
+
+    async def main():
+        h = _GatedHasher("tpu")
+        v = BatchedVerifier(h)
+        first = asyncio.create_task(v.verify(b"p0", _sha(b"p0")))
+        await _until(lambda: h.batches == [1])
+        mates = [asyncio.create_task(v.verify(p, _sha(p))) for p in (b"a", b"b")]
+        doomed = asyncio.create_task(v.verify(b"bad", _sha(b"bad")))
+        await asyncio.sleep(0.01)
+        doomed.cancel()
+        await asyncio.sleep(0)
+        h.gate.set()
+        assert all(await asyncio.wait_for(asyncio.gather(first, *mates), 10))
+        assert doomed.cancelled()
+        assert h.batches == [1, 2]
+
+    asyncio.run(main())
+
+
+def test_cpu_verifier_flushes_concurrently():
+    """A ``cpu`` verifier keeps its one-tick flush and any number of
+    flushes hashing at once: host verify runs across cores."""
+
+    async def main():
+        h = _GatedHasher("cpu")
+        v = BatchedVerifier(h)
+        held0 = _held()
+        first = asyncio.create_task(v.verify(b"p0", _sha(b"p0")))
+        await _until(lambda: h.batches == [1])
+        second = asyncio.create_task(v.verify(b"p1", _sha(b"p1")))
+        try:
+            await _until(lambda: h.max_inflight == 2)
+        finally:
+            h.gate.set()
+        assert all(await asyncio.wait_for(asyncio.gather(first, second), 10))
+        assert h.batches == [1, 1]
+        assert _held() == held0
+
+    asyncio.run(main())
+
+
 # -- torrent storage --------------------------------------------------------
 
 def test_agent_torrent_lifecycle(tmp_path):
@@ -282,7 +432,7 @@ def test_agent_torrent_lifecycle(tmp_path):
         blob = os.urandom(10_000)
         mi = make_metainfo(blob)
         store = CAStore(str(tmp_path / "s"))
-        archive = AgentTorrentArchive(store, BatchedVerifier(max_delay_seconds=0.001))
+        archive = AgentTorrentArchive(store, BatchedVerifier())
         t = archive.create_torrent(mi)
         assert not t.complete()
         assert t.missing_pieces() == list(range(mi.num_pieces))
@@ -550,9 +700,7 @@ def test_duplicate_final_piece_is_benign(tmp_path):
         blob = os.urandom(3000)
         mi = make_metainfo(blob)
         store = CAStore(str(tmp_path / "s"))
-        archive = AgentTorrentArchive(
-            store, BatchedVerifier(max_delay_seconds=0.001)
-        )
+        archive = AgentTorrentArchive(store, BatchedVerifier())
         t = archive.create_torrent(mi)
         pl = mi.piece_length
         for i in range(mi.num_pieces - 1):
@@ -585,7 +733,7 @@ def test_verify_burst_does_not_stall_loop():
     async def attempt() -> float:
         import hashlib
 
-        v = BatchedVerifier(max_delay_seconds=0.001)
+        v = BatchedVerifier()
         pieces = [os.urandom(256 * 1024) for _ in range(100)]
         digests = [hashlib.sha256(p).digest() for p in pieces]
 
