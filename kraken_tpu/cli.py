@@ -74,10 +74,12 @@ async def _run_until_signal(node, describe: dict,
     loop.add_signal_handler(signal.SIGTERM, on_sigterm)
     loop.add_signal_handler(signal.SIGHUP, reload_config)
 
-    # Before anything is started: every to_thread call and the HTTP
-    # server's own work are then steps of the push-step ledger.
+    # Before anything is started: every to_thread call, the HTTP
+    # server's own work and the sockets' reads and writes are then steps
+    # of the push-step ledger.
     pushsteps.install(loop)
     pushsteps.name_http_server()
+    pushsteps.name_socket_io()
     await node.start()
     describe["addr"] = node.addr
     # Agents with the docker-registry read endpoint enabled bind it on its

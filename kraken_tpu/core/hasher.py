@@ -163,6 +163,11 @@ class DeviceLedger:
         start = max(t_enter, last_done); held = now - start
         waited = start - t_enter;        last_done = now
 
+    A section that enters with none open books the time since
+    ``last_done`` as the device's IDLE gap, to its own labels (the
+    process's first section books none): idle / (idle + held) is the
+    device's idle share as the host sees it.
+
     Held intervals never overlap, so their sum never passes the wall. It
     is an estimate with two known errors: host<->device copies and
     dispatch latency are booked as held when the device was idle before,
@@ -214,6 +219,12 @@ class DeviceLedger:
         self._first_s = registry.counter(
             "hasher_device_first_use_seconds_total",
             "Held seconds of first-use sections",
+        )
+        self._idle = registry.counter(
+            "hasher_device_idle_seconds_total",
+            "Seconds from the last section's end to a section's enter with"
+            " none open, booked to the entering section: the device's idle"
+            " time as the host sees it",
         )
 
     def section(
@@ -286,7 +297,13 @@ class DeviceSection:
         self._annotation.__enter__()
         ledger = self._ledger
         with ledger._lock:
-            self._t_enter = ledger._open[id(self)] = ledger._clock()
+            now = self._t_enter = ledger._clock()
+            idle = (now - ledger._last_done
+                    if not ledger._open and ledger._last_done > float("-inf")
+                    else 0.0)
+            ledger._open[id(self)] = now
+        if idle > 0:
+            ledger._idle.inc(idle, **self._labels)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:

@@ -39,6 +39,7 @@ from kraken_tpu.p2p.wire import (
 from kraken_tpu.utils import failpoints, trace
 from kraken_tpu.utils.bandwidth import BandwidthLimiter
 from kraken_tpu.utils.bufpool import BufferPool
+from kraken_tpu.utils.pushsteps import stepped
 
 _SEND_QUEUE = 256
 _RECV_QUEUE = 256
@@ -234,6 +235,7 @@ class Conn:
         finally:
             self.close(reason=reason, detail=detail)
 
+    @stepped("p2p.recv")
     async def _recv_loop(self) -> None:
         reason, detail = "recv_loop_exit", ""
         misbehavior = False

@@ -20,10 +20,26 @@ from kraken_tpu.core.peer import PeerID
 from kraken_tpu.p2p.conn import Conn, ConnClosedError
 from kraken_tpu.p2p.networkevent import NoopProducer, Producer
 from kraken_tpu.p2p.piecerequest import RequestManager
-from kraken_tpu.p2p.storage import PieceError, Torrent
+from kraken_tpu.p2p.storage import PieceError, PieceTimes, Torrent
 from kraken_tpu.p2p.wire import Message, MsgType
 from kraken_tpu.utils import trace
 from kraken_tpu.utils.metrics import REGISTRY
+from kraken_tpu.utils.pushsteps import stepped
+
+# A received piece's life, one request slot's whole cycle, in six stages
+# that do not overlap (``p2p_piece_stage_seconds{stage}``): request queued
+# -> the payload frame's header read (``wait``) -> the whole payload handed
+# to the dispatcher (``recv``) -> the verifier's flush that takes it
+# (``queue``: what it waited for the section in flight, its task's start
+# included) -> its verdict back on the loop (``verify``: the thread hop,
+# staging, the device section, the read-back) -> its data write returned
+# (``write``) -> the next request on this conn queued for the wire
+# (``refill``: the slot stays empty from its write until then; 0 for the
+# piece that completes the torrent). A conn whose frames carry no header
+# reading (a leech worker's ring payloads) books wait + recv as ``wait``
+# and a ``recv`` of 0. A duplicate, a piece that fails verification and
+# one whose request was not stamped here book nothing.
+PIECE_STAGES = ("wait", "recv", "queue", "verify", "write", "refill")
 
 
 def _bits_to_set(bits: bytes, num_pieces: int) -> set[int]:
@@ -110,14 +126,20 @@ class Dispatcher:
             "p2p_piece_bytes_up_total",
             "Piece payload bytes served over the swarm wire (main loop)",
         )
+        self._h_stage = REGISTRY.histogram(
+            "p2p_piece_stage_seconds",
+            "Seconds a received piece spent in each stage of its request"
+            " slot's cycle (wait|recv|queue|verify|write|refill)",
+        )
         self._peers_seen: set[PeerID] = set()
         self._blacklist_events = 0
         # Per-pull stage-timing split for the torrent_summary rollup:
         # plan (metainfo fetch + delta prefill) and dial (handshake)
         # walls are written in by the scheduler; piece_wait accumulates
-        # request->payload gaps here; verify/write walls live on the
-        # Torrent (storage.py). Stages overlap under pipelining -- they
-        # are cumulative stage costs, not a partition of the wall.
+        # request->hand-off gaps here (wait + recv of PIECE_STAGES);
+        # verify/write walls live on the Torrent (storage.py). Stages
+        # overlap under pipelining -- they are cumulative stage costs,
+        # not a partition of the wall.
         self.stage_walls: dict[str, float] = {"plan": 0.0, "dial": 0.0}
         self._stage_piece_wait = 0.0
         self._req_ts: dict[int, float] = {}
@@ -312,7 +334,7 @@ class Dispatcher:
             msg.release()  # raced a drop: nobody else will return it
             return
         peer.last_useful = asyncio.get_running_loop().time()
-        self._spawn_payload(peer, msg)
+        self._spawn_payload(peer, msg, peer.last_useful)
 
     _MAX_RECEIVING_PER_PEER = 64  # concurrent payload tasks per conn: the
     # inbound mirror of _MAX_SERVING_PER_PEER. Each admitted payload holds
@@ -325,14 +347,16 @@ class Dispatcher:
     # bounded endgame duplicates. Over-cap frames are shed (released,
     # dropped) -- no progress for the flooder, no RSS growth for us.
 
-    def _spawn_payload(self, peer: _Peer, msg: Message) -> None:
+    def _spawn_payload(self, peer: _Peer, msg: Message,
+                       handed: float) -> None:
         """Spawn the verify->write handler for one payload frame with the
         ONE release point for its pooled buffer: the task done-callback
         fires on completion, failure, AND cancellation-before-first-step,
         so no path (corrupt-piece ban, mid-transfer disconnect, teardown)
         can leak the lease. Admission is accounted SYNCHRONOUSLY (same
         rationale as _admit_serve: buffered frames arrive without
-        yielding to the loop)."""
+        yielding to the loop). ``handed``: the loop's clock as the frame
+        reached the dispatcher."""
         try:
             idx = self._check_index(msg)
         except PieceError as e:
@@ -343,7 +367,7 @@ class Dispatcher:
             msg.release()
             return
         peer.receiving += 1
-        t = self._spawn_io(peer, self._on_payload(peer, idx, msg))
+        t = self._spawn_io(peer, self._on_payload(peer, idx, msg, handed))
 
         def release(_task: asyncio.Task) -> None:
             peer.receiving -= 1
@@ -387,7 +411,7 @@ class Dispatcher:
         elif msg.type == MsgType.PIECE_PAYLOAD:
             # Cold path: payloads that queued before the fast-path handler
             # was registered (or in unit tests driving _handle directly).
-            self._spawn_payload(peer, msg)
+            self._spawn_payload(peer, msg, peer.last_useful)
         elif msg.type == MsgType.ANNOUNCE_PIECE:
             peer.has.add(self._check_index(msg))
             self._spawn_io(peer, self._request_more(peer))
@@ -408,15 +432,13 @@ class Dispatcher:
         elif msg.type == MsgType.ERROR:
             raise ConnClosedError(msg.header.get("detail", "peer error"))
 
-    async def _on_payload(self, peer: _Peer, idx: int, msg: Message) -> None:
+    @stepped("p2p.payload")
+    async def _on_payload(self, peer: _Peer, idx: int, msg: Message,
+                          handed: float) -> None:
         data = msg.payload  # bytes or a pooled memoryview -- both flow
         # through verify and os.pwrite untouched; the buffer returns via
         # _spawn_payload's done-callback AFTER the bitfield mark below.
         t_req = self._req_ts.pop(idx, None)
-        if t_req is not None:
-            self._stage_piece_wait += (
-                asyncio.get_running_loop().time() - t_req
-            )
         self.events.emit(
             "receive_piece", self.torrent.info_hash.hex,
             peer=peer.conn.peer_id.hex, piece=idx, size=len(data),
@@ -427,32 +449,43 @@ class Dispatcher:
             self.requests.clear_piece(idx)
             await self._request_more(peer)
             return
-        # Per-piece receive span (verify + pwrite) -- gated on the
-        # trace's sampled flag so the data-plane hot path pays nothing
-        # on unsampled pulls (the trace-on overhead band pins this).
+        # Per-piece receive span (verify, pwrite, the slot's refill) --
+        # gated on the trace's sampled flag so the data-plane hot path
+        # pays nothing on unsampled pulls (the trace-on overhead band
+        # pins this).
         cm = (
             trace.span("p2p.piece.receive", piece=idx, size=len(data),
                        peer=peer.conn.peer_id.hex[:12])
             if trace.current_traceparent(sampled_only=True) is not None
             else contextlib.nullcontext()
         )
-        with cm:
+        times = PieceTimes(handed)
+        with cm as sp:
             # Ring-backed payloads (leech shard plane) carry a lease
             # whose remote_write pwrites in the worker that already
             # holds the bytes -- verify here reads the shared mmap
             # zero-copy, and only the verdict crosses the fork.
             rw = getattr(msg.lease, "remote_write", None)
             completed = await self.torrent.write_piece(
-                idx, data, remote_write=rw
+                idx, data, remote_write=rw, times=times
             )  # raises PieceError
-        self.requests.clear_piece(idx)
-        # Fan the new piece out to the swarm.
-        for other in list(self._peers.values()):
-            if other.conn.peer_id != peer.conn.peer_id:
-                try:
-                    await other.conn.send(Message.announce_piece(idx))
-                except ConnClosedError:
-                    pass
+            self.requests.clear_piece(idx)
+            # Fan the new piece out to the swarm.
+            for other in list(self._peers.values()):
+                if other.conn.peer_id != peer.conn.peer_id:
+                    try:
+                        await other.conn.send(Message.announce_piece(idx))
+                    except ConnClosedError:
+                        pass
+            if not completed:
+                await self._request_more(peer)
+            if t_req is not None and times.written is not None:
+                self._book_stages(
+                    t_req, msg.t_head, times,
+                    asyncio.get_running_loop().time() if not completed
+                    else times.written,
+                    sp,
+                )
         if completed:
             if not self.done.done():
                 self.done.set_result(None)
@@ -484,8 +517,24 @@ class Dispatcher:
                     await other.conn.send(Message.complete())
                 except ConnClosedError:
                     pass
-        else:
-            await self._request_more(peer)
+
+    def _book_stages(self, t_req: float, t_head: float | None,
+                     times: PieceTimes, refilled: float, sp) -> None:
+        """One piece's six stages (PIECE_STAGES), from the readings taken
+        on its way: each boundary is read once, so the stages sum to
+        ``refilled - t_req``."""
+        head = times.handed if t_head is None else t_head
+        seconds = (
+            head - t_req, times.handed - head,
+            times.flushed - times.handed, times.verdict - times.flushed,
+            times.written - times.verdict, refilled - times.written,
+        )
+        self._stage_piece_wait += times.handed - t_req
+        for stage, s in zip(PIECE_STAGES, seconds):
+            self._h_stage.observe(s, stage=stage)
+        if sp is not None:
+            sp.set(**{f"{stage}_s": round(s, 6)
+                      for stage, s in zip(PIECE_STAGES, seconds)})
 
     def stage_split(self) -> dict:
         """Public read of the per-pull stage walls (the scheduler's
@@ -518,6 +567,7 @@ class Dispatcher:
             if v - self._plane0.get(k, 0) > 0
         }
 
+    @stepped("p2p.request")
     async def _request_more(self, peer: _Peer) -> None:
         if self.torrent.complete():
             return

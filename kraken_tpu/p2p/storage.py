@@ -25,6 +25,7 @@ from kraken_tpu.core.digest import Digest
 from kraken_tpu.core.hasher import PieceHasher, get_hasher
 from kraken_tpu.core.metainfo import MetaInfo
 from kraken_tpu.store import CAStore, PieceStatusMetadata
+from kraken_tpu.utils.pushsteps import stepped
 
 
 _log = logging.getLogger("kraken.storage")
@@ -32,6 +33,22 @@ _log = logging.getLogger("kraken.storage")
 
 class PieceError(Exception):
     pass
+
+
+class PieceTimes:
+    """``time.monotonic`` readings of one received piece on its way
+    through :meth:`Torrent.write_piece`: ``handed`` (the dispatcher had
+    the whole payload), ``flushed`` (the verifier's flush that took it),
+    ``verdict`` (its digest compared, back on the loop) and ``written``
+    (the data write returned; None for a duplicate, which writes
+    nothing). The dispatcher turns them into the piece's stages."""
+
+    __slots__ = ("handed", "flushed", "verdict", "written")
+
+    def __init__(self, handed: float):
+        self.handed = handed
+        self.flushed = self.verdict = handed
+        self.written: Optional[float] = None
 
 
 class BatchedVerifier:
@@ -52,17 +69,31 @@ class BatchedVerifier:
       tile kernel takes as long over one piece as over sixteen.
 
     ``max_batch`` caps a flush; what is left over goes next.
+
+    On a device hasher the chip's gaps between sections are split by what
+    the host was doing (``verify_starved_seconds_total``): a section that
+    ends with nothing queued stamps the time, and the next arrival with
+    nothing in flight books the seconds since -- the part of the gap in
+    which no piece had reached the verifier. The rest of the gap, to the
+    next section's enter (``hasher_device_idle_seconds_total``), is the
+    host's dispatch: the way back to the loop, the flush, the thread hop
+    and the staging.
     """
 
-    def __init__(self, hasher: PieceHasher | None = None, max_batch: int = 1024):
+    def __init__(self, hasher: PieceHasher | None = None, max_batch: int = 1024,
+                 clock=_time.monotonic):
         # Public: the agent's scrubber reuses this hasher's pool for its
         # digest work (assembly wiring) -- renaming it must break loudly.
         self.hasher = hasher or get_hasher("cpu")
         self._max_batch = max_batch
+        self._clock = clock
         self._device = getattr(self.hasher, "name", "cpu") != "cpu"
-        self._queue: list[tuple[bytes, bytes, asyncio.Future]] = []
+        self._queue: list[
+            tuple[bytes, bytes, asyncio.Future, Optional[PieceTimes]]
+        ] = []
         self._flusher: Optional[asyncio.Task] = None
         self._inflight: set[asyncio.Task] = set()  # strong refs to hash tasks
+        self._idle_since: Optional[float] = None  # device: last section's end
         # Coalescing observability (cached refs: one flush per batch, but
         # the degenerate batch-of-1 case this exists to expose IS the
         # per-piece path): the size histogram says whether arrivals
@@ -80,6 +111,15 @@ class BatchedVerifier:
             "verify_batches_total",
             "Verify flushes dispatched, by hash path (host|tpu)",
         )
+        self._c_pieces = REGISTRY.counter(
+            "verify_pieces_total", "Pieces through batched verification"
+        )
+        self._c_starved = REGISTRY.counter(
+            "verify_starved_seconds_total",
+            "Seconds a device verifier sat with no section in flight and no"
+            " piece queued: from a section's end with an empty queue to the"
+            " next arrival",
+        )
         self._c_held = REGISTRY.counter(
             "verify_held_pieces_total",
             "Pieces that found a device verify section in flight and were "
@@ -87,13 +127,19 @@ class BatchedVerifier:
         )
         self._path_label = "tpu" if self._device else "host"
 
-    async def verify(self, data: bytes | memoryview, expected: bytes) -> bool:
+    async def verify(self, data: bytes | memoryview, expected: bytes,
+                     times: Optional[PieceTimes] = None) -> bool:
+        """``times``, when given, gets the reading of the flush that takes
+        the piece (``flushed``)."""
         # ``data`` may be a pooled memoryview (zero-copy recv path): the
         # caller keeps its lease alive until this returns, and hashlib
         # consumes buffer-protocol objects directly.
         loop = asyncio.get_running_loop()
         fut: asyncio.Future[bool] = loop.create_future()
-        self._queue.append((data, expected, fut))
+        self._queue.append((data, expected, fut, times))
+        if self._idle_since is not None and not self._inflight:
+            self._c_starved.inc(self._clock() - self._idle_since)
+            self._idle_since = None
         if self._device and self._inflight:
             self._c_held.inc()  # the section in flight flushes it as it ends
         elif len(self._queue) >= self._max_batch:
@@ -111,6 +157,7 @@ class BatchedVerifier:
         await asyncio.sleep(0)
         self._flush_now()
 
+    @stepped("verify.flush")
     def _flush_now(self) -> None:
         if self._device and self._inflight:
             return  # held: the section in flight flushes the queue as it ends
@@ -118,15 +165,11 @@ class BatchedVerifier:
         self._queue = self._queue[self._max_batch :]
         if not batch:
             return
-        from kraken_tpu.utils.metrics import REGISTRY
-
-        REGISTRY.counter(
-            "verify_pieces_total", "Pieces through batched verification"
-        ).inc(len(batch))
-        REGISTRY.gauge(
-            "verify_batch_occupancy",
-            "Batch fill of the last verify flush (batched / max_batch)",
-        ).set(len(batch) / self._max_batch)
+        now = self._clock()
+        for _d, _e, _f, times in batch:
+            if times is not None:
+                times.flushed = now
+        self._c_pieces.inc(len(batch))
         self._h_batch_size.observe(len(batch))
         self._c_batches.inc(1, path=self._path_label)
         # The hash itself runs OFF the event loop: a full batch is hundreds
@@ -144,11 +187,16 @@ class BatchedVerifier:
         # so a failed batch never wedges the device slot and every pull
         # behind it.
         self._inflight.discard(t)
-        if self._device and self._queue:
+        if not self._device:
+            return
+        if self._queue:
             self._flush_now()
+        elif not self._inflight:
+            self._idle_since = self._clock()  # starved until the next arrival
 
+    @stepped("verify.flush")
     async def _hash_off_loop(
-        self, batch: list[tuple[bytes, bytes, asyncio.Future]]
+        self, batch: list[tuple[bytes, bytes, asyncio.Future, object]]
     ) -> None:
         # Drop abandoned entries BEFORE touching their buffers: a waiter
         # cancelled mid-verify (torrent teardown, peer drop) releases its
@@ -157,19 +205,19 @@ class BatchedVerifier:
         # memoryview would fail the whole batch and blacklist innocent
         # peers of unrelated torrents. A cancelled await marks its future
         # done, so this filter removes exactly the doomed entries.
-        batch = [(d, e, f) for d, e, f in batch if not f.done()]
+        batch = [entry for entry in batch if not entry[2].done()]
         if not batch:
             return
         try:
             digests = await asyncio.to_thread(
-                self.hasher.hash_batch, [d for d, _e, _f in batch]
+                self.hasher.hash_batch, [entry[0] for entry in batch]
             )
         except Exception:
             # One bad entry (e.g. a buffer released in the race window
             # between the filter above and the hash) must not fail its
             # batch-mates: retry per item, failing only what individually
             # fails.
-            for d, expected, fut in batch:
+            for d, expected, fut, _times in batch:
                 if fut.done():
                     continue
                 try:
@@ -181,7 +229,7 @@ class BatchedVerifier:
                     if not fut.done():
                         fut.set_exception(e)
             return
-        for (d, expected, fut), got in zip(batch, digests):
+        for (_d, expected, fut, _times), got in zip(batch, digests):
             if not fut.done():
                 fut.set_result(bytes(got) == expected)
 
@@ -272,7 +320,8 @@ class Torrent:
         self._bits_flusher: Optional[asyncio.Task] = None
         # Cumulative per-piece stage walls for the torrent_summary
         # stage split (dispatch.py): how long this torrent's pieces
-        # spent parked on verify vs the data write. Pieces pipeline, so
+        # spent from the dispatcher's hand-off to the verdict (queue +
+        # verify) vs the data write. Pieces pipeline, so
         # these OVERLAP each other and the wire wait -- they sum past
         # the pull's wall clock; they are stage COSTS, not a timeline.
         self.verify_wall = 0.0
@@ -460,6 +509,7 @@ class Torrent:
         i: int,
         data: bytes | memoryview,
         remote_write=None,
+        times: Optional[PieceTimes] = None,
     ) -> bool:
         """Verify + persist piece ``i``. Returns True when this write
         completed the torrent. Raises :class:`PieceError` on corrupt data
@@ -477,7 +527,11 @@ class Torrent:
         (bit set only after the data is durably written) holds
         unchanged. A remote write that fails (worker died mid-flight)
         raises, the piece stays unmarked, and the dispatcher requeues
-        it like any peer error."""
+        it like any peer error.
+
+        ``times`` (the dispatcher's, from when it had the payload) gets
+        the piece's readings; without it they start here. The stage
+        walls add ``verdict - handed`` and ``written - verdict``."""
         if self._status is None:
             # With endgame duplication a second copy of the final piece
             # can arrive after completion: a benign duplicate, never a
@@ -488,10 +542,14 @@ class Torrent:
                 f"piece {i}: wrong length {len(data)} != "
                 f"{self.metainfo.piece_length_of(i)}"
             )
-        t0 = _time.perf_counter()
-        if not await self._verifier.verify(data, self.metainfo.piece_hash(i)):
+        if times is None:
+            times = PieceTimes(_time.monotonic())
+        if not await self._verifier.verify(
+            data, self.metainfo.piece_hash(i), times
+        ):
             raise PieceError(f"piece {i}: digest mismatch")
-        self.verify_wall += _time.perf_counter() - t0
+        times.verdict = _time.monotonic()
+        self.verify_wall += times.verdict - times.handed
         if self._status is None or self._status.has(i):
             return False  # duplicate arrival (endgame copies are benign)
         # The data write runs OUTSIDE the lock: pieces occupy disjoint
@@ -501,12 +559,12 @@ class Torrent:
         # rewrites identical bytes -- benign. Completion cannot race this
         # write: it requires every bit set, and piece i's bit is only set
         # below, after this write returns.
-        t0 = _time.perf_counter()
         if remote_write is not None:
             await remote_write(i)
         else:
             await asyncio.to_thread(self._write_at, i, data)
-        self.write_wall += _time.perf_counter() - t0
+        times.written = _time.monotonic()
+        self.write_wall += times.written - times.verdict
         async with self._lock:
             # Re-check under the lock: a concurrent writer of the same
             # final piece may have completed the torrent (set _status to

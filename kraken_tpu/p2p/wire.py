@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import asyncio
 import enum
+import time
 from typing import Any, Iterable, Optional
 
 import msgpack
@@ -76,9 +77,14 @@ class Message:
     ``payload`` is ``bytes`` for control frames and (on the pooled recv
     path) a ``memoryview`` into a leased buffer for PIECE_PAYLOAD;
     ``release()`` returns that buffer to its pool and is a no-op for
-    unpooled messages, so consumers call it unconditionally."""
+    unpooled messages, so consumers call it unconditionally.
 
-    __slots__ = ("type", "header", "payload", "lease")
+    ``t_head`` is when :func:`recv_message` had the frame's prefix
+    (``time.monotonic``): where a piece's ``wait`` on the wire ends and
+    its ``recv`` begins (p2p/dispatch.py). None for a frame built here or
+    handed over by a leech worker."""
+
+    __slots__ = ("type", "header", "payload", "lease", "t_head")
 
     def __init__(
         self,
@@ -91,6 +97,7 @@ class Message:
         self.header = header or {}
         self.payload = payload
         self.lease = lease
+        self.t_head: Optional[float] = None
 
     def release(self) -> None:
         lease, self.lease = self.lease, None
@@ -295,6 +302,7 @@ async def recv_message(
         prefix = await reader.readexactly(9)
     except asyncio.IncompleteReadError as e:
         raise WireError("connection closed") from e
+    t_head = time.monotonic()
     mtype = prefix[0]
     header_len = int.from_bytes(prefix[1:5], "big")
     payload_len = int.from_bytes(prefix[5:9], "big")
@@ -342,4 +350,6 @@ async def recv_message(
             payload = await reader.readexactly(payload_len)
         except asyncio.IncompleteReadError as e:
             raise WireError("connection closed mid-frame") from e
-    return Message(t, header, payload, lease=lease)
+    msg = Message(t, header, payload, lease=lease)
+    msg.t_head = t_head
+    return msg

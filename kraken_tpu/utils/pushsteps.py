@@ -40,6 +40,17 @@ Counters, per process: ``origin_push_steps_total{step}`` and
 ``process_thread_cpu_seconds_total``). Read them with the process's own
 bill (utils/metrics.py ``collect_process``) over
 two scrapes: ``python -m kraken_tpu.utils.pushsteps before after``.
+
+In an agent the same families hold the agent's steps, under the same
+names (the families keep the origin's prefix): the pull path's loop
+stretches are ``p2p.recv`` (a conn's receive loop, slice by slice: frame
+parsing and the hand-off of each payload), ``p2p.payload`` (a received
+piece's task on the loop, one entry a piece), ``p2p.request`` (request
+selection and the frames it queues) and ``verify.flush`` (the piece
+verifier's loop side: a flush, and its section's task on the loop), with
+``thread.<function>`` for its ``to_thread`` calls, ``http.*`` for its
+server and ``loop.read`` / ``loop.write`` for its sockets as in any node.
+``--unit p2p.payload`` reads the table a piece.
 """
 
 from __future__ import annotations
@@ -376,6 +387,25 @@ def name_http_server() -> None:
             setattr(RequestHandler, method, stepped(step)(fn))
 
 
+def name_socket_io() -> None:
+    """What the loop runs for a socket outside every task is asyncio's
+    transport: its callbacks are wrapped where they are defined, once a
+    process: ``loop.read`` (``_read_ready``: the ``recv`` and the
+    protocol's ``data_received``, which for a stream copies the bytes
+    into its reader's buffer) and ``loop.write`` (what a full socket
+    buffer left of a write, sent as the socket drains). Steps opened
+    inside (aiohttp's ``http.parse``) come off them."""
+    from asyncio import selector_events
+
+    transport = selector_events._SelectorSocketTransport
+    for method, step in (("_read_ready", "loop.read"),
+                         ("_write_send", "loop.write"),
+                         ("_write_sendmsg", "loop.write")):
+        fn = getattr(transport, method, None)
+        if fn is not None and not hasattr(fn, "__wrapped__"):
+            setattr(transport, method, stepped(step)(fn))
+
+
 def handler_step(name: str):
     """Name an aiohttp handler's step: the middleware of utils/metrics.py
     books what the request costs the loop outside the steps the handler
@@ -417,12 +447,15 @@ def _samples(text: str) -> dict[tuple[str, tuple], float]:
     return out
 
 
-def push_step_table(before: str, after: str) -> dict:
-    """What two scrapes of one origin say a push cost its interpreter.
-    A push is one entry into ``create``. Seconds are a push's; ``cpu`` is
-    user + system of the thread inside the step; ``coverage`` is the
-    steps' cpu over the thread class's own (user + system), class by
-    class: what share of the bill the named steps account for."""
+def push_step_table(before: str, after: str, unit: str = "create") -> dict:
+    """What two scrapes of one process say a unit of its work cost its
+    interpreter. A unit is one entry into the step ``unit``: ``create``,
+    a push on an origin; ``p2p.payload``, a piece received on an agent.
+    ``pushes`` is the number of units, and every figure is a unit's:
+    seconds, entries (``entries_a_push``); ``cpu`` is user + system of
+    the thread inside the step; ``coverage`` is the steps' cpu over the
+    thread class's own (user + system), class by class: what share of the
+    bill the named steps account for."""
     b, a = _samples(before), _samples(after)
 
     def grown(family: str) -> dict[tuple, float]:
@@ -435,9 +468,9 @@ def push_step_table(before: str, after: str) -> dict:
         dict(k)["step"]: v
         for k, v in grown("origin_push_steps_total").items()
     }
-    pushes = entries.get("create", 0.0)
+    pushes = entries.get(unit, 0.0)
     if not pushes:
-        return {"pushes": 0}
+        return {"pushes": 0, "unit": unit}
     seconds: dict[tuple[str, str], dict[str, float]] = {}
     for k, v in grown("origin_push_step_seconds_total").items():
         k = dict(k)
@@ -475,16 +508,17 @@ def push_step_table(before: str, after: str) -> dict:
         )
         bill = row.get("user", 0.0) + row.get("system", 0.0)
         row["coverage"] = row["steps_cpu"] / bill if bill else None
-    return {"pushes": pushes, "cpu_s": process, "switches": switches,
-            "classes": classes, "steps": steps}
+    return {"pushes": pushes, "unit": unit, "cpu_s": process,
+            "switches": switches, "classes": classes, "steps": steps}
 
 
 def render_table(table: dict) -> str:
+    unit = table.get("unit", "create")
     if not table["pushes"]:
-        return "no push between the two scrapes (create did not grow)\n"
+        return f"no unit between the two scrapes ({unit} did not grow)\n"
     ms = lambda s: "      -" if s is None else f"{s * 1e3:7.3f}"  # noqa: E731
     out = [
-        f"pushes {table['pushes']:.0f}; a push: cpu user "
+        f"units ({unit}) {table['pushes']:.0f}; a unit: cpu user "
         f"{ms(table['cpu_s'].get('user'))} ms, system "
         f"{ms(table['cpu_s'].get('system'))} ms; switches voluntary "
         f"{table['switches'].get('voluntary', 0.0):.1f}, involuntary "
@@ -495,7 +529,7 @@ def render_table(table: dict) -> str:
         cover = "      -" if row["coverage"] is None else f"{row['coverage']:7.1%}"
         out.append(f"{cls:8} {ms(row.get('user'))}  {ms(row.get('system'))}"
                    f"   {ms(row['steps_cpu'])}   {cover}")
-    out.append("step                     class   a push   cpu ms  wall ms"
+    out.append("step                     class   a unit   cpu ms  wall ms"
                "  wait ms  cpu us/entry")
     for r in table["steps"]:
         each = ("" if r["cpu_s"] is None or not r["entries_a_push"]
@@ -513,14 +547,18 @@ def main(argv: list[str] | None = None) -> int:
     import sys
 
     ap = argparse.ArgumentParser(
-        description="The push-step table of one origin over two scrapes of"
-                    " its /metrics (files, as `curl` wrote them).")
+        description="The step table of one node over two scrapes of its"
+                    " /metrics (files, as `curl` wrote them).")
     ap.add_argument("before")
     ap.add_argument("after")
+    ap.add_argument("--unit", default="create",
+                    help="the step one entry of which is a unit of work:"
+                         " create (a push, on an origin; the default) or"
+                         " p2p.payload (a piece received, on an agent)")
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
     with open(args.before) as f, open(args.after) as g:
-        table = push_step_table(f.read(), g.read())
+        table = push_step_table(f.read(), g.read(), unit=args.unit)
     sys.stdout.write(json.dumps(table) + "\n" if args.json else render_table(table))
     return 0
 

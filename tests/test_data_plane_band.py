@@ -50,7 +50,7 @@ def test_pair_pump_knockout_regression_band(monkeypatch):
 
     full = _median_wall(3, blob_mb=64, piece_kb=256)
 
-    async def _verified(self, data, expected):
+    async def _verified(self, data, expected, times=None):
         return True
 
     monkeypatch.setattr(st.BatchedVerifier, "verify", _verified)
@@ -86,7 +86,7 @@ def test_pair_pump_knockout_band_with_workers(monkeypatch):
 
     full = _median_wall(3, blob_mb=64, piece_kb=256, workers=2)
 
-    async def _verified(self, data, expected):
+    async def _verified(self, data, expected, times=None):
         return True
 
     monkeypatch.setattr(st.BatchedVerifier, "verify", _verified)
@@ -123,7 +123,7 @@ def test_pair_pump_knockout_band_with_leech_workers(monkeypatch):
 
     full = _median_wall(3, blob_mb=64, piece_kb=256, leech_workers=2)
 
-    async def _verified(self, data, expected):
+    async def _verified(self, data, expected, times=None):
         return True
 
     monkeypatch.setattr(st.BatchedVerifier, "verify", _verified)

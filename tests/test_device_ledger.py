@@ -469,3 +469,35 @@ def test_device_hasher_exports_identity_and_peak_memory():
     assert 'hasher_device_memory_peak_bytes{hasher="tpu"}' in text
     assert hasher.devices() == [dev]
     assert DEVICE_LEDGER.held_seconds() >= 0
+
+
+def test_idle_gap_is_booked_to_the_section_that_ends_it():
+    """``hasher_device_idle_seconds_total``: the process's first section
+    books no gap, a section that enters while another is open books none,
+    and a gap is booked to the labels of the section that enters."""
+    clock, registry = FakeClock(), Registry()
+    ledger = DeviceLedger(clock=clock, registry=registry)
+    idle = registry.counter("hasher_device_idle_seconds_total")
+
+    def section(purpose, kernel):
+        return ledger.section(purpose, kernel, rows=1, blocks=1,
+                              useful_blocks=1, payload_bytes=64)
+
+    clock.t = 10.0
+    with section("verify", "sha256_tiles"):
+        clock.t = 11.0
+    assert idle.total() == 0  # nothing before the first section
+    clock.t = 13.5
+    with section("verify", "sha256_ragged_tiles"):
+        clock.t = 14.0
+        with section("piece", "sha256_tiles"):  # one is open: no gap
+            clock.t = 15.0
+        clock.t = 16.0
+    assert idle.value(purpose="verify", kernel="sha256_ragged_tiles") == 2.5
+    assert idle.value(purpose="piece", kernel="sha256_tiles") == 0
+    clock.t = 20.0
+    with section("piece", "sha256_tiles"):
+        clock.t = 21.0
+    assert idle.value(purpose="piece", kernel="sha256_tiles") == 4.0
+    assert idle.value(purpose="verify", kernel="sha256_tiles") == 0
+    assert idle.total() == 6.5

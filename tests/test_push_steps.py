@@ -393,3 +393,98 @@ def test_no_proc_no_thread_family(tmp_path, monkeypatch, how):
     text = reg.render()
     assert "process_thread_cpu_seconds_total{" not in text
     assert 'process_cpu_seconds_total{mode="user"}' in text  # getrusage needs no /proc
+
+
+def test_step_table_of_an_agent_is_read_a_piece(tmp_path, capsys):
+    """``unit="p2p.payload"``: every figure is a received piece's, and the
+    loop's coverage is its named steps' cpu over its own bill. The same
+    scrapes read a push at a time have no unit in them."""
+    from kraken_tpu.utils.pushsteps import main
+
+    before = "\n".join((
+        'origin_push_steps_total{step="p2p.payload"} 50',
+        'origin_push_step_seconds_total{class="loop",clock="cpu",step="p2p.payload"} 0.1',
+        'process_cpu_seconds_total{mode="user"} 1.0',
+        'process_thread_cpu_seconds_total{class="loop",mode="user"} 0.5',
+    ))
+    after = "\n".join((
+        "# TYPE origin_push_steps_total counter",
+        'origin_push_steps_total{step="p2p.payload"} 250',
+        'origin_push_steps_total{step="p2p.request"} 450',
+        'origin_push_step_seconds_total{class="loop",clock="cpu",step="p2p.payload"} 0.5',
+        'origin_push_step_seconds_total{class="loop",clock="wall",step="p2p.payload"} 0.7',
+        'origin_push_step_seconds_total{class="loop",clock="cpu",step="p2p.recv"} 0.6',
+        'origin_push_step_seconds_total{class="loop",clock="wall",step="p2p.recv"} 0.6',
+        'origin_push_step_seconds_total{class="loop",clock="cpu",step="p2p.request"} 0.2',
+        'origin_push_step_seconds_total{class="loop",clock="wall",step="p2p.request"} 0.2',
+        'origin_push_step_seconds_total{class="worker",clock="cpu",'
+        'step="thread.TPUPieceHasher.hash_batch"} 0.8',
+        'origin_push_step_seconds_total{class="worker",clock="wall",'
+        'step="thread.TPUPieceHasher.hash_batch"} 2.0',
+        'process_cpu_seconds_total{mode="user"} 3.0',
+        'process_cpu_seconds_total{mode="system"} 0.4',
+        'process_thread_cpu_seconds_total{class="loop",mode="user"} 1.5',
+        'process_thread_cpu_seconds_total{class="loop",mode="system"} 0.5',
+        'process_thread_cpu_seconds_total{class="worker",mode="user"} 1.0',
+    ))
+    table = push_step_table(before, after, unit="p2p.payload")
+    assert table["pushes"] == 200 and table["unit"] == "p2p.payload"
+    assert table["cpu_s"] == pytest.approx({"user": 0.01, "system": 0.002})
+    rows = {(r["step"], r["class"]): r for r in table["steps"]}
+    payload = rows["p2p.payload", "loop"]
+    assert payload["entries_a_push"] == 1.0
+    assert payload["cpu_s"] == pytest.approx(0.002)
+    assert payload["waiting_s"] == pytest.approx(0.0015)  # 0.7 - 0.4 s
+    assert rows["p2p.request", "loop"]["entries_a_push"] == pytest.approx(2.25)
+    # The loop: 1.2 s of named steps over 1.5 s of its own.
+    assert table["classes"]["loop"]["coverage"] == pytest.approx(0.8)
+    assert table["classes"]["worker"]["coverage"] == pytest.approx(0.8)
+    assert push_step_table(before, after) == {"pushes": 0, "unit": "create"}
+    for name, text in (("before", before), ("after", after)):
+        (tmp_path / name).write_text(text)
+    assert main(["--unit", "p2p.payload", str(tmp_path / "before"),
+                 str(tmp_path / "after")]) == 0
+    out = capsys.readouterr().out
+    assert out.startswith("units (p2p.payload) 200;") and "p2p.recv" in out
+
+
+def test_socket_reads_and_writes_are_loop_steps(monkeypatch):
+    """``name_socket_io``: asyncio's transport callbacks are steps of the
+    loop, wrapped once however often asked. A stream's reads of a 16 MiB
+    payload book ``loop.read``; what the socket buffer could not take at
+    once goes out under ``loop.write``."""
+    from asyncio import selector_events
+
+    from kraken_tpu.utils.pushsteps import name_socket_io
+
+    transport = selector_events._SelectorSocketTransport
+    for method in ("_read_ready", "_write_send", "_write_sendmsg"):
+        if hasattr(transport, method):  # put back as it was afterwards
+            monkeypatch.setattr(transport, method, getattr(transport, method))
+    name_socket_io()
+    name_socket_io()
+    assert not hasattr(transport._read_ready.__wrapped__, "__wrapped__")
+    before = PUSH_STEPS.totals()[0]
+    payload = os.urandom(1 << 20) * 16
+
+    async def main():
+        got = asyncio.get_running_loop().create_future()
+
+        async def on_conn(reader, writer):
+            got.set_result(await reader.readexactly(len(payload)))
+            writer.close()
+
+        server = await asyncio.start_server(on_conn, "127.0.0.1", 0)
+        port = server.sockets[0].getsockname()[1]
+        _reader, writer = await asyncio.open_connection("127.0.0.1", port)
+        writer.write(payload)
+        await writer.drain()
+        assert await asyncio.wait_for(got, 10) == payload
+        writer.close()
+        server.close()
+        await server.wait_closed()
+
+    asyncio.run(main())
+    after = PUSH_STEPS.totals()[0]
+    for step in ("loop.read", "loop.write"):
+        assert after.get(step, 0) > before.get(step, 0), step

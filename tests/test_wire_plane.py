@@ -441,7 +441,7 @@ def test_payload_flood_bound_sheds_and_releases(tmp_path):
         d = Dispatcher(t)
         hold = asyncio.Event()
 
-        async def parked(self, peer, idx, msg):
+        async def parked(self, peer, idx, msg, handed):
             await hold.wait()
 
         d._on_payload = parked.__get__(d)
