@@ -1457,8 +1457,9 @@ class AgentNode:
         _sync_chunkstore(self)
         # The verifier takes its batching rule from the hasher's kind:
         # CPU verify flushes every tick, any number at once; device verify
-        # keeps one section in flight and sends what arrived meanwhile as
-        # the next (BatchedVerifier).
+        # keeps at most two sections in flight, one on the chip and one
+        # staging on the host, and sends what arrived while both were out
+        # as the next (BatchedVerifier).
         # hash_workers: the same host hash pool the origin uses, here
         # feeding BatchedVerifier.hash_batch -- a multi-core agent
         # verifies a piece batch across cores instead of one. Only >= 2

@@ -51,6 +51,11 @@ class PieceTimes:
         self.written: Optional[float] = None
 
 
+# A device verifier's sections in flight: one holding the chip, one
+# staging the next batch on the host meanwhile (BatchedVerifier).
+DEVICE_SECTIONS_IN_FLIGHT = 2
+
+
 class BatchedVerifier:
     """Verifies received pieces against their expected digests, batching
     concurrent arrivals into one ``PieceHasher.hash_batch`` dispatch.
@@ -61,23 +66,30 @@ class BatchedVerifier:
     * host (``cpu``): a flush one event-loop tick after the first arrival,
       any number hashing at once -- hashlib runs across cores, and
       serialising host verify cost goodput 2.4x (test_data_plane_band.py).
-    * device (any other hasher): at most one section in flight. Pieces
-      that arrive while it runs are held, and when it ends everything held
-      goes as the next section: a batch is what arrived while the chip was
-      busy, so its size follows the arrival rate and the section time. The
-      chip runs sections one after another whatever the host does, and the
-      tile kernel takes as long over one piece as over sixteen.
+    * device (any other hasher): at most two sections in flight
+      (:data:`DEVICE_SECTIONS_IN_FLIGHT`). One holds the chip; the other is
+      taken from the queue at once and stages on the host in its own
+      thread -- the hop, ``hash_batch``'s row copies and the copy in --
+      while the chip runs the first. A piece that finds a slot free goes on
+      the next tick with whatever arrived in that tick; pieces that arrive
+      while two are in flight are held, and when either ends everything
+      held goes as the next section. A batch is what arrived in about one
+      device cycle, so its size follows the arrival rate and the section
+      time. A cap of two cannot rebuild an unbounded queue of one-piece
+      sections at the chip: the chip runs sections one after another
+      whatever the host does, at most one waits behind the one it runs,
+      and the tile kernel takes as long over one piece as over sixteen.
 
     ``max_batch`` caps a flush; what is left over goes next.
 
     On a device hasher the chip's gaps between sections are split by what
-    the host was doing (``verify_starved_seconds_total``): a section that
-    ends with nothing queued stamps the time, and the next arrival with
-    nothing in flight books the seconds since -- the part of the gap in
-    which no piece had reached the verifier. The rest of the gap, to the
-    next section's enter (``hasher_device_idle_seconds_total``), is the
-    host's dispatch: the way back to the loop, the flush, the thread hop
-    and the staging.
+    the host was doing (``verify_starved_seconds_total``): the last section
+    in flight that ends with nothing queued stamps the time, and the next
+    arrival with nothing in flight books the seconds since -- the part of
+    the gap in which no piece had reached the verifier. The rest of the
+    gap, to the next section's enter (``hasher_device_idle_seconds_total``),
+    is the host's dispatch: the way back to the loop, the flush, the thread
+    hop and the staging.
     """
 
     def __init__(self, hasher: PieceHasher | None = None, max_batch: int = 1024,
@@ -122,8 +134,13 @@ class BatchedVerifier:
         )
         self._c_held = REGISTRY.counter(
             "verify_held_pieces_total",
-            "Pieces that found a device verify section in flight and were "
+            "Pieces that found two device verify sections in flight and were "
             "held for the next one",
+        )
+        self._c_overlapped = REGISTRY.counter(
+            "verify_overlapped_sections_total",
+            "Device verify sections taken while another verify section was in"
+            " flight: staged on the host while the chip ran the other",
         )
         self._path_label = "tpu" if self._device else "host"
 
@@ -140,8 +157,8 @@ class BatchedVerifier:
         if self._idle_since is not None and not self._inflight:
             self._c_starved.inc(self._clock() - self._idle_since)
             self._idle_since = None
-        if self._device and self._inflight:
-            self._c_held.inc()  # the section in flight flushes it as it ends
+        if self._device and len(self._inflight) >= DEVICE_SECTIONS_IN_FLIGHT:
+            self._c_held.inc()  # the first section to end flushes it
         elif len(self._queue) >= self._max_batch:
             self._flush_now()
         elif self._flusher is None or self._flusher.done():
@@ -159,8 +176,8 @@ class BatchedVerifier:
 
     @stepped("verify.flush")
     def _flush_now(self) -> None:
-        if self._device and self._inflight:
-            return  # held: the section in flight flushes the queue as it ends
+        if self._device and len(self._inflight) >= DEVICE_SECTIONS_IN_FLIGHT:
+            return  # held: the first section to end flushes the queue
         batch = self._queue[: self._max_batch]
         self._queue = self._queue[self._max_batch :]
         if not batch:
@@ -172,6 +189,8 @@ class BatchedVerifier:
         self._c_pieces.inc(len(batch))
         self._h_batch_size.observe(len(batch))
         self._c_batches.inc(1, path=self._path_label)
+        if self._device and self._inflight:
+            self._c_overlapped.inc()
         # The hash itself runs OFF the event loop: a full batch is hundreds
         # of MBs (CPU: ~100+ ms; TPU: a blocking device round-trip), and an
         # on-loop hash stalls every conn pump, announce, and accept for the

@@ -277,13 +277,15 @@ def test_batched_verifier_correct_and_batches():
 
 class _GatedHasher:
     """A hasher whose ``hash_batch`` blocks until ``gate`` is set, so a test
-    holds a verify section in flight; ``name`` picks the verifier's rule.
-    A batch holding ``b"bad"`` raises, as a released pooled buffer does."""
+    holds verify sections in flight, then takes ``delay`` seconds; ``name``
+    picks the verifier's rule. A batch holding ``b"bad"`` raises, as a
+    released pooled buffer does."""
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, delay: float = 0.0):
         import threading
 
         self.name = name
+        self.delay = delay
         self.gate = threading.Event()
         self.batches: list[int] = []
         self.inflight = self.max_inflight = 0
@@ -291,6 +293,7 @@ class _GatedHasher:
 
     def hash_batch(self, pieces, purpose="verify"):
         import hashlib
+        import time
 
         import numpy as np
 
@@ -300,6 +303,7 @@ class _GatedHasher:
             self.max_inflight = max(self.max_inflight, self.inflight)
         try:
             assert self.gate.wait(timeout=10)
+            time.sleep(self.delay)
             if any(bytes(p) == b"bad" for p in pieces):
                 raise ValueError("released buffer")
             return np.stack([
@@ -324,59 +328,81 @@ async def _until(cond, timeout: float = 5.0) -> None:
         await asyncio.sleep(0.001)
 
 
-def _held() -> float:
+def _counted(name: str) -> float:
     from kraken_tpu.utils.metrics import REGISTRY
 
-    return REGISTRY.counter("verify_held_pieces_total").value()
+    return REGISTRY.counter(name).value()
+
+
+def _held() -> float:
+    return _counted("verify_held_pieces_total")
+
+
+def _overlapped() -> float:
+    return _counted("verify_overlapped_sections_total")
+
+
+async def _two_sections_out(h: "_GatedHasher", v: BatchedVerifier) -> list:
+    """Two lone pieces, each taken as a section of its own while the gate
+    is shut: both of a device verifier's slots are out."""
+    first = asyncio.create_task(v.verify(b"p0", _sha(b"p0")))
+    await _until(lambda: h.batches == [1])
+    second = asyncio.create_task(v.verify(b"p1", _sha(b"p1")))
+    await _until(lambda: h.batches == [1, 1])
+    return [first, second]
 
 
 def test_device_verifier_holds_arrivals_for_one_next_section():
-    """On a device hasher one section is in flight; the N pieces that
-    arrive meanwhile are held and go as ONE next section of N."""
+    """On a device hasher two sections are in flight, one on the chip and
+    one staging; the N pieces that arrive while both are out are held and
+    go as ONE third section of N."""
 
     async def main():
         h = _GatedHasher("tpu")
         v = BatchedVerifier(h)
-        held0 = _held()
-        first = asyncio.create_task(v.verify(b"p0", _sha(b"p0")))
-        await _until(lambda: h.batches == [1])
-        pieces = [b"p%d" % i for i in range(1, 8)]
+        held0, over0 = _held(), _overlapped()
+        out = await _two_sections_out(h, v)
+        assert _overlapped() - over0 == 1  # the second staged beside the first
+        pieces = [b"p%d" % i for i in range(2, 9)]
         rest = [asyncio.create_task(v.verify(p, _sha(p))) for p in pieces]
         for _ in range(5):
             await asyncio.sleep(0.01)
-        assert h.batches == [1]  # held: no second section while one runs
+        assert h.batches == [1, 1]  # held: no third section while two run
         assert _held() - held0 == len(pieces)
         h.gate.set()
-        assert all(await asyncio.wait_for(asyncio.gather(first, *rest), 10))
-        assert h.batches == [1, len(pieces)]
-        assert h.max_inflight == 1
+        assert all(await asyncio.wait_for(asyncio.gather(*out, *rest), 10))
+        assert h.batches == [1, 1, len(pieces)]
+        assert h.max_inflight == 2
+        await _until(lambda: not v._inflight)
         # Idle again: the next arrival goes alone, on the next tick.
+        over = _overlapped()
         assert await asyncio.wait_for(v.verify(b"x", _sha(b"x")), 10)
-        assert h.batches == [1, len(pieces), 1]
+        assert h.batches == [1, 1, len(pieces), 1]
         assert _held() - held0 == len(pieces)
+        assert _overlapped() == over  # nothing else was in flight
 
     asyncio.run(main())
 
 
 def test_device_verifier_failed_section_releases_the_slot():
     """A section that raises retries its entries one by one, fails only
-    the bad one, and frees the slot: later verifies still complete."""
+    the bad one, and frees its slot: later verifies still complete."""
 
     async def main():
         h = _GatedHasher("tpu")
         v = BatchedVerifier(h)
-        first = asyncio.create_task(v.verify(b"p0", _sha(b"p0")))
-        await _until(lambda: h.batches == [1])
+        out = await _two_sections_out(h, v)
         good = [asyncio.create_task(v.verify(p, _sha(p))) for p in (b"a", b"b")]
         bad = asyncio.create_task(v.verify(b"bad", _sha(b"bad")))
         await asyncio.sleep(0.01)
         h.gate.set()
-        assert all(await asyncio.wait_for(asyncio.gather(first, *good), 10))
+        assert all(await asyncio.wait_for(asyncio.gather(*out, *good), 10))
         with pytest.raises(ValueError):
             await asyncio.wait_for(bad, 10)
-        assert h.batches == [1, 3, 1, 1, 1]  # the batch, then one by one
+        assert h.batches == [1, 1, 3, 1, 1, 1]  # the batch, then one by one
+        await _until(lambda: not v._inflight)
         assert await asyncio.wait_for(v.verify(b"y", _sha(b"y")), 10)
-        assert h.max_inflight == 1
+        assert h.max_inflight == 2
 
     asyncio.run(main())
 
@@ -388,17 +414,48 @@ def test_device_verifier_drops_cancelled_waiters():
     async def main():
         h = _GatedHasher("tpu")
         v = BatchedVerifier(h)
-        first = asyncio.create_task(v.verify(b"p0", _sha(b"p0")))
-        await _until(lambda: h.batches == [1])
+        out = await _two_sections_out(h, v)
         mates = [asyncio.create_task(v.verify(p, _sha(p))) for p in (b"a", b"b")]
         doomed = asyncio.create_task(v.verify(b"bad", _sha(b"bad")))
         await asyncio.sleep(0.01)
         doomed.cancel()
         await asyncio.sleep(0)
         h.gate.set()
-        assert all(await asyncio.wait_for(asyncio.gather(first, *mates), 10))
+        assert all(await asyncio.wait_for(asyncio.gather(*out, *mates), 10))
         assert doomed.cancelled()
-        assert h.batches == [1, 2]
+        assert h.batches == [1, 1, 2]
+
+    asyncio.run(main())
+
+
+def test_device_verifier_never_has_a_third_section_in_flight():
+    """Under a burst of 40 arrivals a device verifier keeps at most two
+    sections out, and ``verify_overlapped_sections_total`` counts exactly
+    the sections taken while another was in flight."""
+
+    async def main():
+        h = _GatedHasher("tpu", delay=0.004)
+        h.gate.set()
+        v = BatchedVerifier(h)
+        taken: list[int] = []  # sections already out as each is taken
+        hash_off_loop = v._hash_off_loop
+
+        def spy(batch):
+            taken.append(len(v._inflight))
+            return hash_off_loop(batch)
+
+        v._hash_off_loop = spy
+        over0 = _overlapped()
+        pieces = [b"q%d" % i for i in range(40)]
+        waits = []
+        for k, p in enumerate(pieces):
+            waits.append(asyncio.create_task(v.verify(p, _sha(p))))
+            await asyncio.sleep(0.001 if k % 3 else 0)
+        assert all(await asyncio.wait_for(asyncio.gather(*waits), 10))
+        assert sum(h.batches) == len(pieces)  # none skipped, none twice
+        assert max(taken) <= 1 and h.max_inflight <= 2
+        assert _overlapped() - over0 == sum(1 for n in taken if n)
+        assert any(taken)  # some section did stage beside another
 
     asyncio.run(main())
 
