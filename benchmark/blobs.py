@@ -7,6 +7,7 @@ the blob's size. Nothing here imports the program.
 
 from __future__ import annotations
 
+import copy
 import hashlib
 
 import numpy as np
@@ -79,6 +80,16 @@ class SeededBlob:
                 pieces.append(hashlib.sha256(view[off:off + plen]).digest())
         self.hex = whole.hexdigest()
         self.piece_hashes = b"".join(pieces)
+
+    def stream(self) -> "SeededBlob":
+        """The same blob with a compare cache of its own, for one reader. A
+        rollout pulls one blob through every agent at once, and the readers
+        compare from threads of their own: sharing one cache, each would
+        evict the others' chunk, and one that reached the blob's end would
+        drop it while another was reading it."""
+        twin = copy.copy(self)
+        twin._kept = None
+        return twin
 
     def differs_at(self, offset: int, data: bytes) -> bool:
         """Whether ``data``, received at ``offset``, differs from the blob.

@@ -1,8 +1,26 @@
-"""The processes of one run: tracker, origin and agent through the CLI an
-operator calls, with the shipped ``config/*/base.yaml`` (only store paths,
-ports and the file backend's root are overridden). The component the
-configuration gives the chip goes through ``serve.py``; every other child
-is pinned to the CPU. This parent never imports JAX.
+"""The processes of one run: a tracker, an origin and one or more agents
+through the CLI an operator calls, with the shipped ``config/*/base.yaml``
+(only store paths, ports, the file backend's root and a role's own
+``settings`` are written over it). The component the configuration gives
+the chip goes through ``serve.py``; every other child is pinned to the CPU.
+This parent never imports JAX.
+
+What a configuration states of its herd:
+
+- ``herd``: a spec for ``tracker``, ``origin`` and ``agent``. A spec gives
+  the ``hasher`` and the shipped ``config``; ``override: "backend_root"``
+  points the file backend into the run's work directory, and ``settings``
+  is a mapping of top-level keys laid over the shipped file (a per-host cap:
+  ``{"p2p_bandwidth": {"egress_bps": ..., "ingress_bps": ...}}``). A role
+  with either gets a generated file that ``extends:`` the shipped one; a
+  role with neither gets the shipped path itself.
+- ``agents``: how many agents (1 when absent). The first is the role
+  ``agent``; the others are ``agent-1`` ... ``agent-{N-1}``, each with ports,
+  store and log of its own and the same ``--tracker``. They take the agent's
+  spec, with the hasher ``peer_hasher`` where the spec gives one, and run on
+  the CPU whatever ``chip`` says: one process holds the chip.
+- ``chip``: the role that goes through ``serve.py`` (``origin`` or
+  ``agent``).
 
 Child handling is copied from ``chip_smoke.py`` (PR 21), which later PRs may
 change; the yardstick may not move with it.
@@ -40,7 +58,6 @@ def free_port() -> int:
         return s.getsockname()[1]
 
 
-PORT_NAMES = ("tracker", "origin", "origin_p2p", "agent", "agent_p2p", "control")
 ADDRESS_IN_USE = re.compile(r"address already in use|Errno 98\b", re.IGNORECASE)
 
 
@@ -171,9 +188,14 @@ class Herd:
         self.work = work
         self.logs = logs
         self.chip_role = config["chip"]
+        self.agents = ["agent"] + [f"agent-{i}" for i in range(1, int(config.get("agents", 1)))]
+        self.nodes = ["origin", *self.agents]  # every role but the tracker
         self.hashers = {
             role: spec.get("hasher") for role, spec in config["herd"].items()
         }
+        peer_hasher = config["herd"]["agent"].get("peer_hasher", self.hashers["agent"])
+        for role in self.agents[1:]:
+            self.hashers[role] = peer_hasher
         if chip_hasher is not None:
             self.hashers[self.chip_role] = chip_hasher
         shutil.rmtree(work, ignore_errors=True)
@@ -181,28 +203,44 @@ class Herd:
         os.makedirs(work)
         os.makedirs(logs)
         self.backend_root = os.path.join(work, "backend")
-        self.ports = {k: free_port() for k in PORT_NAMES}
+        self.ports = self._draw_ports()
         self.children: dict[str, Child] = {}
         self.ready: dict[str, dict] = {}
         self.control: Control | None = None
 
+    def _draw_ports(self) -> dict[str, int]:
+        """A port for the tracker, two for every other role (its API and its
+        P2P listener, ``<role>_p2p``) and one for the control socket."""
+        names = ["tracker", *(n for role in self.nodes for n in (role, role + "_p2p")),
+                 "control"]
+        return {name: free_port() for name in names}
+
     def addr(self, role: str) -> str:
         return f"127.0.0.1:{self.ports[role]}"
 
+    def _spec(self, role: str) -> dict:
+        return self.config["herd"]["agent" if role in self.agents else role]
+
     def _config_path(self, role: str) -> str:
-        spec = self.config["herd"][role]
+        spec = self._spec(role)
         shipped = os.path.join(REPO, spec["config"])
-        if spec.get("override") != "backend_root":
+        backend = spec.get("override") == "backend_root"
+        settings = spec.get("settings") or {}
+        if not backend and not settings:
             return shipped
-        path = os.path.join(self.work, role + ".yaml")
-        with open(path, "w") as f:
-            f.write(
-                f"extends: {shipped}\n"
+        text = f"extends: {shipped}\n"
+        if backend:
+            text += (
                 "backends:\n"
                 '  - namespace: ".*"\n'
                 "    backend: file\n"
                 f"    config: {{root: {self.backend_root}}}\n"
             )
+        for key, value in settings.items():  # JSON is YAML's flow style
+            text += f"{key}: {json.dumps(value)}\n"
+        path = os.path.join(self.work, role + ".yaml")
+        with open(path, "w") as f:
+            f.write(text)
         return path
 
     def _argv(self, role: str) -> list[str]:
@@ -213,8 +251,9 @@ class Herd:
                 "--port", str(self.ports["tracker"]),
                 "--origins", self.addr("origin"),
             ]
+        component = "agent" if role in self.agents else role
         return [
-            role, "--host", "127.0.0.1", "--config", self._config_path(role),
+            component, "--host", "127.0.0.1", "--config", self._config_path(role),
             "--store", os.path.join(self.work, role + "-store"),
             "--port", str(self.ports[role]),
             "--p2p-port", str(self.ports[role + "_p2p"]),
@@ -252,17 +291,17 @@ class Herd:
                            os.path.join(self.logs, name + ".boot1"))
             shutil.rmtree(self.work, ignore_errors=True)
             os.makedirs(self.work)
-            self.ports = {k: free_port() for k in PORT_NAMES}
+            self.ports = self._draw_ports()
             self._boot(timeout)
 
     def _boot(self, timeout: float) -> None:
-        """Tracker first (the others announce to it), then origin and agent
-        side by side."""
+        """Tracker first (the others announce to it), then the origin and
+        every agent side by side."""
         self.spawn("tracker")
         self.ready["tracker"] = self.children["tracker"].wait_ready(timeout)
-        for role in ("origin", "agent"):
+        for role in self.nodes:
             self.spawn(role)
-        for role in ("origin", "agent"):
+        for role in self.nodes:
             self.ready[role] = self.children[role].wait_ready(timeout)
         self.control = Control(self.ports["control"])
 

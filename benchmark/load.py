@@ -2,9 +2,13 @@
 
 A push is ``POST .../uploads`` -> ``PATCH`` (16 MiB a request) ->
 ``PUT .../commit`` -> ``GET .../metainfo``; a pull is ``GET`` of the blob
-through the agent. Every answer is held to the reference as it arrives:
-the served metainfo to hashlib over the generator's bytes, the delivered
-bytes to the bytes generated. One record a blob, with the client's spans.
+through an agent. A rollout (``drive.op: "rollout"``) is one blob pulled
+through every agent of the herd at once, as a deploy pulls one layer on
+every host; it ends when every agent's pull has been compared, and the blob
+is then deleted from every agent. Every answer is held to the reference as
+it arrives: the served metainfo to hashlib over the generator's bytes, the
+delivered bytes to the bytes generated. One record a blob and agent, with
+the client's spans.
 """
 
 from __future__ import annotations
@@ -107,25 +111,26 @@ class Load:
 
     # -- one pull --------------------------------------------------------------
 
-    async def pull(self, blob: SeededBlob) -> dict:
-        rec = {"op": "pull", "index": blob.index, "bytes": blob.size,
+    async def pull(self, blob: SeededBlob, role: str = "agent") -> dict:
+        rec = {"op": "pull", "agent": role, "index": blob.index, "bytes": blob.size,
                "pieces": blob.n_pieces, "t_start": _now(), "gen_s": 0.0,
                "ok": False, "why": "", "fault": ""}
         got = 0
         differs = False
         held = bytearray()  # delivered and counted, not yet compared
+        reader = blob.stream()
 
         async def compare() -> None:
             nonlocal got, differs, held
             t = _now()
             if not differs:
-                differs = await asyncio.to_thread(blob.differs_at, got, held)
+                differs = await asyncio.to_thread(reader.differs_at, got, held)
             rec["gen_s"] += _now() - t
             got += len(held)
             held = bytearray()
 
         path = f"/namespace/{NS}/blobs/sha256:{blob.hex}"
-        async with self.http.get(self._url("agent", path)) as r:
+        async with self.http.get(self._url(role, path)) as r:
             r.raise_for_status()
             # A response arrives in reads of some 64 KiB; a hop to a thread
             # for each cost a pull a fifth of its span (PERF.md section 6,
@@ -153,16 +158,28 @@ class Load:
 
     # -- the loop ----------------------------------------------------------------
 
-    async def _one(self, blob: SeededBlob, phase: str) -> None:
+    async def _attempt(self, blob: SeededBlob, phase: str, role: str = "agent") -> dict:
+        """One push, or one pull through ``role``, recorded."""
         try:
-            rec = await (self.push(blob) if self.op == "push" else self.pull(blob))
+            rec = await (self.push(blob) if self.op == "push" else self.pull(blob, role))
         except (aiohttp.ClientError, asyncio.TimeoutError, OSError) as e:
-            rec = {"op": self.op, "index": blob.index, "bytes": blob.size,
-                   "t_start": _now(), "t_end": _now(), "gen_s": 0.0, "ok": False,
-                   "why": f"{type(e).__name__}: {e}", "fault": "unanswered"}
+            rec = {"op": "push" if self.op == "push" else "pull", "index": blob.index,
+                   "bytes": blob.size, "t_start": _now(), "t_end": _now(), "gen_s": 0.0,
+                   "ok": False, "why": f"{type(e).__name__}: {e}", "fault": "unanswered"}
+            if self.op != "push":
+                rec["agent"] = role
         rec["phase"] = phase
         self.gen_s += rec["gen_s"]
         self.records.append(rec)
+        return rec
+
+    async def _one(self, blob: SeededBlob, phase: str) -> None:
+        if self.op == "rollout":
+            agents = self.herd.agents
+            await asyncio.gather(*(self._attempt(blob, phase, role) for role in agents))
+            await asyncio.gather(*(self._delete(role, blob) for role in agents))
+            return
+        rec = await self._attempt(blob, phase)
         if self.op == "pull":
             await self._delete("agent", blob)
         elif rec["ok"] and phase == "window" and self._keep(blob):
@@ -293,7 +310,8 @@ class Load:
         await self.http.close()
 
     async def seed_pool(self) -> None:
-        """Pull cells: push this seed's deal through the (CPU) origin."""
+        """Pull and rollout cells: push this seed's deal through the (CPU)
+        origin."""
         blobs = await asyncio.gather(*[
             asyncio.to_thread(self.make_blob, i, size)
             for i, size in enumerate(self.sizes)

@@ -9,8 +9,13 @@ metric out of the line. ``ctx`` is what one run observed:
              (monotonic clock; payload on the wire, and of operations ended,
              between the open and the close)
     records  finished operations of the window phase, each with its spans
+             (a pull also with the ``agent`` role that delivered it)
     harness  {"ready_s", "warm_s", "setup_s", "gen_busy_s", "clients"}
+             (``clients``: the operations in flight at once; in a rollout
+             its clients times the herd's agents)
     prom     {component: {"before": text, "after": text}}   /metrics scrapes
+             of each role a counter check or metric file names, the chip's,
+             and in a rollout every role of the herd (``agent-1``, ...)
     trace    None, or {"busy_s", "window_s", "payload_bytes", ...}
     peaks    the device's row of peaks.json
 """

@@ -71,12 +71,21 @@ def disk_written_bytes() -> int:
     return total
 
 
-def counter_checks(config: dict, ctx: dict, payload: dict) -> dict:
+def payload_of(records: list[dict], role: str) -> dict:
+    """The payload ``role`` had to handle in the window: in a rollout the
+    pulls that agent delivered; in any other drive every operation."""
+    done = [r for r in records if r["ok"] and r.get("agent", role) == role]
+    return {"bytes": sum(r["bytes"] for r in done),
+            "pieces": sum(r.get("pieces", 0) for r in done)}
+
+
+def counter_checks(config: dict, ctx: dict) -> dict:
     """The configuration's guarantees about the serving process's own
     counters: the device path, not a host fallback, did the work."""
     out = {}
     for check in config["counter_checks"]:
         delta = prom_delta(ctx, check["component"], check["metric"], check["labels"])
+        payload = payload_of(ctx["records"], check["component"])
         if check["rule"] == "delta_at_most":
             value = delta
         elif check["rule"] == "covers_payload_bytes":
@@ -112,6 +121,45 @@ async def traced_stretch(herd: Herd, plan: dict, trace_dir: str,
     into.update(await asyncio.to_thread(herd.control.ask, op="stop_trace"))
 
 
+def scraped_roles(config: dict, bench: dict, cell: dict, herd: Herd, trace: bool) -> list:
+    """The roles whose ``/metrics`` the run scrapes before and after the
+    window: the chip's, every ``component`` the counter checks and this run's
+    metric files name, and in a rollout every process of the herd."""
+    roles = {c["component"] for c in config["counter_checks"]} | {herd.chip_role}
+    for m in contract.metrics_of(bench, cell["name"], trace):
+        with open(os.path.join(HERE, "metrics", m["name"] + ".json")) as f:
+            component = json.load(f).get("component")
+        if component:
+            roles.add(component)
+    if config["drive"]["op"] == "rollout":
+        roles.update(["tracker", *herd.nodes])
+    return sorted(roles)
+
+
+def fleet_summary(herd: Herd, records: list[dict], prom: dict) -> dict:
+    """A rollout's herd, role by role, over the window: each agent's pulls,
+    the bytes it delivered and its fastest and slowest download; the piece
+    bytes each node served and took over the swarm and the CPU seconds of
+    every process (from the two scrapes); and the share of the piece bytes
+    served that agents served each other."""
+    ctx = {"prom": prom}
+    out = {}
+    for role in ["tracker", *herd.nodes]:
+        row = {"cpu_s": prom_delta(ctx, role, "process_cpu_seconds_total", {})}
+        if role != "tracker":
+            row["piece_bytes_up"] = prom_delta(ctx, role, "p2p_piece_bytes_up_total", {})
+            row["piece_bytes_down"] = prom_delta(ctx, role, "p2p_piece_bytes_down_total", {})
+        if role in herd.agents:
+            done = [r for r in records if r.get("agent") == role and r["ok"]]
+            spans = [r["t_end"] - r["t_start"] for r in done]
+            row.update(pulls=len(done), bytes=sum(r["bytes"] for r in done),
+                       fastest_s=min(spans, default=None), slowest_s=max(spans, default=None))
+        out[role] = row
+    by_agents = sum(out[role]["piece_bytes_up"] for role in herd.agents)
+    served = by_agents + out["origin"]["piece_bytes_up"]
+    return {"roles": out, "agents_served_share": by_agents / served if served else None}
+
+
 def label_gaps(reduced: dict, stretch: dict, records: list[dict]) -> list:
     """The idle gaps of one stretch, each named by the client phase that
     covered most of it. The trace's clock is set against the host's at the
@@ -138,7 +186,7 @@ def label_gaps(reduced: dict, stretch: dict, records: list[dict]) -> list:
     return out
 
 
-async def drive(args, cell: dict, herd: Herd, config: dict, mix: dict,
+async def drive(args, bench: dict, cell: dict, herd: Herd, config: dict, mix: dict,
                 out_dir: str) -> dict:
     """Everything between the herd's READY lines and its stop."""
     t_ready = time.monotonic()
@@ -153,7 +201,7 @@ async def drive(args, cell: dict, herd: Herd, config: dict, mix: dict,
     load = Load(herd, config, mix, args.seed, sizes)
     await load.open()
     try:
-        if load.op == "pull":
+        if load.op in ("pull", "rollout"):
             t = time.monotonic()
             await load.seed_pool()
             say(event="seeded", blobs=len(load.pool), seconds=time.monotonic() - t)
@@ -169,8 +217,7 @@ async def drive(args, cell: dict, herd: Herd, config: dict, mix: dict,
         say(event="warmed", operations=n_warm, seconds=warm_s,
             compiles=warmed["compiles"], compile_s=warmed["compile_s"])
 
-        roles = sorted({c["component"] for c in config["counter_checks"]}
-                       | {herd.chip_role})
+        roles = scraped_roles(config, bench, cell, herd, bool(args.trace))
         prom = {role: {"before": await load.metrics_text(role)} for role in roles}
         stretch: dict = {}
         on_open = None
@@ -192,6 +239,8 @@ async def drive(args, cell: dict, herd: Herd, config: dict, mix: dict,
         await load.close()
 
     records = [r for r in load.records if r["phase"] == "window"]
+    # A rollout keeps a pull in flight on every agent for each of its clients.
+    streams = load.clients * (len(herd.agents) if load.op == "rollout" else 1)
     ctx = {
         # bytes_moved: payload on the wire between the open and the close.
         # bytes_done: payload of the operations that ended between them.
@@ -201,7 +250,7 @@ async def drive(args, cell: dict, herd: Herd, config: dict, mix: dict,
                                      if r["ok"] and r["t_end"] <= t_close)},
         "records": records,
         "harness": {"ready_s": t_ready - T0, "warm_s": warm_s, "setup_s": setup_s,
-                    "gen_busy_s": gen_busy_s, "clients": load.clients},
+                    "gen_busy_s": gen_busy_s, "clients": streams},
         "prom": prom,
         "trace": None,
     }
@@ -219,7 +268,7 @@ async def drive(args, cell: dict, herd: Herd, config: dict, mix: dict,
             "value": sum(1 for r in readback if not r["ok"]), "limit": 0}
         checks["readback_blobs_short"] = {
             "value": max(0, min(1, len(records)) - len(readback)), "limit": 0}
-    checks.update(counter_checks(config, ctx, payload))
+    checks.update(counter_checks(config, ctx))
     say(event="window", attempted=len(records),
         ended_in_window=sum(1 for r in records if r["t_end"] <= t_close),
         drained_s=drained_s, read_back=len(readback),
@@ -227,6 +276,10 @@ async def drive(args, cell: dict, herd: Herd, config: dict, mix: dict,
         compiles_in_window=after["compiles"] - warmed["compiles"],
         compile_s_in_window=after["compile_s"] - warmed["compile_s"],
         faults=[r["why"] for r in records + readback if not r["ok"]][:5])
+    if load.op == "rollout":
+        say(event="fleet", **fleet_summary(herd, records, prom),
+            delivered_bytes_all_phases=sum(r["bytes"] for r in load.records if r["ok"]),
+            seeded_bytes=sum(b.size for b in load.pool))
     with open(os.path.join(out_dir, "records.jsonl"), "w") as f:
         for r in load.records:
             f.write(json.dumps(r) + "\n")
@@ -339,7 +392,9 @@ def main(argv: list[str] | None = None, require_chip: bool = True,
         return 2
     bench = contract.load_benchmark(bench_path)
     cell = contract.cell_of(bench, args.workload)
-    config = load_json("configs", cell["config"] + ".json")
+    entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    with open(os.path.join(REPO, entry["file"])) as f:
+        config = json.load(f)
     mix = traffic.load_traffic(cell["traffic"], args.scale)
     peaks = load_json("peaks.json")
     out_dir = os.path.join(HERE, "_out", cell["name"])
@@ -352,7 +407,7 @@ def main(argv: list[str] | None = None, require_chip: bool = True,
     try:
         try:
             herd.start(timeout=300)
-            run = asyncio.run(drive(args, cell, herd, config, mix, out_dir))
+            run = asyncio.run(drive(args, bench, cell, herd, config, mix, out_dir))
         finally:
             herd.stop()
         kind = run["device"]["kind"]

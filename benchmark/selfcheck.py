@@ -64,11 +64,13 @@ def main() -> int:
     problems = contract.check_benchmark(bench)
     check(not problems, f"BENCHMARK.json keeps to the contract {problems}")
 
+    files = {c["name"]: c["file"] for c in bench["configs"]}
     for cell in bench["workloads"]:
-        with open(os.path.join(HERE, "configs", cell["config"] + ".json")) as f:
+        with open(os.path.join(os.path.dirname(HERE), files[cell["config"]])) as f:
             config = json.load(f)
         mix = traffic.load_traffic(cell["traffic"])
-        check(config["chip"] in config["herd"] and config["drive"]["op"] in ("push", "pull"),
+        check(config["chip"] in config["herd"]
+              and config["drive"]["op"] in ("push", "pull", "rollout"),
               f"{cell['name']}: configuration names its chip's component and its drive")
         for m in contract.metrics_of(bench, cell["name"], True) + \
                 contract.metrics_of(bench, cell["name"], False):

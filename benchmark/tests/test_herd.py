@@ -3,7 +3,7 @@ be taken before the child binds it (one run of thirteen died at boot for it,
 PR 25). A child that exits at boot on an address in use makes the herd draw
 new ports and boot once more, and say so; anything else fails as it did.
 
-    python3 -m pytest benchmark/tests/test_herd.py -q   (about half a minute)
+    python3 -m pytest benchmark/tests/test_herd.py -q   (about a minute)
 """
 
 import json
@@ -59,6 +59,21 @@ def test_taken_port_boots_once_more_and_says_so(config, tmp_path, squat_on,
     assert said[0]["address_in_use"] == [
         {"tracker": "tracker", "control": "origin"}[squat_on]]
     assert any(name.endswith(".boot1") for name in os.listdir(tmp_path / "logs"))
+
+
+def test_taken_port_of_a_fleets_agent_boots_once_more(tmp_path, monkeypatch, capfd):
+    """The ports of ``agent-1`` ... are drawn anew too."""
+    with open(os.path.join(HERE, "agent-tpu-fleet.json")) as f:
+        fleet = json.load(f)
+    h, first = boot(fleet, tmp_path, "agent-2_p2p", monkeypatch)
+    try:
+        assert h.ports["agent-2_p2p"] != first["agent-2_p2p"]
+        assert set(h.ready) == {"tracker", "origin", "agent", "agent-1", "agent-2"}
+    finally:
+        h.stop()
+    said = [json.loads(line) for line in capfd.readouterr().err.splitlines()
+            if line.startswith('{"event": "herd_reboot"')]
+    assert len(said) == 1 and said[0]["address_in_use"] == ["agent-2"]
 
 
 def test_other_boot_failures_are_not_retried(config, tmp_path, monkeypatch, capfd):
