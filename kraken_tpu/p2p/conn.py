@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import asyncio
 import dataclasses
+import time
 from typing import Callable, Optional
 
 from kraken_tpu.core.metainfo import InfoHash
@@ -207,12 +208,15 @@ class Conn:
                         break
                     batch.append(m)
                 if batch:
+                    t_taken = time.monotonic()
                     payload_bytes = sum(
                         len(m.payload) for m in batch
                         if m.type == MsgType.PIECE_PAYLOAD
                     )
+                    t_shaped = t_taken
                     if self._bw and payload_bytes:
                         await self._bw.send(payload_bytes)
+                        t_shaped = time.monotonic()
                     # Failpoint p2p.conn.send.delay: stall this batch (a
                     # congested/slow link) -- drives churn-exemption and
                     # adaptive piece-timeout paths. Evaluated once per
@@ -224,6 +228,13 @@ class Conn:
                             await asyncio.sleep(hit.delay_s)
                     await send_messages(self._writer, batch)
                     self.bytes_sent += sum(len(m.payload) for m in batch)
+                    # Every frame of the batch waited for all of it: each
+                    # gets the batch's readings (a served piece's stages,
+                    # p2p/dispatch.py SERVE_STAGES).
+                    t_written = time.monotonic()
+                    for m in batch:
+                        if m.on_sent is not None:
+                            m.on_sent(t_taken, t_shaped, t_written)
                 if stop:
                     return
         except ConnectionError as e:

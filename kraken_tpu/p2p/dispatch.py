@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import asyncio
 import contextlib
+import functools
+import time
 from typing import Callable, Optional
 
 from kraken_tpu.core.peer import PeerID
@@ -40,6 +42,20 @@ from kraken_tpu.utils.pushsteps import stepped
 # and a ``recv`` of 0. A duplicate, a piece that fails verification and
 # one whose request was not stamped here book nothing.
 PIECE_STAGES = ("wait", "recv", "queue", "verify", "write", "refill")
+
+# A served piece's life on the main loop, in five stages that do not
+# overlap (``p2p_serve_stage_seconds{stage}``): the PIECE_REQUEST's header
+# read -> its serve task starts (``admit``: the recv queue, the pump, the
+# loop turn before the task runs) -> ``read_piece_async`` returns
+# (``read``: the thread hop and the pread) -> the conn's send loop takes
+# the payload into a batch (``queue``: a wait for room in the send queue
+# included) -> the egress bucket returns (``shape``; 0 on an unshaped
+# node) -> the batch written and drained (``write``). Each payload of a
+# batch books the batch's shape and write. A serve that fails or is
+# cancelled, or whose frame is never written, books nothing; so does the
+# shard-worker serve path (``data_plane_workers`` > 0), whose conns live
+# in worker processes.
+SERVE_STAGES = ("admit", "read", "queue", "shape", "write")
 
 
 def _bits_to_set(bits: bytes, num_pieces: int) -> set[int]:
@@ -130,6 +146,13 @@ class Dispatcher:
             "p2p_piece_stage_seconds",
             "Seconds a received piece spent in each stage of its request"
             " slot's cycle (wait|recv|queue|verify|write|refill)",
+        )
+        self._h_serve = REGISTRY.histogram(
+            "p2p_serve_stage_seconds",
+            "Seconds a piece served on the main loop spent in each stage,"
+            " its request's header read to its payload written"
+            " (admit|read|queue|shape|write); the shard-worker serve path"
+            " books none",
         )
         self._peers_seen: set[PeerID] = set()
         self._blacklist_events = 0
@@ -309,8 +332,8 @@ class Dispatcher:
     # bound, each pending serve holds a piece-sized buffer and a hostile
     # leecher could drive a seeder to OOM.
 
-    def _admit_serve(self, peer: _Peer, idx: int,
-                     tp: str | None = None) -> None:
+    def _admit_serve(self, peer: _Peer, idx: int, tp: str | None = None,
+                     t_head: float | None = None) -> None:
         """``serving`` must be bumped HERE, synchronously at admission:
         ``conn.recv()`` on already-buffered frames completes without
         yielding to the loop, so a burst of buffered PIECE_REQUESTs would
@@ -319,7 +342,7 @@ class Dispatcher:
         exists to prevent. Decrement in the task's done callback, so
         cancellation-before-first-step can't leak the slot."""
         peer.serving += 1
-        t = self._spawn_io(peer, self._serve_piece(peer, idx, tp))
+        t = self._spawn_io(peer, self._serve_piece(peer, idx, tp, t_head))
 
         def release(_task: asyncio.Task) -> None:
             peer.serving -= 1
@@ -376,7 +399,12 @@ class Dispatcher:
         t.add_done_callback(release)
 
     async def _serve_piece(self, peer: _Peer, idx: int,
-                           tp: str | None = None) -> None:
+                           tp: str | None = None,
+                           t_head: float | None = None) -> None:
+        """``t_head``: the PIECE_REQUEST's header reading; without one
+        (a frame handed over by a leech worker) the serve books no
+        stages."""
+        t_start = time.monotonic()
         # The serve span joins the REQUESTER's trace (the PIECE_REQUEST
         # carried its traceparent only when that trace is sampled), so
         # request -> serve -> payload reads as one tree across nodes.
@@ -386,9 +414,17 @@ class Dispatcher:
                        peer=peer.conn.peer_id.hex[:12])
             if parent is not None else contextlib.nullcontext()
         )
-        with cm:
+        with cm as sp:
             data = await self.torrent.read_piece_async(idx)
-            await peer.conn.send(Message.piece_payload(idx, data))
+            t_read = time.monotonic()
+            msg = Message.piece_payload(idx, data)
+            if t_head is not None:
+                msg.on_sent = functools.partial(
+                    self._book_serve, t_head, t_start, t_read)
+                if sp is not None:  # the stages known by the span's end
+                    sp.set(admit_s=round(t_start - t_head, 6),
+                           read_s=round(t_read - t_start, 6))
+            await peer.conn.send(msg)
         self._bytes_up += len(data)
         self._ctr_up.inc(len(data))
         # A completed send is progress: an honest-but-slow link keeps
@@ -407,7 +443,7 @@ class Dispatcher:
                 self.torrent.has_piece(idx)
                 and peer.serving < self._MAX_SERVING_PER_PEER
             ):
-                self._admit_serve(peer, idx, msg.header.get("tp"))
+                self._admit_serve(peer, idx, msg.header.get("tp"), msg.t_head)
         elif msg.type == MsgType.PIECE_PAYLOAD:
             # Cold path: payloads that queued before the fast-path handler
             # was registered (or in unit tests driving _handle directly).
@@ -535,6 +571,17 @@ class Dispatcher:
         if sp is not None:
             sp.set(**{f"{stage}_s": round(s, 6)
                       for stage, s in zip(PIECE_STAGES, seconds)})
+
+    def _book_serve(self, t_head: float, t_start: float, t_read: float,
+                    t_taken: float, t_shaped: float, t_written: float) -> None:
+        """One served piece's five stages (SERVE_STAGES), called by the
+        conn's send loop once its payload is written: they sum to
+        ``t_written - t_head``."""
+        for stage, s in zip(SERVE_STAGES, (
+            t_start - t_head, t_read - t_start, t_taken - t_read,
+            t_shaped - t_taken, t_written - t_shaped,
+        )):
+            self._h_serve.observe(s, stage=stage)
 
     def stage_split(self) -> dict:
         """Public read of the per-pull stage walls (the scheduler's

@@ -33,7 +33,7 @@ from __future__ import annotations
 import asyncio
 import enum
 import time
-from typing import Any, Iterable, Optional
+from typing import Any, Callable, Iterable, Optional
 
 import msgpack
 
@@ -82,9 +82,15 @@ class Message:
     ``t_head`` is when :func:`recv_message` had the frame's prefix
     (``time.monotonic``): where a piece's ``wait`` on the wire ends and
     its ``recv`` begins (p2p/dispatch.py). None for a frame built here or
-    handed over by a leech worker."""
+    handed over by a leech worker.
 
-    __slots__ = ("type", "header", "payload", "lease", "t_head")
+    ``on_sent``, when set on an outbound frame, is called by the conn's
+    send loop once the frame is written and drained, with three
+    ``time.monotonic`` readings of its batch: taken off the send queue,
+    past the egress bucket, written. A frame that is never written never
+    calls it."""
+
+    __slots__ = ("type", "header", "payload", "lease", "t_head", "on_sent")
 
     def __init__(
         self,
@@ -98,6 +104,7 @@ class Message:
         self.payload = payload
         self.lease = lease
         self.t_head: Optional[float] = None
+        self.on_sent: Optional[Callable[[float, float, float], None]] = None
 
     def release(self) -> None:
         lease, self.lease = self.lease, None
